@@ -3,7 +3,10 @@
 Many rank processes may import concurrently (the launcher spawns them in a
 burst), so the compile is serialized with an exclusive flock and lands via
 atomic rename; losers of the race find the finished .so.  The .so is cached
-next to the source and rebuilt whenever shmring.cpp is newer.
+next to the source, keyed by a hash of shmring.cpp and the compiler flags
+(stored beside it in ``<name>.so.key``): a .so whose key does not match —
+say, an untracked build copied along with a newer tree — is rebuilt, and
+file times play no part.
 
 Sanitizer builds: ``MPI_TPU_SANITIZE=address|undefined|thread`` adds the
 matching ``-fsanitize=`` flags and caches the result under a
@@ -18,6 +21,7 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -59,32 +63,52 @@ def _so_path(mode: str) -> str:
     return os.path.join(_DIR, f"_shmring.{_SANITIZERS[mode][0]}.so")
 
 
+def _cmd(mode: str, out: str) -> list:
+    # -lrt: shm_open/shm_unlink live in librt on pre-2.34 glibc (a stub
+    # librt still exists on newer ones, so the flag is portable both ways)
+    return ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
+            *(_SANITIZERS[mode][1] if mode else []),
+            "-o", out, _SRC, "-pthread", "-lrt"]
+
+
+def _key(mode: str) -> str:
+    """Hash of the source and the command the .so is built with."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(_cmd(mode, "")).encode())
+    return h.hexdigest()
+
+
+def _fresh(so: str, key: str) -> bool:
+    try:
+        with open(so + ".key") as f:
+            return f.read() == key and os.path.exists(so)
+    except OSError:
+        return False
+
+
 def ensure_built(force: bool = False) -> str:
     """Compile shmring.cpp if needed; return the path to the .so.
 
-    ``force`` rebuilds even when the cached .so looks fresh — the recovery
-    path for a .so carried over from a host with a different glibc layout
-    (dlopen fails with an unresolved symbol; see load_shmring)."""
+    ``force`` rebuilds even when the cached .so's key matches — the
+    recovery path for a .so carried over from a host with a different
+    glibc layout (dlopen fails with an unresolved symbol; see
+    load_shmring)."""
     mode = sanitize_mode()
     so = _so_path(mode)
-    if (not force and os.path.exists(so)
-            and os.path.getmtime(so) >= os.path.getmtime(_SRC)):
+    key = _key(mode)
+    if not force and _fresh(so, key):
         return so
     lock_path = os.path.join(_DIR, ".build.lock")
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
         try:
-            if (not force and os.path.exists(so)
-                    and os.path.getmtime(so) >= os.path.getmtime(_SRC)):
+            if not force and _fresh(so, key):
                 return so  # another process built it while we waited
             fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
             os.close(fd)
-            # -lrt: shm_open/shm_unlink live in librt on pre-2.34 glibc
-            # (a stub librt still exists on newer ones, so the flag is
-            # portable both ways)
-            cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                   *(_SANITIZERS[mode][1] if mode else []),
-                   "-o", tmp, _SRC, "-pthread", "-lrt"]
+            cmd = _cmd(mode, tmp)
             try:
                 proc = subprocess.run(cmd, capture_output=True, text=True)
             except FileNotFoundError as e:
@@ -99,6 +123,9 @@ def ensure_built(force: bool = False) -> str:
                     f"{f' (MPI_TPU_SANITIZE={mode})' if mode else ''}:\n"
                     f"{proc.stderr[-2000:]}")
             os.replace(tmp, so)
+            with open(tmp + ".key", "w") as f:
+                f.write(key)
+            os.replace(tmp + ".key", so + ".key")
             return so
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)

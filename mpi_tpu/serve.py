@@ -922,7 +922,7 @@ class WorldServer:
         pool = self._pools[pool_id]
         env = dict(os.environ)
         want = self._env_extra.get("MPI_TPU_RANK_JAX_PLATFORMS")
-        cpu_pinned_env(env, want)
+        cpu_pinned_env(env, want, pool.size)
         env.update({
             "MPI_TPU_RANK": str(slot),
             "MPI_TPU_SIZE": str(pool.size),

@@ -116,6 +116,13 @@ def _spawn_segments(segments: List[Tuple[List[str], int]],
     nchildren = sum(n for _, n in segments)
     if nchildren < 1:
         raise ValueError("maxprocs must total >= 1")
+    from .launcher import cpu_pinned_env
+
+    # every parent validates the children's platform before any
+    # collective step, so a refused spawn raises everywhere alike
+    cpu_pinned_env(dict(os.environ),
+                   (env_extra or {}).get("MPI_TPU_RANK_JAX_PLATFORMS"),
+                   nchildren)
     p = comm.size
     total = p + nchildren
     # root makes the rendezvous dirs; everyone learns them collectively
@@ -137,12 +144,11 @@ def _spawn_segments(segments: List[Tuple[List[str], int]],
         child_rank = 0
         for argv, n in segments:
             for _ in range(n):
-                from .launcher import cpu_pinned_env
-
                 env = dict(os.environ)
                 # same CPU pinning as the launcher (shared helper)
                 cpu_pinned_env(
-                    env, (env_extra or {}).get("MPI_TPU_RANK_JAX_PLATFORMS"))
+                    env, (env_extra or {}).get("MPI_TPU_RANK_JAX_PLATFORMS"),
+                    nchildren)
                 env.update({
                     ENV_RANK: str(child_rank),
                     ENV_SIZE: str(nchildren),

@@ -2,7 +2,8 @@
 
 Public surface:
 * :func:`run_spmd` / :func:`default_mesh` — run a portable MPI program as one
-  SPMD trace over the device mesh.
+  SPMD trace over the device mesh; :func:`enable_compile_cache` for chip
+  programs.
 * :class:`TpuCommunicator` — the Communicator bound to a mesh axis; fused XLA
   collectives plus hand-scheduled ppermute algorithms (ring /
   recursive-halving / tree / doubling / pairwise).
@@ -11,7 +12,7 @@ Public surface:
 """
 
 from .communicator import SpmdSemanticsError, TpuCommunicator
-from .runner import default_mesh, run_spmd
+from .runner import default_mesh, enable_compile_cache, run_spmd
 from . import collectives
 
 
@@ -31,6 +32,7 @@ __all__ = [
     "SpmdSemanticsError",
     "run_spmd",
     "default_mesh",
+    "enable_compile_cache",
     "collectives",
     "pallas_ring_attention",
 ]

@@ -54,16 +54,10 @@ def _ensure_varying(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
     Loop carries fed to ppermute inside fori_loop must enter the loop with
     the same varying-axes type they leave with; inputs that are replicated
-    (e.g. broadcast operands) need an explicit pvary."""
-    try:
-        vma = jax.typeof(x).vma
-    except AttributeError:  # pragma: no cover - non-shard_map tracing
+    (e.g. broadcast operands) need an explicit pcast."""
+    if axis_name in jax.typeof(x).vma:
         return x
-    if axis_name in vma:
-        return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    return lax.pvary(x, (axis_name,))  # pragma: no cover - pre-0.9 jax
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _mask_of(ranks: Sequence[int], axis_size: int, axis_name: str):

@@ -662,25 +662,13 @@ class TpuCommunicator(Communicator):
         and therefore varying by definition.  No protection exists
         under ``check_vma=False`` (there is no typing to flag against);
         that caveat is documented at every sharded-gather call site."""
-        try:  # already varying over the axis (the usual case: the
-            # gathered value is per-rank data) — nothing to brand
-            if self.axis_name in jax.typeof(x).vma:
-                return x
-        except AttributeError:
-            pass  # no vma typing on this value/jax
-        # pcast is the current spelling (a no-op outside shard_map, so
-        # no exception guard: real API breakage must FAIL the tests,
-        # not silently un-brand the slice — review round 5)
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, self.axis_name, to="varying")
-        try:  # pre-pcast jax: pvary raises on an unbound axis name
-            return lax.pvary(x, self.axis_name)
-        except (NameError, ValueError):
-            # outside shard_map: nothing to brand against.  Which exception
-            # an unbound axis raises has moved between jax releases
-            # (NameError historically, ValueError in newer trace-context
-            # plumbing — ADVICE r5 #3), so both mean the same benign thing
+        # already varying over the axis (the usual case: the gathered
+        # value is per-rank data) — nothing to brand
+        if self.axis_name in jax.typeof(x).vma:
             return x
+        # pcast is a no-op outside shard_map, so no exception guard: real
+        # API breakage must FAIL the tests, not silently un-brand the slice
+        return lax.pcast(x, self.axis_name, to="varying")
 
     def gather(self, obj, root: int = 0, sharded: bool = False):
         """Stacked [size, ...] — contract guarantees it only at root (other

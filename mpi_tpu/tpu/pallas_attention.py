@@ -44,11 +44,11 @@ VMEM budget:
   in VMEM and each fold materializes one [Sb, Sb] score block.  The
   fast path for blocks up to ~1-2k rows at d=128/f32.
 * *tiled* — flash-attention-style inner tiling: the m/l/o state lives
-  in HBM scratch; each arrival loops over [tq]-row query tiles and
+  in HBM; each arrival loops over [tq]-row query tiles and
   [tk]-row K/V tiles (``lax.fori_loop``), staging each tile through
   small VMEM buffers, so the live score block is [tq, tk] and the
   block size is bounded by HBM, not VMEM.  Tile sizes are the largest
-  sublane-aligned divisors of Sb that fit the budget.
+  sublane-aligned divisors of Sb, at most 512, that fit the budget.
 
 Either way the RDMA circulation (slots, credits, barriers) is
 IDENTICAL — the fold is a local subroutine between protocol events, so
@@ -112,6 +112,11 @@ _MASKED = -1e30  # large-negative finite (an -inf mask would NaN through exp)
 # headroom for Mosaic's own spills/semaphores/metadata.
 _VMEM_BUDGET = 12 * 2 ** 20
 
+# Largest tile edge of the tiled folds.  Mosaic unrolls a tile's vector
+# ops, so compile time grows with tq*tk times the heads: 8 heads of
+# [1024, 1024] tiles took ~95 s to compile for v5e, [512, 512] ~17 s.
+_MAX_TILE = 512
+
 
 def _online_fold(q, k, v, m, l, o, scale, mask=None):
     """One block's online-softmax fold (shared by kernel and fallback).
@@ -144,16 +149,10 @@ def _causal_mask(my, kv_idx, sb: int, i0=0, j0=0,
     return kj <= qi
 
 
-def _divisors_desc(n: int):
-    """Divisors of n, descending."""
-    out = set()
-    i = 1
-    while i * i <= n:
-        if n % i == 0:
-            out.add(i)
-            out.add(n // i)
-        i += 1
-    return sorted(out, reverse=True)
+def _tile_sizes(sb: int, sub: int):
+    """Sublane-aligned divisors of ``sb`` up to ``_MAX_TILE``, descending."""
+    return [t for t in range(min(sb, _MAX_TILE), 0, -1)
+            if t % sub == 0 and sb % t == 0]
 
 
 # Shared kernel helpers (one definition serves forward and backward —
@@ -295,8 +294,8 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
 
     Returns ``("resident", None)`` when the whole-block fold fits, or
     ``("tiled", (tq, tk))`` with the largest sublane-aligned divisor
-    tile that fits — for the forward AND (round 5) the backward.  A
-    backward no tile can satisfy returns ``("fallback", None)`` (→
+    tile (at most ``_MAX_TILE`` rows) that fits — for the forward AND
+    (round 5) the backward.  A backward no tile can satisfy returns ``("fallback", None)`` (→
     ppermute recompute, correct at any size); the forward instead
     raises NotImplementedError with the arithmetic — which the caller
     (pallas_ring_attention) converts into the loud ppermute-ring
@@ -322,8 +321,7 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                     + 2 * sb * d * 4)          # fold temporaries
         if resident <= limit:
             return ("resident", None)
-        for mdiv in _divisors_desc(sb // sub):
-            t = sub * mdiv
+        for t in _tile_sizes(sb, sub):
             tiled = (2 * t * d * esz           # q/do tiles
                      + 2 * t * _LANES * 4      # lse/delta tiles
                      + 2 * t * d * esz         # k/v tiles (wire dtype)
@@ -345,8 +343,7 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                 + 2 * sb * d * 4)              # fold temporaries
     if resident <= limit:
         return ("resident", None)
-    for mdiv in _divisors_desc(sb // sub):
-        t = sub * mdiv
+    for t in _tile_sizes(sb, sub):
         tiled = (3 * t * d * esz               # q/k/v tiles
                  + 2 * t * _LANES * 4          # m, l tiles
                  + t * d * 4                   # o tile
@@ -382,7 +379,7 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     AttentionSim runs, tests/test_pallas_protocol.py).
 
     ``tiles=None`` → resident fold; ``tiles=(tq, tk)`` → flash-style
-    inner tiling with the m/l/o state in HBM scratch (module
+    inner tiling with the m/l/o state in HBM (module
     docstring).  The protocol events are identical in both modes.
 
     ``with_lse`` adds a second output ref carrying L = m + log l (the
@@ -679,7 +676,7 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     copy_par = _mk_copy_par(par_sems)
 
     # send u (0..P-1): the block folded at step u moves on; send 0
-    # reads the assembled own-block scratch, not a comm slot.  Two
+    # reads the assembled own-block buffer, not a comm slot.  Two
     # planes per hop (split dtypes), one protocol (_SndPair).
     snd_kv = _mk_snd(own_kv_hbm, comm_kv_hbm, send_sem, recv_sem, dev_kw,
                      right, col=0)
@@ -1045,16 +1042,29 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         _fallback("ring_attention", axis_name, vma_on, multi_axis)
         return _reference(q, k, v)
 
-    def _out_structs(shapes):
-        if vma_on:
-            try:
-                in_vma = frozenset(jax.typeof(q).vma)
-            except (AttributeError, NameError):
-                in_vma = frozenset()
-            return tuple(jax.ShapeDtypeStruct(s, jnp.float32,
-                                              vma=in_vma | {axis_name})
-                         for s in shapes)
-        return tuple(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)
+    vma = (frozenset(jax.typeof(q).vma) | {axis_name} if vma_on
+           else frozenset())
+
+    def _hbm_call(kern, outs, scratch, collective_id, params, *args):
+        """One ring-kernel launch: ``params`` in SMEM, ``args`` in HBM.
+        ``outs`` = (shape, dtype) pairs, all in HBM: the results first,
+        then the kernel's HBM working buffers (landing slots, tiled
+        state).  Mosaic allocates scratch only in VMEM/SMEM, so those
+        buffers are extra outputs the caller drops."""
+        return pl.pallas_call(
+            kern,
+            out_shape=tuple(jax.ShapeDtypeStruct(s, dt, vma=vma)
+                            for s, dt in outs),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] +
+                     [pl.BlockSpec(memory_space=pl.ANY)] * len(args),
+            out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
+                            for _ in outs),
+            scratch_shapes=scratch,
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                collective_id=collective_id, has_side_effects=True,
+                vmem_limit_bytes=vmem_limit_bytes),
+            interpret=interpret,
+        )(params, *args)
 
     def _kernel_call(q_, k_, v_, with_lse):
         """→ out [shaped like q], or (out, lse [hq*sb, _LANES] f32)
@@ -1073,11 +1083,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=tiles,
             with_lse=with_lse)
-        compiler_params = None if interpret else pltpu.CompilerParams(
-            collective_id=16, has_side_effects=True)
+        outs = [((hq * sb, d), jnp.float32)]
+        if with_lse:
+            outs.append(((hq * sb, _LANES), jnp.float32))
+        outs.append(((2, 2 * hkv * sb, d), q.dtype))     # landing slots
         if tiles is None:
             scratch = [
-                pl.ANY((2, 2 * hkv * sb, d), q.dtype),   # landing slots
                 pltpu.VMEM((hq * sb, d), q.dtype),       # Q (all heads)
                 pltpu.VMEM((2 * hkv * sb, d), q.dtype),  # K/V staging
                 pltpu.VMEM((hq * sb, 1), jnp.float32),   # m
@@ -1089,11 +1100,10 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     pltpu.VMEM((hq * sb, _LANES), jnp.float32))  # lse
         else:
             tq, tk = tiles
+            outs += [((hq * sb, _LANES), jnp.float32),    # m state
+                     ((hq * sb, _LANES), jnp.float32),    # l state
+                     ((hq * sb, d), jnp.float32)]         # o state
             scratch = [
-                pl.ANY((2, 2 * hkv * sb, d), q.dtype),   # landing slots
-                pl.ANY((hq * sb, _LANES), jnp.float32),  # m state (HBM)
-                pl.ANY((hq * sb, _LANES), jnp.float32),  # l state (HBM)
-                pl.ANY((hq * sb, d), jnp.float32),       # o state (HBM)
                 pltpu.VMEM((tq, d), q.dtype),            # q tile
                 pltpu.VMEM((tk, d), q.dtype),            # k tile
                 pltpu.VMEM((tk, d), q.dtype),            # v tile
@@ -1108,21 +1118,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        shapes = [(hq * sb, d)]
-        if with_lse:
-            shapes.append((hq * sb, _LANES))
-        res = pl.pallas_call(
-            kern,
-            out_shape=_out_structs(shapes),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
-                            for _ in shapes),
-            scratch_shapes=scratch,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(params, qf, kv)
+        res = _hbm_call(kern, outs, scratch, 16, params, qf, kv)
         out = res[0].astype(q_.dtype)
         out = out.reshape(hq, sb, d) if multihead else out
         return (out, res[1]) if with_lse else out
@@ -1147,17 +1143,15 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             _bwd_kernel, axis_name=axis_name, size=size, sb=sb, d=d,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles)
-        compiler_params = None if interpret else pltpu.CompilerParams(
-            collective_id=17, has_side_effects=True)
         kv_rows = 2 * hkv * sb
-        scratch = [
-            pl.ANY((kv_rows, d), q.dtype),               # own [K,V] (wire)
-            pl.ANY((kv_rows, d), jnp.float32),           # own [dK,dV]
-            pl.ANY((2, kv_rows, d), q.dtype),            # K/V landing slots
-            pl.ANY((2, kv_rows, d), jnp.float32),        # dK/dV landing slots
-        ]
+        outs = [((hq * sb, d), jnp.float32),             # dQ
+                ((kv_rows, d), jnp.float32),             # dK/dV (home)
+                ((kv_rows, d), q.dtype),                 # own [K,V] (wire)
+                ((kv_rows, d), jnp.float32),             # own [dK,dV]
+                ((2, kv_rows, d), q.dtype),              # K/V landing slots
+                ((2, kv_rows, d), jnp.float32)]          # dK/dV landing slots
         if bwd_tiles is None:
-            scratch += [
+            scratch = [
                 pltpu.VMEM((hq * sb, d), q.dtype),           # Q
                 pltpu.VMEM((hq * sb, d), q.dtype),           # dOut
                 pltpu.VMEM((hq * sb, _LANES), jnp.float32),  # lse
@@ -1168,7 +1162,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             ]
         else:
             tqb, tkb = bwd_tiles
-            scratch += [
+            scratch = [
                 pltpu.VMEM((tqb, d), q.dtype),               # q tile
                 pltpu.VMEM((tqb, d), q.dtype),               # dOut tile
                 pltpu.VMEM((tqb, _LANES), jnp.float32),      # lse tile
@@ -1186,17 +1180,8 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        dq, dkv = pl.pallas_call(
-            kern,
-            out_shape=_out_structs([(hq * sb, d), (kv_rows, d)]),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] +
-                     [pl.BlockSpec(memory_space=pl.ANY)] * 5,
-            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pl.ANY)),
-            scratch_shapes=scratch,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(params, qf, kv, dof, lse, delta)
+        dq, dkv = _hbm_call(kern, outs, scratch, 17,
+                            params, qf, kv, dof, lse, delta)[:2]
         dq = dq.astype(q_.dtype)
         dk = dkv[:hkv * sb].astype(k_.dtype)
         dv = dkv[hkv * sb:].astype(v_.dtype)

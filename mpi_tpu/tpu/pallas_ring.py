@@ -39,8 +39,8 @@ Design (v2 — pipelined; v1 serialized every step behind an RDMA wait and a
 Under the Pallas **interpreter** (the CPU-mesh test path) remote
 semaphore signalling is unavailable, so barriers/credits are skipped and
 every RDMA is started+waited serially — same data path, no pipelining;
-the overlap logic itself is exercised by the AOT compile checks in the
-real-TPU test tier (tests/test_tpu_real.py).
+the pipelined kernel compiles for a described v5e in
+tests/test_tpu_compile.py and runs on four chips in ``chip_smoke.py``.
 
 **Protocol invariants** — verified by the discrete-event model in
 ``mpi_tpu/tpu/ring_model.py`` (exhaustive interleaving search for small
@@ -143,7 +143,7 @@ def _kernel(params_smem, x_hbm, out_hbm, comm_hbm, a_vmem, b_vmem,
 
     ``mesh_ids`` selects the neighbor ADDRESSING mode (VERDICT r3
     missing #2 — multi-axis meshes):  False → the neighbor's axis index
-    IS its logical device id (1-D mesh; the path validated on silicon).
+    IS its logical device id (1-D mesh).
     True → the neighbor is named by its coordinate along ``axis_name``
     via a dict-MESH device id ``{axis_name: idx}``; Mosaic fills the
     other mesh axes with this device's own coordinates and converts to
@@ -266,13 +266,18 @@ def _kernel(params_smem, x_hbm, out_hbm, comm_hbm, a_vmem, b_vmem,
             if u < n_rs:
                 # accumulate landing[slot, flow] into out[accum_chunk, flow]
                 ci = accum_chunk(u, dirn)
-                for t in range(t0, t0 + nt):
-                    row0 = ci * rows + t * tile_rows
+
+                # a loop, not an unrolled Python range: a 256 MiB buffer
+                # has hundreds of tiles per flow, and unrolled they cost
+                # minutes of tracing and Mosaic compile time
+                def accum_tile(t, carry, ci=ci, slot=slot):
+                    r_in = pl.multiple_of(t * tile_rows, tile_rows)
+                    row0 = pl.multiple_of(ci * rows + r_in, tile_rows)
                     cp_a = pltpu.make_async_copy(
                         out_hbm.at[pl.ds(row0, tile_rows)], a_vmem,
                         copy_sem_a)
                     cp_b = pltpu.make_async_copy(
-                        comm_hbm.at[slot, pl.ds(t * tile_rows, tile_rows)],
+                        comm_hbm.at[slot, pl.ds(r_in, tile_rows)],
                         b_vmem, copy_sem_b)
                     cp_a.start()
                     cp_b.start()
@@ -285,6 +290,9 @@ def _kernel(params_smem, x_hbm, out_hbm, comm_hbm, a_vmem, b_vmem,
                         copy_sem_a)
                     cp_out.start()
                     cp_out.wait()
+                    return carry
+
+                lax.fori_loop(t0, t0 + nt, accum_tile, 0)
             if pipelined and u + 2 < n_steps:
                 # step-u consumption done (RS: landing slot accumulated;
                 # AG: chunk landed) → credit the writer (the flow's
@@ -346,19 +354,16 @@ def _check_args(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
         raise ValueError(
             f"tile_rows must be a positive multiple of {sub} "
             f"({dtype} sublane tile), got {tile_rows}")
-    try:
-        from jax.sharding import get_abstract_mesh
+    from jax.sharding import get_abstract_mesh
 
-        mesh_axes = get_abstract_mesh().axis_names
-    except Exception:
-        mesh_axes = (axis_name,)
+    mesh_axes = get_abstract_mesh().axis_names
     multi_axis = tuple(mesh_axes) not in ((), (axis_name,))
     # vma typing may be active even when the payload is replicated; probe
     # with axis_index, which is varying exactly when check_vma is on
     try:
         vma_on = bool(jax.typeof(lax.axis_index(axis_name)).vma)
-    except (AttributeError, NameError):
-        vma_on = False  # no vma typing / not under shard_map (yet)
+    except NameError:
+        vma_on = False  # not under shard_map (yet)
     return vma_on, multi_axis
 
 
@@ -443,7 +448,7 @@ def _launch(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
     ``vma_on``: varying-axes typing is active on the enclosing shard_map.
     The compiled kernel supports it directly — the out_shape declares the
     result varying over ``axis_name`` and Mosaic lowers the body outside
-    vma land (verified by the real-TPU AOT tier).  Callers on the
+    vma land (tests/test_tpu_compile.py).  Callers on the
     *interpreter* must not reach here with ``vma_on`` (the interpreter
     evaluates the body as jax ops, where hbm↔scratch mixes trip the vma
     checker) — they take the vma-typed ppermute fallback instead."""
@@ -471,29 +476,26 @@ def _launch(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
     compiler_params = None if interpret else pltpu.CompilerParams(
         collective_id=collective_id, has_side_effects=True)
     k = len(flows)
-    if vma_on:
-        # the result varies over the ring axis AND over any other mesh
-        # axis the input already varies over (multi-axis meshes: a dp
-        # ring's payload is usually mp-varying too)
-        try:
-            in_vma = frozenset(jax.typeof(grid_in).vma)
-        except (AttributeError, NameError):
-            in_vma = frozenset()
-        out_shape = jax.ShapeDtypeStruct((size * rows, _LANES), dtype,
-                                         vma=in_vma | {axis_name})
-    else:
-        out_shape = jax.ShapeDtypeStruct((size * rows, _LANES), dtype)
+    # the result varies over the ring axis AND over any other mesh axis
+    # the input already varies over (multi-axis meshes: a dp ring's
+    # payload is usually mp-varying too)
+    vma = (frozenset(jax.typeof(grid_in).vma) | {axis_name}
+           if vma_on else frozenset())
     params = _ring_params(axis_name, size, groups)
-    return pl.pallas_call(
+    # The RDMA landing zone lives in HBM, which Mosaic cannot allocate as
+    # scratch: it is a second output, dropped after the call.  Unused
+    # (1-row stub) in the ag-only mode, where RDMAs land in the output.
+    out, _ = pl.pallas_call(
         kern,
-        out_shape=out_shape,
+        out_shape=(
+            jax.ShapeDtypeStruct((size * rows, _LANES), dtype, vma=vma),
+            jax.ShapeDtypeStruct((2, rows if rs else 1, _LANES), dtype,
+                                 vma=vma)),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
-            # RDMA landing zone — unused (1-row stub) in the ag-only mode,
-            # where RDMAs land directly in the output
-            pl.ANY((2, rows if rs else 1, _LANES), dtype),
             pltpu.VMEM((tile_rows, _LANES), dtype),
             pltpu.VMEM((tile_rows, _LANES), dtype),
             pltpu.SemaphoreType.DMA(()),
@@ -505,6 +507,7 @@ def _launch(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
         compiler_params=compiler_params,
         interpret=interpret,
     )(params, grid_in)
+    return out
 
 
 def flow_summary(n_elements: int, size: int, tile_rows: int = 256,
@@ -539,8 +542,8 @@ def pallas_ring_allreduce(x: jnp.ndarray, axis_name: str, size: int,
     schedule executes as vma-typed ppermute steps instead (the kernel body
     cannot be interpreted under vma typing — kernel-body interpretation is
     covered by the check_vma=False tests, the pipelined protocol by
-    mpi_tpu/tpu/ring_model.py, and the compiled path by the real-TPU AOT
-    tier).
+    mpi_tpu/tpu/ring_model.py, and the compiled path by
+    tests/test_tpu_compile.py and chip_smoke.py).
 
     ``groups``: optional equal-sized partition of the axis (a split
     communicator's axis_index_groups); each group runs its own

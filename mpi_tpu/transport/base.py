@@ -235,7 +235,7 @@ class Transport(ABC):
     """Moves payloads between world ranks; owns a Mailbox for incoming
     traffic.
 
-    Fault taxonomy (ISSUE 10): transports distinguish LINK faults (a
+    Fault classes (ISSUE 10): transports distinguish LINK faults (a
     connection-level hiccup between two live processes — healed
     transparently where the transport has connections to heal, see
     transport/socket.py + mpi_tpu/resilience.py) from PEER faults (the

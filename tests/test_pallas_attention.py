@@ -12,17 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from mpi_tpu.tpu import default_mesh
-from mpi_tpu.tpu import runner as _runner
 from mpi_tpu.tpu.pallas_attention import pallas_ring_attention
-
-# jax-0.4.37 vintage (the pl.ANY memory-space shim active): a handful of
-# tiled-interpret programs trip a FATAL XLA-CPU CHECK (array.h reshape of
-# a 0-element buffer) at compile time — a process abort, not a test
-# failure — so they must skip rather than take the whole suite down.
-tiled_interpret_aborts = pytest.mark.skipif(
-    getattr(_runner, "_PALLAS_MEMSPACE_SHIMMED", False),
-    reason="XLA CPU aborts (array.h 0-element reshape CHECK) compiling "
-           "this tiled interpret fold on the pre-0.5 jax vintage")
 
 
 def _oracle(q, k, v, scale=None):
@@ -507,9 +497,6 @@ def test_tiled_parity_forced(causal):
     """A small vmem_limit_bytes forces the tiled fold (state in HBM,
     [tq,tk] inner loop) at test-friendly sizes: parity with the dense
     oracle, full and causal."""
-    if not causal and getattr(_runner, "_PALLAS_MEMSPACE_SHIMMED", False):
-        pytest.skip("non-causal tiled interpret fold aborts XLA CPU on "
-                    "the pre-0.5 jax vintage (array.h reshape CHECK)")
     Pn, Sb, d = 4, 32, 128
     rng = np.random.RandomState(23)
     q = rng.randn(Pn * Sb, d).astype(np.float32)
@@ -534,7 +521,6 @@ def test_tiled_parity_forced(causal):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
-@tiled_interpret_aborts
 def test_tiled_parity_gqa_bf16():
     """Tiled fold with multi-head GQA layout and bf16 inputs (16-row
     sublane tiles): parity per head."""
@@ -560,7 +546,6 @@ def test_tiled_parity_gqa_bf16():
                                    rtol=5e-2, atol=5e-2)
 
 
-@tiled_interpret_aborts
 def test_tiled_parity_large_block():
     """The VERDICT r4 ask: Sb >= 4096 f32 green on the interpreter —
     the default budget picks the tiled fold (the resident score matrix
@@ -710,7 +695,6 @@ def test_bwd_tiled_parity(causal):
     assert all(np.abs(g).max() > 0 for g in grads["kernel"])
 
 
-@tiled_interpret_aborts
 def test_bwd_fallback_out_of_budget():
     """When even the minimal backward tile exceeds the budget the
     custom-vjp recomputes through the pure-jax ring — gradients still

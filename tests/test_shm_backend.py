@@ -257,3 +257,32 @@ def test_shm_random_frame_sizes_roundtrip():
 
     res = run_shm_world(prog, 2)
     assert res[0] is True
+
+
+def test_native_build_is_keyed_on_source_hash(tmp_path, monkeypatch):
+    """A cached .so is reused only when its key (hash of source + build
+    command) matches; a stale .so with a newer mtime is rebuilt."""
+    import shutil
+
+    from mpi_tpu.native import build
+
+    src = tmp_path / "shmring.cpp"
+    shutil.copy(build._SRC, src)
+    monkeypatch.setattr(build, "_DIR", str(tmp_path))
+    monkeypatch.setattr(build, "_SRC", str(src))
+    monkeypatch.setattr(build, "_SO", str(tmp_path / "_shmring.so"))
+    so = build.ensure_built()
+    key = (tmp_path / "_shmring.so.key").read_text()
+    assert so == str(tmp_path / "_shmring.so") and key == build._key("")
+    # a foreign .so, newer than the source, without a matching key
+    (tmp_path / "_shmring.so").write_bytes(b"stale")
+    (tmp_path / "_shmring.so.key").write_text("0" * 64)
+    build.ensure_built()
+    assert (tmp_path / "_shmring.so").read_bytes() != b"stale"
+    # an unchanged source and key: no rebuild
+    mtime = os.path.getmtime(so)
+    build.ensure_built()
+    assert os.path.getmtime(so) == mtime
+    # a source edit changes the key
+    src.write_text(src.read_text() + "\n// edit\n")
+    assert build._key("") != key

@@ -159,3 +159,22 @@ def test_launcher_propagates_failure(tmp_path):
     from mpi_tpu.launcher import launch
 
     assert launch(2, [str(script)], timeout=60.0) == 7
+
+
+def test_launcher_refuses_ranks_sharing_a_chip(tmp_path, monkeypatch):
+    """A chip belongs to one process: asking for the accelerator platform
+    with more than one rank raises before anything is spawned."""
+    import subprocess
+
+    from mpi_tpu.launcher import cpu_pinned_env, launch
+
+    env = cpu_pinned_env({"MPI_TPU_RANK_JAX_PLATFORMS": "tpu"}, nprocs=1)
+    assert env == {"JAX_PLATFORMS": "tpu"}
+    assert cpu_pinned_env({}, nprocs=4) == {"JAX_PLATFORMS": "cpu"}
+    spawned = []
+    monkeypatch.setattr(subprocess, "Popen",
+                        lambda *a, **k: spawned.append(a))
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        launch(2, [str(tmp_path / "x.py")],
+               env_extra={"MPI_TPU_RANK_JAX_PLATFORMS": "tpu"})
+    assert spawned == []

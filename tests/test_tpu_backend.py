@@ -430,3 +430,29 @@ def test_grouped_shift_stays_in_group():
     out = np.asarray(run_spmd(prog)).ravel()
     # within [0..3]: comes from (grank-1)%4 of same group; same for [4..7]
     np.testing.assert_array_equal(out, [3, 0, 1, 2, 7, 4, 5, 6])
+
+
+def test_enable_compile_cache_placement(tmp_path):
+    """Chip programs' compile cache: JAX_COMPILATION_CACHE_DIR where set
+    (and no other directory), else the fixed ``.jax_cache`` of the
+    checkout.  Runs in children: the suite itself keeps the cache off."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import jax, jax.numpy as jnp\n"
+            "from mpi_tpu.tpu import enable_compile_cache\n"
+            "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.ones(3)).block_until_ready()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                         capture_output=True, text=True, check=True)
+    assert out.stdout.split() == [str(tmp_path)] * 2
+    assert any(tmp_path.iterdir())
+    del env["JAX_COMPILATION_CACHE_DIR"]
+    out = subprocess.run(
+        [sys.executable, "-c", code.rsplit("jax.jit", 1)[0]], env=env,
+        cwd=repo, capture_output=True, text=True, check=True)
+    assert out.stdout.split() == [os.path.join(repo, ".jax_cache")] * 2

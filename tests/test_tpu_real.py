@@ -29,11 +29,13 @@ from mpi_tpu.tpu import TpuCommunicator
 
 pytestmark = pytest.mark.tpu
 
-_HAS_TPU = any(d.platform == "tpu" for d in jax.devices())
-if not _HAS_TPU:
-    pytestmark = [pytest.mark.tpu,
-                  pytest.mark.skip(reason="no real TPU attached "
-                                   "(run with MPI_TPU_TEST_TPU=1)")]
+
+@pytest.fixture(scope="module", autouse=True)
+def _real_tpu():
+    """Every test here needs the chip.  Asked in a fixture, not at import,
+    so that collecting this file never initializes a backend."""
+    if not any(d.platform == "tpu" for d in jax.devices()):
+        pytest.skip("no real TPU attached (run with MPI_TPU_TEST_TPU=1)")
 
 
 def _mesh1() -> Mesh:
