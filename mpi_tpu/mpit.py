@@ -55,7 +55,7 @@ class _Counters:
                  "link_retained", "link_cow_snaps", "link_cow_bytes",
                  "link_syscalls", "link_rsyscalls", "link_torn",
                  "nbc_threads", "nbc_sms", "persist_starts",
-                 "trace_events",
+                 "trace_events", "tpu_traces",
                  "rp_hits", "rp_misses", "rp_rdv", "rp_steered",
                  "rp_fold", "rp_user_in", "rp_user_fb",
                  "store_elections", "store_truncated", "store_dropped")
@@ -108,6 +108,7 @@ class _Counters:
         self.nbc_sms = 0
         self.persist_starts = 0
         self.trace_events = 0
+        self.tpu_traces = 0
         self.rp_hits = 0
         self.rp_misses = 0
         self.rp_rdv = 0
@@ -156,6 +157,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           nbc_state_machines: int = 0,
           persistent_starts: int = 0,
           trace_events: int = 0,
+          tpu_program_traces: int = 0,
           recv_pool_hits: int = 0,
           recv_pool_misses: int = 0,
           recv_pool_rendezvous: int = 0,
@@ -216,6 +218,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.nbc_sms += nbc_state_machines
         counters.persist_starts += persistent_starts
         counters.trace_events += trace_events
+        counters.tpu_traces += tpu_program_traces
         counters.rp_hits += recv_pool_hits
         counters.rp_misses += recv_pool_misses
         counters.rp_rdv += recv_pool_rendezvous
@@ -378,6 +381,12 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # `telemetry.REC is None` attribute test; bench.py --verify-overhead
     # --trace asserts it alongside the unchanged wire accounting).
     "trace_events": lambda: counters.trace_events,
+    # TPU entry (mpi_tpu/tpu/runner.py run_spmd): traces of the SPMD
+    # program, counted in the traced body, so an executed call costs
+    # nothing.  Always on: a count that rises with every mpi_tpu.run
+    # call means each call re-traces (and re-lowers and re-compiles or
+    # re-loads) its program.
+    "tpu_program_traces": lambda: counters.tpu_traces,
     # receive-side zero-copy (mpi_tpu/recvpool.py, ISSUE 17): pool
     # requests served by a recycled size-class buffer vs fresh
     # allocations (the page-fault pass the pool removes), frames the

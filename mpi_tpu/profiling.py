@@ -1,77 +1,17 @@
-"""Profiling / observability helpers (SURVEY.md §5: tracing row).
+"""Per-op communication counters (SURVEY.md §5: tracing row).
 
-* :func:`trace` — context manager around ``jax.profiler`` emitting a
-  Perfetto/XProf trace directory for the enclosed collectives.
-* :func:`timeit` — robust wall-clock timing of a jax callable
-  (``block_until_ready`` fencing, warmup, median/percentiles) — the
-  measurement core shared by bench.py and benchmarks/osu.py conventions.
-* :class:`CommStats` — per-op counters (counts + bytes).  Since ISSUE
-  13 this is no longer dead API waiting for a wrapper that never came:
-  the flight recorder (mpi_tpu/telemetry) fills one per traced run —
-  every traced collective records (op, payload bytes) — and
-  :func:`comm_stats` returns it.
+:class:`CommStats` counts collectives and their payload bytes by op; the
+flight recorder (mpi_tpu/telemetry) fills one per traced run — every
+traced collective records (op, payload bytes) — and :func:`comm_stats`
+returns it.  Traces come from the recorder and, for the TPU path, from
+``jax.profiler`` itself (``telemetry.span`` writes into its trace).
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
-import statistics
-import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Profile the enclosed block with jax.profiler (XProf/Perfetto trace in
-    ``log_dir``); works on TPU and CPU."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@dataclass
-class Timing:
-    p50_s: float
-    p10_s: float
-    p90_s: float
-    n: int
-
-    @property
-    def p50_us(self) -> float:
-        return self.p50_s * 1e6
-
-
-def timeit(fn: Callable[[], Any], iters: int = 50, warmup: int = 5) -> Timing:
-    """Median wall-clock of ``fn()`` with device-fence per call: any returned
-    jax arrays are blocked on, so async dispatch doesn't fake the numbers."""
-    import jax
-
-    def call():
-        out = fn()
-        jax.block_until_ready(out)
-        return out
-
-    for _ in range(warmup):
-        call()
-    samples: List[float] = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        call()
-        samples.append(time.perf_counter() - t0)
-    samples.sort()
-    n = len(samples)
-    return Timing(
-        p50_s=statistics.median(samples),
-        p10_s=samples[round(0.1 * (n - 1))],
-        p90_s=samples[round(0.9 * (n - 1))],
-        n=n,
-    )
+from typing import Dict
 
 
 @dataclass

@@ -27,19 +27,27 @@ Enablement mirrors verify/progress exactly: ``MPI_TPU_TRACE=1`` (init),
 ``None`` and every instrumented seam is one attribute test — zero
 events (``trace_events`` pvar), unchanged wire accounting
 (``bench.py --verify-overhead --trace`` asserts it).
+
+The TPU layer's host spans (:func:`span`: ``mpi_tpu.run``'s ``run``,
+``trace``, ``lower``, ``compile``) are recorded as kind ``"tpu"`` and,
+while a JAX profiler session records, also written into its trace as
+``TraceAnnotation("mpi_tpu.<name>")``.  A profiler session alone turns
+them on: the first span then starts the recorder.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
+import time
 from typing import Optional
 
 from .recorder import Recorder, WAIT_MIN_NS
 
 __all__ = [
     "Recorder", "REC", "WAIT_MIN_NS", "enable", "disable", "enabled",
-    "recorder", "export_chrome", "env_enabled", "env_trace_dir",
+    "recorder", "export_chrome", "env_enabled", "env_trace_dir", "span",
 ]
 
 # THE off-mode gate: every instrumentation seam in the library reads
@@ -119,3 +127,75 @@ def enable_from_env(rank: Optional[int] = None) -> Optional[Recorder]:
 
         atexit.register(rec.export_to_dir)
     return rec
+
+
+# -- host spans of the TPU layer ---------------------------------------------
+
+_OFF = contextlib.nullcontext()  # the shared no-op span
+
+
+def _never() -> bool:
+    return False
+
+
+def _bind_profiler() -> bool:
+    """The first check binds :func:`_profiler_recording` to the flag JAX's
+    own ``TraceAnnotation`` reads (jaxlib's ``TraceMe.is_enabled``), so
+    every later check is one C call and this module never imports JAX on
+    its own.  Where the installed jaxlib lacks it, only the recorder
+    switch gates."""
+    global _profiler_recording
+    try:
+        from jax._src.lib import _profiler
+
+        _profiler_recording = _profiler.TraceMe.is_enabled
+    except (ImportError, AttributeError):
+        _profiler_recording = _never
+    return _profiler_recording()
+
+
+_profiler_recording = _bind_profiler
+
+
+class _Span:
+    """One ``"tpu"`` recorder event from enter to exit, inside a
+    ``TraceAnnotation`` while a profiler session records."""
+
+    __slots__ = ("name", "attrs", "t0", "annotation")
+
+    def __init__(self, name: str, attrs: dict) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.annotation = None
+
+    def __enter__(self) -> "_Span":
+        if _profiler_recording():
+            from jax.profiler import TraceAnnotation
+
+            self.annotation = TraceAnnotation("mpi_tpu." + self.name)
+            self.annotation.__enter__()
+        self.t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur = time.time_ns() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        (REC or enable()).emit("tpu", self.name, dur_ns=dur,
+                               attrs=self.attrs or None, start_ns=self.t0)
+
+
+def span(name: str, **attrs):
+    """Context manager: a host span of the TPU layer, named ``name``.
+
+    Off (no recorder and no JAX profiler session recording) it returns
+    one shared no-op context.  On, the span is recorded as a ``"tpu"``
+    event carrying ``attrs`` and, while a profiler session records, is
+    also the ``TraceAnnotation("mpi_tpu.<name>")`` of that trace, so it
+    lies on the host plane beside the device's ops.  A profiler session
+    with no recorder starts one (:func:`enable`), as ``MPI_TPU_TRACE=1``
+    would.  Timestamps are ``time.time_ns()``, the profiler trace's
+    clock."""
+    if REC is None and not _profiler_recording():
+        return _OFF
+    return _Span(name, attrs)

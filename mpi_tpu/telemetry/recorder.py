@@ -9,9 +9,13 @@ mechanically by the ``trace_events`` pvar staying 0 and the
 
 An event is one tuple ``(t_ns, dur_ns, kind, name, tid, attrs)``:
 
-* ``t_ns`` — ``time.perf_counter_ns()`` at emit (monotonic; the
-  recorder stores a (wall, mono) anchor pair taken at enable so export
-  maps every event onto the wall clock);
+* ``t_ns`` — ``time.time_ns()`` at emit, or at a span's start where
+  the caller stamped it: the clock on which a JAX
+  profiler trace's events lie (its session's ``profile_start_time``
+  plus an event's offset), so the TPU layer's spans can be placed
+  beside the device's ops and idle gaps.  The (wall, mono) anchor pair
+  that export and ``tools/tracecat.py`` read is taken from that clock
+  too, and maps every event onto itself;
 * ``dur_ns`` — 0 for instant events, the span length for completed
   spans (collective begin/end, link heal, lease job, blocked wait);
 * ``kind``/``name`` — the event class and the specific event
@@ -52,7 +56,8 @@ _DEFAULT_CAPACITY = int(os.environ.get("MPI_TPU_TRACE_EVENTS", "65536"))
 # Span kinds the Chrome export renders as complete ("X") events; every
 # other kind is an instant.  A kind may still emit dur=0 spans (a
 # sub-microsecond collective) — they render fine.
-_SPAN_KINDS = frozenset({"coll", "wait", "link", "lease", "sm", "heal"})
+_SPAN_KINDS = frozenset({"coll", "wait", "link", "lease", "sm", "heal",
+                         "tpu"})
 
 # Blocked-wait spans below this duration are NOT recorded: a healthy
 # recv that hit its message on the first slice would otherwise emit one
@@ -72,12 +77,12 @@ class Recorder:
         self.rank = rank
         self.trace_dir = trace_dir
         self.pid = os.getpid()
-        # the clock anchor pair: every event timestamp is monotonic;
-        # export maps mono -> wall through this pair, so single-host
-        # multi-process traces land on one shared timeline (refined
-        # further by tracecat's message-matching offset estimation)
-        self.wall_anchor_ns = time.time_ns()
-        self.mono_anchor_ns = time.perf_counter_ns()
+        # the clock anchor pair: export maps event time -> wall through
+        # it, so single-host multi-process traces land on one shared
+        # timeline (refined further by tracecat's message-matching offset
+        # estimation).  Events are stamped on the wall clock itself, the
+        # profiler's, so both anchors are one reading of it.
+        self.wall_anchor_ns = self.mono_anchor_ns = time.time_ns()
         self._buf: List[Optional[tuple]] = [None] * self.capacity
         self._n = 0  # total events ever emitted (ring index = n % cap)
         self._lock = threading.Lock()
@@ -90,8 +95,13 @@ class Recorder:
     # -- emission ----------------------------------------------------------
 
     def emit(self, kind: str, name: str, dur_ns: int = 0,
-             attrs: Optional[dict] = None) -> None:
-        evt = (time.perf_counter_ns() - dur_ns, dur_ns, kind, name,
+             attrs: Optional[dict] = None,
+             start_ns: Optional[int] = None) -> None:
+        """Record one event; a span's start is ``start_ns`` where the
+        caller stamped it, else now less ``dur_ns``."""
+        if start_ns is None:
+            start_ns = time.time_ns() - dur_ns
+        evt = (start_ns, dur_ns, kind, name,
                threading.get_ident(), attrs)
         with self._lock:
             self._buf[self._n % self.capacity] = evt
@@ -108,7 +118,7 @@ class Recorder:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
-        cell = [name, algorithm, nbytes, time.perf_counter_ns()]
+        cell = [name, algorithm, nbytes, time.time_ns()]
         stack.append(cell)
         return cell
 
@@ -125,7 +135,7 @@ class Recorder:
         if stack and stack[-1] is cell:
             stack.pop()
         name, algorithm, nbytes, t0 = cell
-        dur = time.perf_counter_ns() - t0
+        dur = time.time_ns() - t0
         attrs: Dict[str, Any] = {}
         if algorithm is not None:
             attrs["algorithm"] = algorithm

@@ -64,6 +64,13 @@ def _pallas_op_name(op: _ops.ReduceOp) -> str:
         f"reductions")
 
 
+def _scope(op: str, algorithm: str):
+    """The name a collective's ops carry in the program's metadata and in a
+    device trace: ``mpi_tpu.<op>.<algorithm>``, with the resolved
+    algorithm."""
+    return jax.named_scope(f"mpi_tpu.{op}.{algorithm}")
+
+
 class SpmdSemanticsError(NotImplementedError):
     """An MPI idiom with no SPMD analogue was used on the TPU backend."""
 
@@ -260,16 +267,18 @@ class TpuCommunicator(Communicator):
                 "backends agree"
             )
         x = jnp.asarray(obj)
-        p = self.size
-        pairs = self._world_pairs(schedules.ring_perm(p, offset, wrap=wrap))
-        recvd = lax.ppermute(x, self.axis_name, pairs)
-        if not wrap and fill is not None:
-            receivers = [r for r in range(p) if 0 <= r - offset < p]
-            has_src = algos._mask_of(
-                [g[r] for g in (self._groups or [list(range(p))]) for r in receivers],
-                self._axis_size, self.axis_name)
-            recvd = jnp.where(has_src, recvd, jnp.full_like(recvd, fill))
-        return recvd
+        with _scope("shift", "ppermute"):
+            p = self.size
+            pairs = self._world_pairs(schedules.ring_perm(p, offset, wrap=wrap))
+            recvd = lax.ppermute(x, self.axis_name, pairs)
+            if not wrap and fill is not None:
+                receivers = [r for r in range(p) if 0 <= r - offset < p]
+                groups = self._groups or [list(range(p))]
+                has_src = algos._mask_of(
+                    [g[r] for g in groups for r in receivers],
+                    self._axis_size, self.axis_name)
+                recvd = jnp.where(has_src, recvd, jnp.full_like(recvd, fill))
+            return recvd
 
     def localize(self, obj):
         """Brand a (replicated) value as rank-varying over this comm's axis.
@@ -316,13 +325,14 @@ class TpuCommunicator(Communicator):
         spelling of a set of matched MPI_Send/MPI_Recv calls; ranks not
         receiving get zeros (or ``fill`` when given)."""
         x = jnp.asarray(obj)
-        world = self._world_pairs(pairs)
-        out = lax.ppermute(x, self.axis_name, world)
-        if fill is not None:
-            receivers = [d for _, d in world]
-            has_src = algos._mask_of(receivers, self._axis_size, self.axis_name)
-            out = jnp.where(has_src, out, jnp.full_like(out, fill))
-        return out
+        with _scope("exchange", "ppermute"):
+            world = self._world_pairs(pairs)
+            out = lax.ppermute(x, self.axis_name, world)
+            if fill is not None:
+                receivers = [d for _, d in world]
+                has_src = algos._mask_of(receivers, self._axis_size, self.axis_name)
+                out = jnp.where(has_src, out, jnp.full_like(out, fill))
+            return out
 
     # -- nonblocking collectives -------------------------------------------
     # In one traced SPMD program, "nonblocking" IS the compiler's job: XLA
@@ -371,21 +381,23 @@ class TpuCommunicator(Communicator):
         x = jnp.asarray(obj)
         if algorithm == "auto":
             algorithm = "fused"
-        if self.size == 1:
-            return self._degenerate(x)
-        if algorithm == "fused":
-            # masked psum: transfers one payload-sized reduction instead of
-            # materializing P gathered copies per device
-            if x.dtype == jnp.bool_:
-                return self.bcast(x.astype(jnp.uint8), root, "fused").astype(jnp.bool_)
-            masked = jnp.where(self.rank == root, x, jnp.zeros_like(x))
-            if self._groups is None:
-                return lax.psum(masked, self.axis_name)
-            return self._grouped_psum(masked)
-        if algorithm == "tree":
-            return algos.tree_bcast(x, self.axis_name, self.size, self.rank,
-                                    self._world_pairs, self._axis_size, root)
-        raise ValueError(f"unknown bcast algorithm {algorithm!r}")
+        with _scope("bcast", algorithm):
+            if self.size == 1:
+                return self._degenerate(x)
+            if algorithm == "fused":
+                # masked psum: transfers one payload-sized reduction instead of
+                # materializing P gathered copies per device
+                if x.dtype == jnp.bool_:
+                    return self.bcast(x.astype(jnp.uint8), root,
+                                      "fused").astype(jnp.bool_)
+                masked = jnp.where(self.rank == root, x, jnp.zeros_like(x))
+                if self._groups is None:
+                    return lax.psum(masked, self.axis_name)
+                return self._grouped_psum(masked)
+            if algorithm == "tree":
+                return algos.tree_bcast(x, self.axis_name, self.size, self.rank,
+                                        self._world_pairs, self._axis_size, root)
+            raise ValueError(f"unknown bcast algorithm {algorithm!r}")
 
     def reduce(self, obj, op: _ops.ReduceOp = _ops.SUM, root: int = 0,
                algorithm: str = "auto"):
@@ -395,16 +407,17 @@ class TpuCommunicator(Communicator):
         x = jnp.asarray(obj)
         if algorithm == "auto":
             algorithm = "tree"
-        if self.size == 1:
-            return self._degenerate(x)
-        if algorithm == "fused":
-            full = self.allreduce(x, op, algorithm="fused")
-            ident = jnp.full(x.shape, op.identity(np.dtype(x.dtype)), x.dtype)
-            return jnp.where(self.rank == root, full, ident)
-        if algorithm == "tree":
-            return algos.tree_reduce(x, self.axis_name, self.size, self.rank,
-                                     self._world_pairs, self._axis_size, op, root)
-        raise ValueError(f"unknown reduce algorithm {algorithm!r}")
+        with _scope("reduce", algorithm):
+            if self.size == 1:
+                return self._degenerate(x)
+            if algorithm == "fused":
+                full = self.allreduce(x, op, algorithm="fused")
+                ident = jnp.full(x.shape, op.identity(np.dtype(x.dtype)), x.dtype)
+                return jnp.where(self.rank == root, full, ident)
+            if algorithm == "tree":
+                return algos.tree_reduce(x, self.axis_name, self.size, self.rank,
+                                         self._world_pairs, self._axis_size, op, root)
+            raise ValueError(f"unknown reduce algorithm {algorithm!r}")
 
     def allreduce(self, obj, op: _ops.ReduceOp = _ops.SUM, algorithm: str = "auto"):
         """``algorithm='auto'`` resolves to 'fused' at every size: on the
@@ -420,28 +433,29 @@ class TpuCommunicator(Communicator):
         x = jnp.asarray(obj)
         if algorithm == "auto":
             algorithm = "fused"
-        if self.size == 1:
-            return self._degenerate(x)
-        if algorithm == "fused":
-            return self._fused_allreduce(x, op)
-        if algorithm == "ring":
-            return algos.ring_allreduce(x, self.axis_name, self.size, self.rank,
-                                        self._world_pairs, op)
-        if algorithm == "pallas_ring":
-            # in-kernel pipelined RDMA ring (mpi_tpu/tpu/pallas_ring.py):
-            # f32/bf16 sum/max/min; split comms run one ring per group
-            from .pallas_ring import pallas_ring_allreduce
+        with _scope("allreduce", algorithm):
+            if self.size == 1:
+                return self._degenerate(x)
+            if algorithm == "fused":
+                return self._fused_allreduce(x, op)
+            if algorithm == "ring":
+                return algos.ring_allreduce(x, self.axis_name, self.size, self.rank,
+                                            self._world_pairs, op)
+            if algorithm == "pallas_ring":
+                # in-kernel pipelined RDMA ring (mpi_tpu/tpu/pallas_ring.py):
+                # f32/bf16 sum/max/min; split comms run one ring per group
+                from .pallas_ring import pallas_ring_allreduce
 
-            return pallas_ring_allreduce(x, self.axis_name, self.size,
-                                         interpret=self._pallas_interp,
-                                         groups=self._groups,
-                                         op=_pallas_op_name(op))
-        if algorithm == "recursive_halving":
-            return algos.halving_allreduce(x, self.axis_name, self.size, self.rank,
-                                           self._world_pairs, op)
-        if algorithm == "reduce_bcast":
-            return self.bcast(self.reduce(x, op, 0, "tree"), 0, "tree")
-        raise ValueError(f"unknown allreduce algorithm {algorithm!r}")
+                return pallas_ring_allreduce(x, self.axis_name, self.size,
+                                             interpret=self._pallas_interp,
+                                             groups=self._groups,
+                                             op=_pallas_op_name(op))
+            if algorithm == "recursive_halving":
+                return algos.halving_allreduce(x, self.axis_name, self.size, self.rank,
+                                               self._world_pairs, op)
+            if algorithm == "reduce_bcast":
+                return self.bcast(self.reduce(x, op, 0, "tree"), 0, "tree")
+            raise ValueError(f"unknown allreduce algorithm {algorithm!r}")
 
     def _degenerate(self, x):
         """Size-1 communicator: the value is its own reduction, but a real
@@ -497,23 +511,24 @@ class TpuCommunicator(Communicator):
         x = jnp.asarray(obj)
         if algorithm == "auto":
             algorithm = "fused"
-        if algorithm == "fused":
-            return self._fused_allgather(x)
-        if algorithm == "ring":
-            return algos.ring_allgather(x, self.axis_name, self.size, self.rank,
-                                        self._world_pairs)
-        if algorithm == "doubling":
-            return algos.doubling_allgather(x, self.axis_name, self.size, self.rank,
+        with _scope("allgather", algorithm):
+            if algorithm == "fused":
+                return self._fused_allgather(x)
+            if algorithm == "ring":
+                return algos.ring_allgather(x, self.axis_name, self.size, self.rank,
                                             self._world_pairs)
-        if algorithm == "pallas_ring":
-            # allgather-only mode of the in-kernel RDMA ring: P-1 pipelined
-            # land-direct steps (mpi_tpu/tpu/pallas_ring.py)
-            from .pallas_ring import pallas_ring_allgather
+            if algorithm == "doubling":
+                return algos.doubling_allgather(x, self.axis_name, self.size, self.rank,
+                                                self._world_pairs)
+            if algorithm == "pallas_ring":
+                # allgather-only mode of the in-kernel RDMA ring: P-1 pipelined
+                # land-direct steps (mpi_tpu/tpu/pallas_ring.py)
+                from .pallas_ring import pallas_ring_allgather
 
-            return pallas_ring_allgather(x, self.axis_name, self.size,
-                                         interpret=self._pallas_interp,
-                                         groups=self._groups)
-        raise ValueError(f"unknown allgather algorithm {algorithm!r}")
+                return pallas_ring_allgather(x, self.axis_name, self.size,
+                                             interpret=self._pallas_interp,
+                                             groups=self._groups)
+            raise ValueError(f"unknown allgather algorithm {algorithm!r}")
 
     def alltoall(self, objs, algorithm: str = "auto"):
         """``objs``: stacked [size, ...] array, block i destined for group
@@ -527,15 +542,16 @@ class TpuCommunicator(Communicator):
             )
         if algorithm == "auto":
             algorithm = "fused"
-        if self.size == 1:
-            return x
-        if algorithm == "fused":
-            return lax.all_to_all(x, self.axis_name, split_axis=0, concat_axis=0,
-                                  axis_index_groups=self._groups, tiled=False)
-        if algorithm == "pairwise":
-            return algos.pairwise_alltoall(x, self.axis_name, self.size, self.rank,
-                                           self._world_pairs)
-        raise ValueError(f"unknown alltoall algorithm {algorithm!r}")
+        with _scope("alltoall", algorithm):
+            if self.size == 1:
+                return x
+            if algorithm == "fused":
+                return lax.all_to_all(x, self.axis_name, split_axis=0, concat_axis=0,
+                                      axis_index_groups=self._groups, tiled=False)
+            if algorithm == "pairwise":
+                return algos.pairwise_alltoall(x, self.axis_name, self.size, self.rank,
+                                               self._world_pairs)
+            raise ValueError(f"unknown alltoall algorithm {algorithm!r}")
 
     def barrier(self) -> None:
         """SPMD programs are globally scheduled; emit a tiny psum as an
@@ -581,28 +597,29 @@ class TpuCommunicator(Communicator):
                 f"size ({self.size}), got {x.shape}")
         if algorithm == "auto":
             algorithm = "fused"
-        if self.size == 1:
-            return self._degenerate(x[0])
-        if algorithm == "fused":
-            if op.name == "sum":
-                return lax.psum_scatter(x, self.axis_name, scatter_dimension=0,
-                                        axis_index_groups=self._groups,
-                                        tiled=False)
-            # non-SUM: reduce locally after a fused alltoall of blocks
-            return algos.tree_reduce_local(op, self.alltoall(x, "fused"))
-        if algorithm == "ring":
-            return algos.ring_reduce_scatter(x, self.axis_name, self.size,
-                                             self.rank, self._world_pairs, op)
-        if algorithm == "pallas_ring":
-            # in-kernel RDMA ring, reduce-scatter half only (the ZeRO
-            # gradient-sharding primitive at half the allreduce traffic)
-            from .pallas_ring import pallas_ring_reduce_scatter
+        with _scope("reduce_scatter", algorithm):
+            if self.size == 1:
+                return self._degenerate(x[0])
+            if algorithm == "fused":
+                if op.name == "sum":
+                    return lax.psum_scatter(x, self.axis_name, scatter_dimension=0,
+                                            axis_index_groups=self._groups,
+                                            tiled=False)
+                # non-SUM: reduce locally after a fused alltoall of blocks
+                return algos.tree_reduce_local(op, self.alltoall(x, "fused"))
+            if algorithm == "ring":
+                return algos.ring_reduce_scatter(x, self.axis_name, self.size,
+                                                 self.rank, self._world_pairs, op)
+            if algorithm == "pallas_ring":
+                # in-kernel RDMA ring, reduce-scatter half only (the ZeRO
+                # gradient-sharding primitive at half the allreduce traffic)
+                from .pallas_ring import pallas_ring_reduce_scatter
 
-            return pallas_ring_reduce_scatter(x, self.axis_name, self.size,
-                                              interpret=self._pallas_interp,
-                                              groups=self._groups,
-                                              op=_pallas_op_name(op))
-        raise ValueError(f"unknown reduce_scatter algorithm {algorithm!r}")
+                return pallas_ring_reduce_scatter(x, self.axis_name, self.size,
+                                                  interpret=self._pallas_interp,
+                                                  groups=self._groups,
+                                                  op=_pallas_op_name(op))
+            raise ValueError(f"unknown reduce_scatter algorithm {algorithm!r}")
 
     def scatter(self, objs, root: int = 0):
         """``objs``: stacked [size, ...] meaningful at root; every rank gets

@@ -1045,8 +1045,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vma = (frozenset(jax.typeof(q).vma) | {axis_name} if vma_on
            else frozenset())
 
-    def _hbm_call(kern, outs, scratch, collective_id, params, *args):
-        """One ring-kernel launch: ``params`` in SMEM, ``args`` in HBM.
+    def _hbm_call(name, kern, outs, scratch, collective_id, params, *args):
+        """One ring-kernel launch, named ``name`` in the program and in a
+        device trace: ``params`` in SMEM, ``args`` in HBM.
         ``outs`` = (shape, dtype) pairs, all in HBM: the results first,
         then the kernel's HBM working buffers (landing slots, tiled
         state).  Mosaic allocates scratch only in VMEM/SMEM, so those
@@ -1064,6 +1065,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 collective_id=collective_id, has_side_effects=True,
                 vmem_limit_bytes=vmem_limit_bytes),
             interpret=interpret,
+            name=name,
         )(params, *args)
 
     def _kernel_call(q_, k_, v_, with_lse):
@@ -1118,7 +1120,8 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        res = _hbm_call(kern, outs, scratch, 16, params, qf, kv)
+        res = _hbm_call("mpi_tpu_ring_attention_fwd", kern, outs, scratch,
+                        16, params, qf, kv)
         out = res[0].astype(q_.dtype)
         out = out.reshape(hq, sb, d) if multihead else out
         return (out, res[1]) if with_lse else out
@@ -1180,8 +1183,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        dq, dkv = _hbm_call(kern, outs, scratch, 17,
-                            params, qf, kv, dof, lse, delta)[:2]
+        dq, dkv = _hbm_call("mpi_tpu_ring_attention_bwd", kern, outs,
+                            scratch, 17, params, qf, kv, dof, lse,
+                            delta)[:2]
         dq = dq.astype(q_.dtype)
         dk = dkv[:hkv * sb].astype(k_.dtype)
         dv = dkv[hkv * sb:].astype(v_.dtype)

@@ -482,6 +482,9 @@ def _launch(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
     vma = (frozenset(jax.typeof(grid_in).vma) | {axis_name}
            if vma_on else frozenset())
     params = _ring_params(axis_name, size, groups)
+    # the kernel's name in the program and in a device trace
+    mode = ("allreduce" if allgather else "reduce_scatter") if rs else \
+        "allgather"
     # The RDMA landing zone lives in HBM, which Mosaic cannot allocate as
     # scratch: it is a second output, dropped after the call.  Unused
     # (1-row stub) in the ag-only mode, where RDMAs land in the output.
@@ -506,6 +509,7 @@ def _launch(x: jnp.ndarray, axis_name: str, size: int, tile_rows: int,
         ],
         compiler_params=compiler_params,
         interpret=interpret,
+        name=f"mpi_tpu_ring_{mode}",
     )(params, grid_in)
     return out
 

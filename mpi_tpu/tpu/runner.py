@@ -10,6 +10,8 @@ come back stacked on a leading axis (rank order), mirroring
 
 from __future__ import annotations
 
+import functools
+import itertools
 import os
 from typing import Any, Callable, Optional
 
@@ -19,7 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import mpit, telemetry
 from .communicator import TpuCommunicator
+
+# per-process sequence number of run_spmd calls: a ``run`` span's
+# ``seq``, and its stage spans' ``parent``
+_RUN_SEQ = itertools.count(1)
 
 
 def default_mesh(nranks: Optional[int] = None, axis_name: str = "world") -> Mesh:
@@ -61,6 +68,13 @@ def enable_compile_cache() -> str:
     return path
 
 
+@functools.cache
+def _trace_from_env() -> None:
+    """``MPI_TPU_TRACE=1`` starts the flight recorder, read at the first
+    call, as ``init()`` reads it for the process backends."""
+    telemetry.enable_from_env()
+
+
 def run_spmd(
     fn: Callable,
     *args: Any,
@@ -80,18 +94,41 @@ def run_spmd(
     ``check_vma=False`` disables shard_map's varying-axes typing.  Every
     algorithm, including ``'pallas_ring'``, now works with the checker ON
     (the kernel declares its result varying; see pallas_ring docstrings) —
-    the flag remains for users who want the typing overhead gone."""
+    the flag remains for users who want the typing overhead gone.
+
+    With ``jit=True`` the program is traced, lowered and compiled (or
+    loaded from the persistent cache) as explicit stages, then the
+    executable is called.  With tracing on (``telemetry.span``) the call
+    is a ``run`` span (``seq``, ``nranks``) and each stage a child span
+    (``trace``, ``lower``, ``compile``) carrying ``parent=seq``; the
+    dispatch, and the release of the program after it, are the ``run``
+    span's self time.  The ``tpu_program_traces``
+    pvar counts the program's traces, whether tracing is on or not."""
+    _trace_from_env()
     if mesh is None:
         mesh = default_mesh(nranks, axis_name)
     comm = TpuCommunicator(axis_name, mesh)
 
     def shard_fn(*a):
+        mpit.count(tpu_program_traces=1)  # runs once per trace, not per call
         res = fn(comm, *a, **kwargs)
         return jax.tree.map(lambda r: jnp.asarray(r)[None], res)
 
     in_specs = tuple(P() for _ in args)
     f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                       out_specs=P(axis_name), check_vma=check_vma)
-    if jit:
-        f = jax.jit(f)
-    return f(*args)
+    seq = next(_RUN_SEQ)
+    with telemetry.span("run", seq=seq, nranks=comm.size):
+        if not jit:
+            return f(*args)
+        with telemetry.span("trace", parent=seq):
+            traced = jax.jit(f).trace(*args)
+        with telemetry.span("lower", parent=seq):
+            lowered = traced.lower()
+        with telemetry.span("compile", parent=seq):
+            compiled = lowered.compile()
+        out = compiled(*args)
+        # the one-shot program is freed (its executable unloaded) here,
+        # inside the span, and not after it where no span would see it
+        del f, traced, lowered, compiled
+    return out

@@ -22,6 +22,7 @@ Four contracts:
   test) and every ``progress_*`` pvar stays exactly 0.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -406,6 +407,13 @@ def test_off_mode_zero_wakeups_and_single_attribute():
     """progress=none: no engine object anywhere (the hot paths' one
     attribute test reads None) and every progress pvar stays 0 across
     real traffic."""
+    # an earlier test's stopped engine may still be in its last park,
+    # which would count an idle park here: let those threads finish
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline and any(
+            t.name.startswith("mpi-tpu-progress-")
+            for t in threading.enumerate()):
+        time.sleep(0.05)
     ses = mpit.session_create()
     ses.reset_all()
 

@@ -476,17 +476,22 @@ def test_epoch_grace_tolerates_laggy_catchup_only(catches_up):
     straggler (epoch never catches up) still raises EpochSkewError."""
     old = mpit.cvar_read("epoch_grace_s")
     mpit.cvar_write("epoch_grace_s", 1.5 if catches_up else 0.3)
+    # rank 0 connects only once rank 1 is ahead: under load its first
+    # send could otherwise handshake while both are still at epoch 0
+    ahead = threading.Event()
     try:
         def prog(comm):
             t = comm._t
             if comm.rank == 1:
                 t.epoch = 1  # already transitioned
+                ahead.set()
                 if catches_up:
                     comm.recv(source=0, tag=3)
                 else:
                     time.sleep(2.5)  # outlive the connector's grace
                 return True
             # rank 0 lags: its own bump lands mid-grace (or never)
+            assert ahead.wait(10)
             if catches_up:
                 def bump():
                     time.sleep(0.4)

@@ -84,7 +84,11 @@ def test_pallas_ring_allreduce_256mib_compiles(mesh4, bidirectional):
         def fn(v):
             return pr.pallas_ring_allreduce(v, "world", 4,
                                             bidirectional=False)
-    assert "tpu_custom_call" in _compile(_smap(mesh4, fn), x).as_text()
+    text = _compile(_smap(mesh4, fn), x).as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name reaches the compiled program, as a device trace
+    # names its ops
+    assert "%mpi_tpu_ring_allreduce" in text
 
 
 @pytest.mark.parametrize("bidirectional", [True, False])
@@ -92,7 +96,7 @@ def test_pallas_ring_allgather_64mib_compiles(mesh4, bidirectional):
     _, x = _rows(mesh4, 64 * MIB)
     compiled = _compile(_smap(mesh4, lambda v: pr.pallas_ring_allgather(
         v, "world", 4, bidirectional=bidirectional)[None]), x)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "%mpi_tpu_ring_allgather" in compiled.as_text()
 
 
 @pytest.mark.parametrize("bidirectional", [True, False])
@@ -101,7 +105,7 @@ def test_pallas_ring_reduce_scatter_64mib_compiles(mesh4, bidirectional):
     compiled = _compile(_smap(mesh4, lambda v: pr.pallas_ring_reduce_scatter(
         v.reshape(4, rows // 4, 128), "world", 4,
         bidirectional=bidirectional)), x)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "%mpi_tpu_ring_reduce_scatter" in compiled.as_text()
 
 
 # (plan, Sb per chip, vmem_limit_bytes): the resident plan at Sb 2048
@@ -135,7 +139,12 @@ def test_ring_attention_compiles(mesh4, plan, backward):
         fn, out_specs = attn, spec
     compiled = _compile(jax.shard_map(fn, mesh=mesh4, in_specs=(spec,) * 3,
                                       out_specs=out_specs), q, q, q)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # under autodiff the instruction names carry the transforms too
+    # (``%jvp_mpi_tpu_ring_attention_fwd_.1``, ``%transpose_jvp_..._bwd``)
+    assert "mpi_tpu_ring_attention_fwd" in text
+    assert ("mpi_tpu_ring_attention_bwd" in text) == backward
 
 
 def test_jacobi_step_8192_compiles_for_one_chip(topo):

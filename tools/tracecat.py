@@ -8,10 +8,10 @@ tool merges them so a 3-rank run renders as one Perfetto timeline —
 rank per process row, thread per track — with **cross-rank clock
 alignment** in two layers:
 
-1. **Wall anchor** (coarse): every trace carries a ``(time_ns,
-   perf_counter_ns)`` anchor pair taken at enable; export already maps
-   monotonic timestamps onto the wall clock, which is shared on a
-   single host up to the anchor-read jitter.
+1. **Wall anchor** (coarse): every trace carries a wall/event-clock
+   anchor pair taken at enable; export already maps event timestamps
+   onto the wall clock (events are stamped with ``time.time_ns()``, so
+   the map is the identity), which is shared on a single host.
 2. **Message matching** (fine, ``--no-align`` disables): the sequenced
    socket frames are recorded on BOTH ends (``frame send`` carries
    (dest, seq), ``frame recv`` carries (src, seq) — the resilient
