@@ -43,6 +43,7 @@ class _Counters:
                  "pallas_fallbacks", "bytes_raw", "bytes_pickled", "copies",
                  "proc_failed", "revokes", "shrinks",
                  "faulty_dropped", "faulty_duplicated", "attention_oob",
+                 "attention_zz",
                  "sm_hits", "sm_bytes", "sm_fallbacks",
                  "v_deadlocks", "v_mismatches", "v_leaked", "v_double_waits",
                  "v_buf_overlaps", "v_comms_unfreed",
@@ -75,6 +76,7 @@ class _Counters:
         self.faulty_dropped = 0
         self.faulty_duplicated = 0
         self.attention_oob = 0
+        self.attention_zz = 0
         self.sm_hits = 0
         self.sm_bytes = 0
         self.sm_fallbacks = 0
@@ -129,7 +131,8 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           bytes_raw: int = 0, bytes_pickled: int = 0, copies: int = 0,
           proc_failed: int = 0, revokes: int = 0, shrinks: int = 0,
           faulty_dropped: int = 0, faulty_duplicated: int = 0,
-          attention_oob: int = 0, coll_sm_hits: int = 0,
+          attention_oob: int = 0, attention_zigzag: int = 0,
+          coll_sm_hits: int = 0,
           coll_sm_bytes: int = 0, coll_sm_fallbacks: int = 0,
           verify_deadlocks: int = 0, verify_mismatches: int = 0,
           verify_requests_leaked: int = 0, verify_double_waits: int = 0,
@@ -185,6 +188,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.faulty_dropped += faulty_dropped
         counters.faulty_duplicated += faulty_duplicated
         counters.attention_oob += attention_oob
+        counters.attention_zz += attention_zigzag
         counters.sm_hits += coll_sm_hits
         counters.sm_bytes += coll_sm_bytes
         counters.sm_fallbacks += coll_sm_fallbacks
@@ -266,6 +270,10 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # tile fit the VMEM budget (tpu/pallas_attention.py — graceful
     # degradation instead of NotImplementedError; ROADMAP r5 #4)
     "attention_fallbacks": lambda: counters.attention_oob,
+    # causal ring-attention traces that took the zigzag layout (balanced
+    # causal work across the ring; tpu/pallas_attention.py): says
+    # whether the mechanism engaged
+    "attention_zigzag": lambda: counters.attention_zz,
     # shared-memory collective arena (mpi_tpu/coll_sm.py): collectives
     # served entirely by arena load/store (zero ring frames), per-rank
     # payload bytes moved through it, and eligible requests that fell

@@ -48,7 +48,9 @@ VMEM budget:
   [tk]-row K/V tiles (``lax.fori_loop``), staging each tile through
   small VMEM buffers, so the live score block is [tq, tk] and the
   block size is bounded by HBM, not VMEM.  Tile sizes are the largest
-  sublane-aligned divisors of Sb, at most 512, that fit the budget.
+  sublane-aligned divisors of Sb, at most 512, that fit the budget (a
+  causal ring takes those that divide Sb/2 first, for the zigzag
+  layout below).
 
 Either way the RDMA circulation (slots, credits, barriers) is
 IDENTICAL — the fold is a local subroutine between protocol events, so
@@ -75,6 +77,20 @@ HBM output; a K/V-tile outer loop carries dK/dV accumulators over a
 Q-tile inner loop), so long-context training stays on the fused
 kernels; only an impossible budget falls back to recomputing through
 the pure-jax ppermute ring (correct at any size).
+
+**Causal layout** (``_zigzag_tiles``): with the blocks contiguous,
+rank r's causal work is its diagonal block plus r past blocks, and
+the ring paces every rank to the last one.  So where causal attention
+runs both folds tiled, with tiles that divide Sb/2, the wrapper
+re-lays the sequence out zigzag: 2P chunks of Sb/2 rows, rank r
+computing on chunk r and chunk 2P-1-r.  Every off-diagonal arrival
+then costs every rank half a block (``attention_zigzag`` mpit pvar
+counts the traces that take it).  Two ppermutes per array move the
+halves before the kernels and back after them, outside the custom
+VJP, so the caller's contiguous blocks and the kernels' circulation
+protocol are unchanged; only the tile ranges each fold visits differ.
+Every other case (full attention, P = 1, the resident fold, the
+fallbacks) keeps the contiguous blocks.
 
 Under the interpreter (CPU tier) RDMAs run serially (start+wait, no
 credits/barriers) — same data path, no overlap; under vma typing or a
@@ -149,10 +165,117 @@ def _causal_mask(my, kv_idx, sb: int, i0=0, j0=0,
     return kj <= qi
 
 
-def _tile_sizes(sb: int, sub: int):
-    """Sublane-aligned divisors of ``sb`` up to ``_MAX_TILE``, descending."""
-    return [t for t in range(min(sb, _MAX_TILE), 0, -1)
-            if t % sub == 0 and sb % t == 0]
+def _tile_sizes(sb: int, sub: int, halves: bool = False):
+    """Sublane-aligned divisors of ``sb`` up to ``_MAX_TILE``, descending;
+    with ``halves``, those that also divide ``sb // 2`` (the zigzag
+    layout's chunk) first."""
+    ts = [t for t in range(min(sb, _MAX_TILE), 0, -1)
+          if t % sub == 0 and sb % t == 0]
+    if halves:
+        ts.sort(key=lambda t: (sb // 2) % t != 0)  # stable: still descending
+    return ts
+
+
+# -- zigzag causal layout ----------------------------------------------------
+#
+# The global sequence is cut into 2P chunks of c = Sb/2 rows; rank r's
+# block holds chunk r (local rows [0, c)) then chunk 2P-1-r (rows
+# [c, 2c)).  Every off-diagonal K/V block then costs every rank the
+# same half block, so no rank waits on the last one's causal work.
+
+
+def _zigzag_pos(rank, row, c: int, size: int):
+    """Global sequence position of local ``row`` (scalar or array) of
+    ``rank``'s zigzag block of ``2*c`` rows."""
+    return jnp.where(row < c, rank * c + row,
+                     (2 * size - 1 - rank) * c + row - c)
+
+
+def _zigzag_mask(my, c: int, size: int, i0, j0, tq: int, tk: int):
+    """[tq,tk] causal mask of my own zigzag block against itself: rows
+    ``i0..`` attend to rows ``j0..`` by global position.  A tile never
+    straddles the two chunks (tile edges divide ``c``), so each tile's
+    position is its first row's plus the iota."""
+    qi = _zigzag_pos(my, i0, c, size) + \
+        lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+    kj = _zigzag_pos(my, j0, c, size) + \
+        lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+    return kj <= qi
+
+
+def _zigzag_tiles(diag: bool, past, nq: int, nk: int, tq: int, tk: int):
+    """Tile ranges one causal arrival folds on the zigzag layout — the
+    loop bounds of both tiled kernels.  ``diag`` (static): the arrival
+    is my own block; otherwise ``past`` (traced or Python bool) says the
+    K/V block's rank is below mine.
+
+    Returns ``(q_lo, k_hi, k_end, q_lo_of)``: the forward folds q tiles
+    ``[q_lo, nq)``, q tile ``i`` against k tiles ``[0, k_hi(i))``; the
+    backward folds k tiles ``[0, k_end)``, k tile ``j`` against q tiles
+    ``[q_lo_of(j), nq)``.  Both visit the same tile pairs:
+
+    * diagonal: every q tile up to its diagonal (masked there);
+    * past rank: every q tile against the low K/V chunk (its high chunk
+      is in my future for both my chunks);
+    * future rank: my high q chunk against every K/V tile (my low chunk
+      precedes both of its chunks).
+    """
+    if diag:
+        return (0, lambda i: (i * tq + tq + tk - 1) // tk, nk,
+                lambda j: (j * tk) // tq)
+    q_lo = jnp.where(past, 0, nq // 2)
+    k_hi = jnp.where(past, nk // 2, nk)
+    return q_lo, lambda i: k_hi, k_hi, lambda j: q_lo
+
+
+def _zigzag_arrival(a: int, my, size: int, sb: int, tq: int, tk: int):
+    """Inside a kernel: ``_zigzag_tiles`` of arrival ``a`` (the K/V block
+    of rank (my - a) mod size), plus ``mask_of(i, j)``, the tile pair's
+    causal mask — only the diagonal arrival a == 0 needs one, every pair
+    another arrival visits is causal."""
+    bounds = _zigzag_tiles(a == 0, lax.rem(my - a + size, size) < my,
+                           sb // tq, sb // tk, tq, tk)
+    if a > 0:
+        return bounds + (lambda i, j: None,)
+    return bounds + (lambda i, j: _zigzag_mask(my, sb // 2, size, i * tq,
+                                               j * tk, tq, tk),)
+
+
+def _zigzag_perms(size: int):
+    """ppermute pairs moving contiguous half blocks to the zigzag layout:
+    rank s's low half is chunk 2s and its high half chunk 2s+1; chunk x
+    belongs to rank x (as its low half) when x < size, else to rank
+    2*size-1-x (as its high half).  Even and odd chunks each form a
+    permutation of the ranks."""
+    dest = lambda x: x if x < size else 2 * size - 1 - x
+    return ([(s, dest(2 * s)) for s in range(size)],
+            [(s, dest(2 * s + 1)) for s in range(size)])
+
+
+def _to_zigzag(x, axis_name: str, size: int):
+    """This rank's contiguous block (rows on axis -2) → its zigzag block.
+    Rank r's low chunk r comes from the even-chunk permutation when r is
+    even, its high chunk 2*size-1-r from the odd one, and the other way
+    round when r is odd."""
+    even, odd = _zigzag_perms(size)
+    lo, hi = jnp.split(x, 2, axis=-2)
+    e = lax.ppermute(lo, axis_name, even)
+    o = lax.ppermute(hi, axis_name, odd)
+    r_even = lax.rem(lax.axis_index(axis_name), 2) == 0
+    return jnp.concatenate([jnp.where(r_even, e, o),
+                            jnp.where(r_even, o, e)], axis=-2)
+
+
+def _from_zigzag(x, axis_name: str, size: int):
+    """Inverse of :func:`_to_zigzag`."""
+    even, odd = _zigzag_perms(size)
+    lo, hi = jnp.split(x, 2, axis=-2)
+    r_even = lax.rem(lax.axis_index(axis_name), 2) == 0
+    e = jnp.where(r_even, lo, hi)  # my even chunk
+    o = jnp.where(r_even, hi, lo)  # my odd chunk
+    inv = lambda pairs: [(d, s) for s, d in pairs]
+    return jnp.concatenate([lax.ppermute(e, axis_name, inv(even)),
+                            lax.ppermute(o, axis_name, inv(odd))], axis=-2)
 
 
 # Shared kernel helpers (one definition serves forward and backward —
@@ -305,6 +428,16 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
     The estimates are deliberately generous (temporaries counted at
     f32, a spare plane for Mosaic's fusions) so a "resident" or
     "tiled" verdict holds on real hardware with headroom."""
+    return _vmem_plan(sb, d, hq, hkv, dtype, vmem_limit_bytes,
+                      for_backward, halves=False)
+
+
+def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
+               vmem_limit_bytes: Optional[int], for_backward: bool,
+               halves: bool):
+    """:func:`attention_vmem_plan`; ``halves`` prefers tiles that divide
+    ``sb // 2`` (``_tile_sizes``), as the causal ring's zigzag layout
+    needs — the same verdict, a smaller tile at most."""
     from .pallas_ring import _SUBLANES
 
     esz = jnp.dtype(dtype).itemsize
@@ -321,7 +454,7 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                     + 2 * sb * d * 4)          # fold temporaries
         if resident <= limit:
             return ("resident", None)
-        for t in _tile_sizes(sb, sub):
+        for t in _tile_sizes(sb, sub, halves):
             tiled = (2 * t * d * esz           # q/do tiles
                      + 2 * t * _LANES * 4      # lse/delta tiles
                      + 2 * t * d * esz         # k/v tiles (wire dtype)
@@ -343,7 +476,7 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                 + 2 * sb * d * 4)              # fold temporaries
     if resident <= limit:
         return ("resident", None)
-    for t in _tile_sizes(sb, sub):
+    for t in _tile_sizes(sb, sub, halves):
         tiled = (3 * t * d * esz               # q/k/v tiles
                  + 2 * t * _LANES * 4          # m, l tiles
                  + t * d * 4                   # o tile
@@ -366,7 +499,7 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
             scale: float, pipelined: bool, mesh_ids: bool,
             causal: bool = False, hq: int = 1, hkv: int = 1,
             tiles: Optional[Tuple[int, int]] = None,
-            with_lse: bool = False):
+            with_lse: bool = False, zigzag: bool = False):
     """See module docstring for the step/slot/credit schedule.
 
     Multi-head layout (``hq`` query heads, ``hkv`` K/V heads — GQA when
@@ -381,6 +514,9 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     ``tiles=None`` → resident fold; ``tiles=(tq, tk)`` → flash-style
     inner tiling with the m/l/o state in HBM (module
     docstring).  The protocol events are identical in both modes.
+
+    ``zigzag`` (causal, tiled): the blocks are in the zigzag layout and
+    each arrival folds the tile ranges of ``_zigzag_tiles``.
 
     ``with_lse`` adds a second output ref carrying L = m + log l (the
     fused backward's residual); inference/fallback-backward paths skip
@@ -461,7 +597,10 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
         exactly the resident protocol."""
         nq, nk = sb // tq, sb // tk
 
-        def run(kv_idx):
+        def run(q_lo, k_hi, mask_of):
+            """Fold q tiles [q_lo, nq), q tile i against k tiles
+            [0, k_hi(i)); ``mask_of(i, j)``: the tile pair's mask, or
+            None."""
             for h in range(hq):
                 kvh = h // g
                 base = h * sb
@@ -490,25 +629,11 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                                   kt_vmem),
                                  (src.at[pl.ds((hkv + kvh) * sb + j * tk,
                                                tk)], vt_vmem))
-                        mask = None
-                        if causal:
-                            mask = _causal_mask(my, kv_idx, sb,
-                                                i * tq, j * tk, tq, tk)
                         return _online_fold(qt_vmem[:], kt_vmem[:],
                                             vt_vmem[:], m, l, o, scale,
-                                            mask)
+                                            mask_of(i, j))
 
-                    nk_eff = nk
-                    if causal:
-                        # on the DIAGONAL block (kv_idx == my) k-tiles
-                        # past this q-tile's last row are fully masked
-                        # — skip their DMAs and MXU passes (roughly
-                        # half the tile grid; review round 5).  Earlier
-                        # blocks (kv_idx < my) need every tile.
-                        nk_eff = jnp.where(
-                            kv_idx == my,
-                            (i * tq + tq + tk - 1) // tk, nk)
-                    m, l, o = lax.fori_loop(0, nk_eff, k_body,
+                    m, l, o = lax.fori_loop(0, k_hi(i), k_body,
                                             (m0, l0, o0))
                     mt_vmem[:] = jnp.broadcast_to(m, (tq, _LANES))
                     lt_vmem[:] = jnp.broadcast_to(l, (tq, _LANES))
@@ -518,16 +643,35 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                              (ot_vmem, o_hbm.at[pl.ds(r0, tq)]))
                     return 0
 
-                lax.fori_loop(0, nq, q_body, 0)
+                lax.fori_loop(q_lo, nq, q_body, 0)
 
-        if causal and a > 0:
+        if zigzag:
+            # q tiles left out keep their m/l/o state in HBM untouched
+            q_lo, k_hi, _, _, mask_of = _zigzag_arrival(a, my, P, sb, tq, tk)
+            run(q_lo, k_hi, mask_of)
+            return
+        if not causal:
+            run(0, lambda i: nk, lambda i, j: None)
+            return
+
+        def run_causal(kv_idx):
+            # on the DIAGONAL block (kv_idx == my) k-tiles past a q-tile's
+            # last row are fully masked — skip their DMAs and MXU passes
+            # (roughly half the tile grid; review round 5).  Earlier
+            # blocks (kv_idx < my) need every tile.
+            run(0, lambda i: jnp.where(kv_idx == my,
+                                       (i * tq + tq + tk - 1) // tk, nk),
+                lambda i, j: _causal_mask(my, kv_idx, sb, i * tq, j * tk,
+                                          tq, tk))
+
+        if a > 0:
             kv_idx = lax.rem(my - a + P, P)
 
             @pl.when(kv_idx <= my)
             def _():
-                run(kv_idx)
+                run_causal(kv_idx)
         else:
-            run(my)  # a == 0 → kv_idx == my; mask unused when not causal
+            run_causal(my)
 
     def fold(a, src):
         if tiles is None:
@@ -617,7 +761,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                 axis_name: str, size: int, sb: int, d: int, scale: float,
                 pipelined: bool, mesh_ids: bool, causal: bool,
                 hq: int, hkv: int,
-                tiles: Optional[Tuple[int, int]] = None):
+                tiles: Optional[Tuple[int, int]] = None,
+                zigzag: bool = False):
     """Fused ring-attention backward: [K, V] and [dK, dV] circulate for
     a FULL cycle of P sends; dQ accumulates locally; dK/dV accumulate
     in the circulating payload and land home at arrival P.
@@ -654,7 +799,9 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     long contexts need it): dQ accumulates in its HBM output, each
     arrival loops K/V-tiles (outer, dK/dV tile carried as values) over
     Q-tiles (inner, residuals + dQ staged per tile) — the circulation
-    protocol is byte-identical in both modes."""
+    protocol is byte-identical in both modes.  ``zigzag`` (causal,
+    tiled): the blocks are in the zigzag layout and each arrival folds
+    the tile ranges of ``_zigzag_tiles``."""
     if tiles is None:
         (q_vmem, do_vmem, lse_vmem, delta_vmem, kv_vmem, dkv_vmem,
          dq_vmem, copy_sem, send_sem, recv_sem, credit_sem,
@@ -711,16 +858,33 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             dkv_vmem[krows, :] = dkv_vmem[krows, :] + dk_c
             dkv_vmem[vrows, :] = dkv_vmem[vrows, :] + dv_c
 
-    def pair_grads_tiled(kv_idx, kv_at, dkv_at, init_zero, masked):
+    def tiled_bounds(a):
+        """(k_end, q_lo_of, mask_of) of arrival ``a``'s tiled fold: k
+        tiles [0, k_end), k tile j against q tiles [q_lo_of(j), nq),
+        ``mask_of(i, j)`` the tile pair's mask or None.  Off the zigzag
+        layout a causal arrival a > 0 is strictly past (mask provably
+        all-True) or skipped by the caller's pl.when."""
+        if zigzag:
+            return _zigzag_arrival(a, my, P, sb, tq, tk)[2:]
+        nk = sb // tk
+        if causal and a == 0:
+            # on the DIAGONAL block, q-tiles strictly above a k-tile are
+            # fully masked — skip them (mirrors the forward's tile-skip)
+            return nk, lambda j: (j * tk) // tq, lambda i, j: _causal_mask(
+                my, my, sb, i * tq, j * tk, tq, tk)
+        return nk, lambda j: 0, lambda i, j: None
+
+    def pair_grads_tiled(kv_at, dkv_at, init_zero, bounds):
         """Flash-tiled pair gradients: dK/dV tiles ride the inner-loop
         carry (loaded from — or, ``init_zero``, started at zero in —
         the dK/dV planes addressed by ``dkv_at(row0, n)``), residuals
         and the dQ accumulator stage per Q-tile straight from/to their
         HBM refs (dQ lives in its OUTPUT ref between arrivals).
-        ``kv_at(row0, n)`` addresses the arrived K/V planes.  The
-        protocol sees the exact same consume window as the resident
-        fold."""
-        nq, nk = sb // tq, sb // tk
+        ``kv_at(row0, n)`` addresses the arrived K/V planes; ``bounds``
+        are ``tiled_bounds``'.  The protocol sees the exact same consume
+        window as the resident fold."""
+        nq = sb // tq
+        k_end, q_lo_of, mask_of = bounds
         for h in range(hq):
             kvh = h // g
 
@@ -752,30 +916,25 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                              (lse_hbm.at[pl.ds(r0, tq)], lset_vmem),
                              (delta_hbm.at[pl.ds(r0, tq)], deltat_vmem),
                              (dq_hbm.at[pl.ds(r0, tq)], dqt_vmem))
-                    mask = (_causal_mask(my, kv_idx, sb, i * tq, j * tk,
-                                         tq, tk) if masked else None)
                     dq_c, dk_c, dv_c = _pair_grad_tile(
                         qt_vmem[:].astype(jnp.float32),
                         dot_vmem[:].astype(jnp.float32),
                         lset_vmem[:, :1], deltat_vmem[:, :1],
                         kt_vmem[:].astype(jnp.float32),
-                        vt_vmem[:].astype(jnp.float32), scale, mask)
+                        vt_vmem[:].astype(jnp.float32), scale,
+                        mask_of(i, j))
                     dqt_vmem[:] = dqt_vmem[:] + dq_c
                     copy_sync(dqt_vmem, dq_hbm.at[pl.ds(r0, tq)])
                     return dk + dk_c, dv + dv_c
 
-                # on the DIAGONAL block, q-tiles strictly above this
-                # k-tile are fully masked — skip them (mirrors the
-                # forward's diagonal tile-skip)
-                i_lo = (j * tk) // tq if masked else 0
-                dk, dv = lax.fori_loop(i_lo, nq, i_body, (dk0, dv0))
+                dk, dv = lax.fori_loop(q_lo_of(j), nq, i_body, (dk0, dv0))
                 accb_vmem[:] = dk
                 accb2_vmem[:] = dv
                 copy_par((accb_vmem, dkv_at(kr, tk)),
                          (accb2_vmem, dkv_at(hkv * sb + kr, tk)))
                 return 0
 
-            lax.fori_loop(0, nk, j_body, 0)
+            lax.fori_loop(0, k_end, j_body, 0)
 
     if tiles is None:
         # stage the rank-local residuals once (independent → parallel)
@@ -803,9 +962,9 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
         copy_sync(dkv_vmem, own_dkv_hbm)
     else:
         pair_grads_tiled(
-            my, kv_at=lambda r0, n: kv_hbm.at[pl.ds(r0, n)],
+            kv_at=lambda r0, n: kv_hbm.at[pl.ds(r0, n)],
             dkv_at=lambda r0, n: own_dkv_hbm.at[pl.ds(r0, n)],
-            init_zero=True, masked=causal)
+            init_zero=True, bounds=tiled_bounds(0))
 
     neighbor_barrier()
 
@@ -821,33 +980,32 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
         if a < P:
             # fold BEFORE forward: the dK/dV planes must carry my
             # contribution when the block moves on
-            def consume(kv_idx, masked, slot=slot):
+            kv_idx = lax.rem(my - a + P, P)
+
+            def consume(a=a, slot=slot):
                 if tiles is None:
                     copy_sync(comm_kv_hbm.at[slot], kv_vmem)
                     copy_sync(comm_dkv_hbm.at[slot], dkv_vmem)
-                    pair_grads(kv_idx, masked)
+                    pair_grads(kv_idx, masked=False)
                     copy_sync(dkv_vmem, comm_dkv_hbm.at[slot])
                 else:
                     pair_grads_tiled(
-                        kv_idx,
                         kv_at=lambda r0, n: comm_kv_hbm.at[
                             slot, pl.ds(r0, n)],
                         dkv_at=lambda r0, n: comm_dkv_hbm.at[
                             slot, pl.ds(r0, n)],
-                        init_zero=False, masked=masked)
+                        init_zero=False, bounds=tiled_bounds(a))
 
-            if causal:
+            if causal and not zigzag:
                 # the diagonal block is always arrival 0 (kv_idx == my
                 # iff a ≡ 0 mod P), so arrivals 1..P-1 are either
                 # strictly past (mask provably all-True — skip its
                 # materialization) or future (skip everything)
-                kv_idx = lax.rem(my - a + P, P)
-
-                @pl.when(kv_idx < my)
-                def _():
-                    consume(kv_idx, masked=False)
+                pl.when(kv_idx < my)(consume)
             else:
-                consume(lax.rem(my - a + P, P), masked=False)
+                # full attention, or zigzag: every arrival folds (half
+                # a block on the zigzag layout)
+                consume()
             if pipelined:
                 # FIRST retire the previous hop and credit its slot —
                 # this signal transitively feeds the right neighbor's
@@ -948,7 +1106,14 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Differentiable: the forward emits the logsumexp residual and the
     backward runs its own fused ring kernel ([K,V,dK,dV] circulation)
     in resident or flash-tiled mode per its VMEM plan; only an
-    impossible budget recomputes through the pure-jax ring."""
+    impossible budget recomputes through the pure-jax ring.
+
+    Causal layout: with ``causal=True``, ``size >= 2`` and both folds
+    tiled from tiles that divide Sb/2, the kernels run on the zigzag
+    layout (module docstring), which gives every rank the same causal
+    work; two ppermutes per array move the halves in and out.  The
+    blocks this function takes and returns stay contiguous in rank
+    order, and the circulation protocol is the same on either layout."""
     if q.ndim not in (2, 3):
         raise ValueError(
             f"ring attention wants [Sb, dh] or [H, Sb, dh] blocks, got "
@@ -992,10 +1157,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # kernel's RDMA overlap — so the substitution is LOUD (warning +
     # ``attention_fallbacks`` mpit pvar), exactly like the vma/multi-
     # axis interpreter fallback, instead of the former
-    # NotImplementedError that made an over-tight budget fatal.
+    # NotImplementedError that made an over-tight budget fatal.  A
+    # causal ring prefers tiles that divide Sb/2 (the zigzag layout's).
+    halves = causal and size >= 2
     try:
-        _, tiles = attention_vmem_plan(sb, d, hq, hkv, q.dtype,
-                                       vmem_limit_bytes)
+        _, tiles = _vmem_plan(sb, d, hq, hkv, q.dtype, vmem_limit_bytes,
+                              False, halves)
     except NotImplementedError as e:
         import warnings
 
@@ -1007,8 +1174,8 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"kernel. ({e})", RuntimeWarning, stacklevel=2)
         mpit.count(attention_oob=1)
         return _fallback_attention(q, k, v, axis_name, size, scale, causal)
-    bwd_mode, bwd_tiles = attention_vmem_plan(
-        sb, d, hq, hkv, q.dtype, vmem_limit_bytes, for_backward=True)
+    bwd_mode, bwd_tiles = _vmem_plan(sb, d, hq, hkv, q.dtype,
+                                     vmem_limit_bytes, True, halves)
     bwd_fused = bwd_mode in ("resident", "tiled")
 
     def _per_head(fn, q_, k_, v_):
@@ -1041,6 +1208,14 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if (vma_on or multi_axis) and interpret:
         _fallback("ring_attention", axis_name, vma_on, multi_axis)
         return _reference(q, k, v)
+    # causal layout (module docstring): zigzag where both folds are
+    # tiled from half blocks; every other case keeps contiguous blocks
+    zigzag = (halves and tiles is not None and bwd_mode == "tiled"
+              and all((sb // 2) % t == 0 for t in tiles + bwd_tiles))
+    if zigzag:
+        from .. import mpit
+
+        mpit.count(attention_zigzag=1)
 
     vma = (frozenset(jax.typeof(q).vma) | {axis_name} if vma_on
            else frozenset())
@@ -1084,7 +1259,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             _kernel, axis_name=axis_name, size=size, sb=sb, d=d,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=tiles,
-            with_lse=with_lse)
+            with_lse=with_lse, zigzag=zigzag)
         outs = [((hq * sb, d), jnp.float32)]
         if with_lse:
             outs.append(((hq * sb, _LANES), jnp.float32))
@@ -1145,7 +1320,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kern = functools.partial(
             _bwd_kernel, axis_name=axis_name, size=size, sb=sb, d=d,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
-            causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles)
+            causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles, zigzag=zigzag)
         kv_rows = 2 * hkv * sb
         outs = [((hq * sb, d), jnp.float32),             # dQ
                 ((kv_rows, d), jnp.float32),             # dK/dV (home)
@@ -1223,4 +1398,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return _bwd_kernel_call(q_, k_, v_, out, lse, ct)
 
     attn.defvjp(_fwd, _bwd)
-    return attn(q, k, v)
+    if not zigzag:
+        return attn(q, k, v)
+    # the exchange stays outside the custom_vjp, so autodiff transposes
+    # it: the kernels see zigzag blocks only, the caller contiguous ones
+    zz = functools.partial(_to_zigzag, axis_name=axis_name, size=size)
+    return _from_zigzag(attn(zz(q), zz(k), zz(v)), axis_name, size)

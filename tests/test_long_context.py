@@ -195,7 +195,8 @@ def test_long_context_training_tiled_budget():
                                                 init_params,
                                                 sharded_train_step)
     from mpi_tpu.tpu import default_mesh
-    from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
+    from mpi_tpu.tpu.pallas_attention import (attention_vmem_plan,
+                                              pallas_ring_attention)
 
     Pn, s, d, limit = 4, 32, 128, 120_000
     assert attention_vmem_plan(s, d, 1, 1, jnp.float32,
@@ -219,7 +220,34 @@ def test_long_context_training_tiled_budget():
     loss_d, grads_d = jax.jit(dense_train_step())(params, x, y)
     np.testing.assert_allclose(float(loss_s), float(loss_d),
                                rtol=1e-5, atol=1e-6)
+
+    # The attention itself agrees to ~1e-6, but in another f32 summation
+    # order than the dense oracle's; an MLP pre-activation within 1e-6
+    # of zero can flip its ReLU derivative on that difference, which
+    # moves its w1 column by up to |h|·|upstream grad|.  Leave out the
+    # column of at most one such pre-activation, and no other entry.
+    def qkv(p, xs):
+        return xs @ p["wq"], xs @ p["wk"], xs @ p["wv"]
+
+    att_s = jax.jit(jax.shard_map(
+        lambda q, k, v: pallas_ring_attention(
+            q, k, v, "sp", Pn, causal=True, interpret=True,
+            vmem_limit_bytes=limit),
+        mesh=mesh, in_specs=(P("sp"),) * 3, out_specs=P("sp"),
+        check_vma=False))(*qkv(params, x))
+    q, k, v = qkv(params, x)
+    sc = jnp.where(jnp.tril(jnp.ones((S, S), bool)),
+                   (q @ k.T) / np.sqrt(d), -jnp.inf)
+    att_d = jax.nn.softmax(sc, axis=-1) @ v
+    np.testing.assert_allclose(np.asarray(att_s), np.asarray(att_d),
+                               rtol=0, atol=3e-6)
+    pre = np.asarray((x + att_d @ params["wo"]) @ params["w1"])
+    near = np.argwhere(np.abs(pre) < 1e-6)
+    assert len(near) <= 1, near
+    keep = np.ones(params["w1"].shape, bool)
+    keep[:, near[:, 1]] = False
     for name in grads_d:
+        sel = keep if name == "w1" else ...
         np.testing.assert_allclose(
-            np.asarray(grads_s[name]), np.asarray(grads_d[name]),
+            np.asarray(grads_s[name])[sel], np.asarray(grads_d[name])[sel],
             rtol=5e-4, atol=5e-5, err_msg=name)

@@ -762,7 +762,10 @@ def test_bwd_tiled_export_tpu():
     aval = jax.ShapeDtypeStruct((8 * 2048, 128), jnp.float32)
     exp = jax.export.export(jf, platforms=["tpu"])(aval, aval, aval)
     assert exp.mlir_module().count("tpu_custom_call") >= 2
-    assert "collective_permute" not in exp.mlir_module()
+    # causal and tiled, so the zigzag layout: its exchange is the only
+    # collective-permute, two per array (q, k, v and the output) and
+    # their transposes — a recompute ring would add 2(P-1) more
+    assert exp.mlir_module().count("stablehlo.collective_permute") == 16
 
 
 @pytest.mark.parametrize("Hq,Hkv,causal", [(2, 1, False), (4, 2, False),
@@ -830,3 +833,231 @@ def test_forward_oob_falls_back_loudly():
         got, want = _run(Pn, Sb, d, vmem_limit_bytes=1)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     assert mpit.pvar_read("attention_fallbacks") == before + 1
+
+
+# -- zigzag causal layout ----------------------------------------------------
+
+
+def _dense_causal(q, k, v):
+    """Dense causal GQA attention in float32 on the whole sequence."""
+    g = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
+    s = jnp.einsum("hqd,hkd->hqk", q, k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    n = q.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+    return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("Hq,Hkv", [(2, 2), (4, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("Pn", [2, 3, 4])
+def test_zigzag_parity(Pn, Hq, Hkv, dtype):
+    """Causal attention on the tiled plan takes the zigzag layout (the
+    exchange outside the kernels, each arrival folding half a block):
+    the output and jax.grad match dense causal attention on the whole
+    sequence, with the caller's blocks contiguous in rank order."""
+    from mpi_tpu import mpit
+
+    Sb, d, limit = 32, 128, 100_000
+    rng = np.random.RandomState(50 + Pn * 10 + Hq)
+    # inputs rounded to the dtype, so the oracle sees what the kernel sees
+    q, k, v, ct = (np.asarray(jnp.asarray(rng.randn(h, Pn * Sb, d), dtype),
+                              np.float32) for h in (Hq, Hkv, Hkv, Hq))
+    mesh = default_mesh(Pn)
+
+    def loss(qb, kb, vb, cb):
+        out = pallas_ring_attention(qb.astype(dtype), kb.astype(dtype),
+                                    vb.astype(dtype), "world", Pn,
+                                    causal=True, interpret=True,
+                                    vmem_limit_bytes=limit)
+        out = out.astype(jnp.float32)
+        return jnp.sum(out * cb), out
+
+    spec = P(None, "world")
+    before = mpit.pvar_read("attention_zigzag")
+    (dq, dk, dv), out = jax.jit(jax.shard_map(
+        jax.grad(loss, argnums=(0, 1, 2), has_aux=True), mesh=mesh,
+        in_specs=(spec,) * 4, out_specs=((spec,) * 3, spec),
+        check_vma=False))(*map(jnp.asarray, (q, k, v, ct)))
+    assert mpit.pvar_read("attention_zigzag") == before + 1
+
+    def dense_loss(q_, k_, v_):
+        return jnp.sum(_dense_causal(q_, k_, v_) * ct)
+
+    want = [_dense_causal(q, k, v)] + list(
+        jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v))
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    for got, ref in zip((out, dq, dk, dv), want):
+        got, ref = np.asarray(got), np.asarray(ref)
+        assert got.shape == ref.shape
+        assert np.abs(got - ref).max() <= tol * np.abs(ref).max()
+
+
+def _zigzag_case(case):
+    """(a shard_map'd call of ring attention, its input avals) for one
+    way of calling it; tracing it counts the zigzag layout or not."""
+    Pn, Sb, d = (1, 32, 128) if case == "size1" else (4, 32, 128)
+    if case == "halves_untileable":
+        Sb = 24  # Sb/2 = 12 rows: no 8-row tile divides a chunk
+    kw = dict(causal=True, interpret=True, vmem_limit_bytes=100_000)
+    check_vma, grad = False, True
+    if case == "full":
+        kw["causal"] = False
+    elif case == "resident":
+        kw.pop("vmem_limit_bytes")
+    elif case == "vma_fallback":
+        check_vma = True
+    elif case == "forward_oob":
+        kw["vmem_limit_bytes"] = 1
+    elif case == "backward_oob":
+        kw["vmem_limit_bytes"] = 40_000  # tiled forward, no backward tile
+
+    def loss(qb):
+        return jnp.sum(pallas_ring_attention(qb, qb, qb, "world", Pn, **kw))
+
+    fn = jax.grad(loss) if grad else loss
+    aval = jax.ShapeDtypeStruct((Pn * Sb, d), jnp.float32)
+    return jax.shard_map(fn, mesh=default_mesh(Pn), in_specs=P("world"),
+                         out_specs=P("world"), check_vma=check_vma), aval
+
+
+@pytest.mark.parametrize("case,engaged", [
+    ("causal_tiled", 1), ("full", 0), ("resident", 0), ("size1", 0),
+    ("vma_fallback", 0), ("forward_oob", 0), ("backward_oob", 0),
+    ("halves_untileable", 0)])
+def test_zigzag_engages_only_causal_tiled(case, engaged):
+    """``attention_zigzag`` rises once per trace where causal attention
+    runs both tiled kernels, and on no other path."""
+    import warnings
+
+    from mpi_tpu import mpit
+
+    fn, aval = _zigzag_case(case)
+    before = mpit.pvar_read("attention_zigzag")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # the fallbacks'
+        jax.jit(fn).trace(aval)
+    assert mpit.pvar_read("attention_zigzag") - before == engaged
+
+
+def test_causal_tiled_contiguous_where_halves_do_not_tile():
+    """Causal and tiled, but no tile divides Sb/2: the kernels keep the
+    contiguous layout (diagonal tile-skip, future blocks skipped) and
+    still match the differentiated reference ring."""
+    from mpi_tpu import mpit
+    from mpi_tpu.tpu.pallas_attention import (_fallback_attention,
+                                              attention_vmem_plan)
+
+    Pn, Sb, d, limit = 4, 24, 128, 100_000
+    assert attention_vmem_plan(Sb, d, 1, 1, jnp.float32, limit)[0] == "tiled"
+    assert attention_vmem_plan(Sb, d, 1, 1, jnp.float32, limit,
+                               for_backward=True)[0] == "tiled"
+    rng = np.random.RandomState(53)
+    q, k, v, ct = (rng.randn(Pn * Sb, d).astype(np.float32)
+                   for _ in range(4))
+    mesh = default_mesh(Pn)
+
+    def loss_kernel(qb, kb, vb, cb):
+        out = pallas_ring_attention(qb, kb, vb, "world", Pn, causal=True,
+                                    interpret=True, vmem_limit_bytes=limit)
+        return jnp.sum(out * cb), out
+
+    def loss_ref(qb, kb, vb, cb):
+        out = _fallback_attention(qb, kb, vb, "world", Pn,
+                                  1.0 / np.sqrt(d), True)
+        return jnp.sum(out * cb), out
+
+    before = mpit.pvar_read("attention_zigzag")
+    got = {}
+    for name, fn in (("kernel", loss_kernel), ("ref", loss_ref)):
+        g, out = jax.jit(jax.shard_map(
+            jax.grad(fn, argnums=(0, 1, 2), has_aux=True), mesh=mesh,
+            in_specs=(P("world"),) * 4,
+            out_specs=((P("world"),) * 3, P("world")),
+            check_vma=False))(*map(jnp.asarray, (q, k, v, ct)))
+        got[name] = [np.asarray(x) for x in (out,) + tuple(g)]
+    assert mpit.pvar_read("attention_zigzag") == before
+    np.testing.assert_allclose(got["kernel"][0], _causal_oracle(q, k, v),
+                               rtol=2e-4, atol=2e-5)
+    for a, b in zip(got["kernel"], got["ref"]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("sb,sub,first,same", [
+    (8192, 16, 512, True), (512, 8, 256, False), (384, 16, 192, False),
+    (24, 8, 24, True)])
+def test_causal_tiles_prefer_halves(sb, sub, first, same):
+    """A causal ring's plan tries tiles that divide Sb/2 first, still
+    largest first; where none does, the order is the contiguous one."""
+    from mpi_tpu.tpu.pallas_attention import _tile_sizes
+
+    ts, plain = _tile_sizes(sb, sub, halves=True), _tile_sizes(sb, sub)
+    assert ts[0] == first and sorted(ts) == sorted(plain)
+    assert (ts == plain) == same
+    half = [t for t in ts if (sb // 2) % t == 0]
+    assert ts[:len(half)] == half == sorted(half, reverse=True)
+
+
+def _tile_pairs(diag, past, nq, nk, tq, tk):
+    """Tile pairs (i, j) one arrival folds, from the forward's bounds and,
+    separately, from the backward's: the two must agree."""
+    from mpi_tpu.tpu.pallas_attention import _zigzag_tiles
+
+    q_lo, k_hi, k_end, q_lo_of = _zigzag_tiles(diag, past, nq, nk, tq, tk)
+    fwd = {(i, j) for i in range(int(q_lo), nq)
+           for j in range(int(k_hi(i)))}
+    bwd = {(i, j) for j in range(int(k_end))
+           for i in range(int(q_lo_of(j)), nq)}
+    assert fwd == bwd
+    return fwd
+
+
+def test_zigzag_balances_the_cell():
+    """The four-chip cell's shapes (Sb 8192 a chip, 512-row tiles): every
+    rank folds 520 tile pairs on the zigzag layout, against 136 / 392 /
+    648 / 904 on the contiguous one (the diagonal block plus r full past
+    blocks; future blocks skipped)."""
+    Pn, nt, t = 4, 16, 512
+    diag = len(_tile_pairs(True, False, nt, nt, t, t))
+    zigzag = [diag + sum(len(_tile_pairs(False, s < r, nt, nt, t, t))
+                         for s in range(Pn) if s != r) for r in range(Pn)]
+    contiguous = [diag + r * nt * nt for r in range(Pn)]
+    assert zigzag == [520] * 4
+    assert contiguous == [136, 392, 648, 904]
+
+
+@pytest.mark.parametrize("Pn,Sb,tq,tk", [(2, 16, 4, 4), (3, 24, 4, 4),
+                                         (4, 32, 8, 4), (4, 32, 4, 8),
+                                         (4, 64, 16, 16)])
+def test_zigzag_tiles_cover_causal_pairs_once(Pn, Sb, tq, tk):
+    """By brute force: over every rank and arrival, the tiles the kernels
+    visit (masked by global position on the diagonal only) hold each
+    causal (query, key) pair of the global sequence exactly once, and
+    no other pair."""
+    from mpi_tpu.tpu.pallas_attention import _zigzag_mask, _zigzag_pos
+
+    c, nq, nk = Sb // 2, Sb // tq, Sb // tk
+    seen = {}
+    for r in range(Pn):
+        for s in range(Pn):
+            for i, j in _tile_pairs(s == r, s < r, nq, nk, tq, tk):
+                rows = np.arange(i * tq, (i + 1) * tq)[:, None]
+                cols = np.arange(j * tk, (j + 1) * tk)[None, :]
+                pq = np.asarray(_zigzag_pos(r, rows, c, Pn))
+                pk = np.asarray(_zigzag_pos(s, cols, c, Pn))
+                pq, pk = np.broadcast_arrays(pq, pk)
+                if s == r:
+                    keep = np.asarray(_zigzag_mask(r, c, Pn, i * tq,
+                                                   j * tk, tq, tk))
+                    assert np.array_equal(keep, pk <= pq)
+                else:
+                    keep = np.ones_like(pq, bool)
+                    assert (pk <= pq).all()  # unmasked: all causal
+                for a, b in zip(pq[keep], pk[keep]):
+                    seen[(a, b)] = seen.get((a, b), 0) + 1
+    S = Pn * Sb
+    assert set(seen.values()) == {1}
+    assert set(seen) == {(a, b) for a in range(S) for b in range(a + 1)}

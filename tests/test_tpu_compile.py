@@ -118,6 +118,10 @@ _ATTENTION = {"resident": (2048, 100 * MIB), "tiled": (8192, 8 * MIB)}
                          ids=["forward", "fused_backward"])
 @pytest.mark.parametrize("plan", sorted(_ATTENTION))
 def test_ring_attention_compiles(mesh4, plan, backward):
+    """Causal attention over the 4 chips; the tiled plan takes the zigzag
+    layout, whose exchange is the program's only collective-permutes."""
+    from mpi_tpu import mpit
+
     sb, limit = _ATTENTION[plan]
     d = 128
     assert attention_vmem_plan(sb, d, 1, 1, jnp.bfloat16, limit,
@@ -137,10 +141,14 @@ def test_ring_attention_compiles(mesh4, plan, backward):
         out_specs = (spec,) * 3
     else:
         fn, out_specs = attn, spec
+    before = mpit.pvar_read("attention_zigzag")
     compiled = _compile(jax.shard_map(fn, mesh=mesh4, in_specs=(spec,) * 3,
                                       out_specs=out_specs), q, q, q)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    zigzag = plan == "tiled"
+    assert mpit.pvar_read("attention_zigzag") - before == int(zigzag)
+    assert ("collective-permute" in text) == zigzag
     # under autodiff the instruction names carry the transforms too
     # (``%jvp_mpi_tpu_ring_attention_fwd_.1``, ``%transpose_jvp_..._bwd``)
     assert "mpi_tpu_ring_attention_fwd" in text
