@@ -43,7 +43,7 @@ class _Counters:
                  "pallas_fallbacks", "bytes_raw", "bytes_pickled", "copies",
                  "proc_failed", "revokes", "shrinks",
                  "faulty_dropped", "faulty_duplicated", "attention_oob",
-                 "attention_zz",
+                 "attention_zz", "attention_win",
                  "sm_hits", "sm_bytes", "sm_fallbacks",
                  "v_deadlocks", "v_mismatches", "v_leaked", "v_double_waits",
                  "v_buf_overlaps", "v_comms_unfreed",
@@ -77,6 +77,7 @@ class _Counters:
         self.faulty_duplicated = 0
         self.attention_oob = 0
         self.attention_zz = 0
+        self.attention_win = 0
         self.sm_hits = 0
         self.sm_bytes = 0
         self.sm_fallbacks = 0
@@ -132,6 +133,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           proc_failed: int = 0, revokes: int = 0, shrinks: int = 0,
           faulty_dropped: int = 0, faulty_duplicated: int = 0,
           attention_oob: int = 0, attention_zigzag: int = 0,
+          attention_window: int = 0,
           coll_sm_hits: int = 0,
           coll_sm_bytes: int = 0, coll_sm_fallbacks: int = 0,
           verify_deadlocks: int = 0, verify_mismatches: int = 0,
@@ -189,6 +191,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.faulty_duplicated += faulty_duplicated
         counters.attention_oob += attention_oob
         counters.attention_zz += attention_zigzag
+        counters.attention_win += attention_window
         counters.sm_hits += coll_sm_hits
         counters.sm_bytes += coll_sm_bytes
         counters.sm_fallbacks += coll_sm_fallbacks
@@ -274,6 +277,10 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # causal work across the ring; tpu/pallas_attention.py): says
     # whether the mechanism engaged
     "attention_zigzag": lambda: counters.attention_zz,
+    # sliding-window attention traces that took the windowed tile
+    # ranges (k tiles before a query tile's window skipped;
+    # tpu/pallas_attention.py): says whether the window engaged
+    "attention_window": lambda: counters.attention_win,
     # shared-memory collective arena (mpi_tpu/coll_sm.py): collectives
     # served entirely by arena load/store (zero ring frames), per-rank
     # payload bytes moved through it, and eligible requests that fell

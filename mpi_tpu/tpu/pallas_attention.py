@@ -89,8 +89,24 @@ counts the traces that take it).  Two ppermutes per array move the
 halves before the kernels and back after them, outside the custom
 VJP, so the caller's contiguous blocks and the kernels' circulation
 protocol are unchanged; only the tile ranges each fold visits differ.
-Every other case (full attention, P = 1, the resident fold, the
-fallbacks) keeps the contiguous blocks.
+Every other case (full attention, windowed attention, P = 1, the
+resident fold, the fallbacks) keeps the contiguous blocks.
+
+**Sliding window** (``_band_tiles``): with ``window=W`` a query attends
+the W newest keys up to itself.  On the contiguous layout each tiled
+fold visits, per q tile, only the k tiles between its window's start and
+its diagonal (and, in the backward, per k tile only the q tiles whose
+windows reach it): exactly the tile pairs that hold an unmasked element.
+Both bounds are masked by global position, by one mask function.  A past
+block wholly before every local query's window folds nothing; the
+circulation is unchanged.  The windowed kernels carry their own names
+(``mpi_tpu_swa_attention_fwd`` / ``_bwd``) and the ``attention_window``
+mpit pvar counts the traces that take the windowed tile ranges.
+
+**A ring of one**: at P = 1 the same kernels run the step-0 fold alone —
+no landing slots, RDMA, barrier or credit, and none of a collective's
+compiler parameters.  The kernels loop over heads in ``lax.fori_loop``s
+(row offsets traced), so the lowered code does not grow with the heads.
 
 Under the interpreter (CPU tier) RDMAs run serially (start+wait, no
 credits/barriers) — same data path, no overlap; under vma typing or a
@@ -107,7 +123,7 @@ VMEM budget no tile size can satisfy raises with the numbers.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -129,8 +145,9 @@ _MASKED = -1e30  # large-negative finite (an -inf mask would NaN through exp)
 _VMEM_BUDGET = 12 * 2 ** 20
 
 # Largest tile edge of the tiled folds.  Mosaic unrolls a tile's vector
-# ops, so compile time grows with tq*tk times the heads: 8 heads of
-# [1024, 1024] tiles took ~95 s to compile for v5e, [512, 512] ~17 s.
+# ops, so compile time grows with tq*tk: 8 heads of [1024, 1024] tiles
+# took ~95 s to compile for v5e, [512, 512] ~17 s (heads unrolled then;
+# the head loops are rolled since).
 _MAX_TILE = 512
 
 
@@ -153,16 +170,28 @@ def _online_fold(q, k, v, m, l, o, scale, mask=None):
 
 
 def _causal_mask(my, kv_idx, sb: int, i0=0, j0=0,
-                 tq: Optional[int] = None, tk: Optional[int] = None):
+                 tq: Optional[int] = None, tk: Optional[int] = None,
+                 window: Optional[int] = None):
     """[tq,tk] causal mask for rows ``i0..`` of query block ``my`` vs
     rows ``j0..`` of key block ``kv_idx`` (block indices traced, tile
     offsets traced or static): global key position must not exceed
-    global query position.  Defaults cover the whole [Sb,Sb] block."""
+    global query position, and with a ``window`` must lie less than
+    ``window`` positions before it.  Defaults cover the whole [Sb,Sb]
+    block."""
     tq = sb if tq is None else tq
     tk = sb if tk is None else tk
     qi = my * sb + i0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
     kj = kv_idx * sb + j0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-    return kj <= qi
+    if window is None:
+        return kj <= qi
+    return (kj <= qi) & (kj > qi - window)
+
+
+def _head_row(h, sb: int):
+    """First row of head ``h``'s [Sb, d] plane in a heads-stacked buffer
+    (the kernels loop over heads in a ``lax.fori_loop``, so ``h`` is
+    traced: the hint keeps the row offset's alignment visible)."""
+    return pl.multiple_of(h * sb, sb)
 
 
 def _tile_sizes(sb: int, sub: int, halves: bool = False):
@@ -203,16 +232,24 @@ def _zigzag_mask(my, c: int, size: int, i0, j0, tq: int, tk: int):
     return kj <= qi
 
 
-def _zigzag_tiles(diag: bool, past, nq: int, nk: int, tq: int, tk: int):
-    """Tile ranges one causal arrival folds on the zigzag layout — the
-    loop bounds of both tiled kernels.  ``diag`` (static): the arrival
-    is my own block; otherwise ``past`` (traced or Python bool) says the
-    K/V block's rank is below mine.
+class _Tiles(NamedTuple):
+    """The tile pairs one arrival folds — the loop bounds of both tiled
+    kernels.  The forward folds q tiles ``[q_lo, q_hi)``, q tile ``i``
+    against the k tiles ``range(*k_of(i))``; the backward folds k tiles
+    ``[k_lo, k_hi)``, k tile ``j`` against the q tiles
+    ``range(*q_of(j))``.  Both visit the same pairs."""
+    q_lo: object
+    q_hi: object
+    k_of: Callable
+    k_lo: object
+    k_hi: object
+    q_of: Callable
 
-    Returns ``(q_lo, k_hi, k_end, q_lo_of)``: the forward folds q tiles
-    ``[q_lo, nq)``, q tile ``i`` against k tiles ``[0, k_hi(i))``; the
-    backward folds k tiles ``[0, k_end)``, k tile ``j`` against q tiles
-    ``[q_lo_of(j), nq)``.  Both visit the same tile pairs:
+
+def _zigzag_tiles(diag: bool, past, nq: int, nk: int, tq: int, tk: int):
+    """:class:`_Tiles` of one causal arrival on the zigzag layout.
+    ``diag`` (static): the arrival is my own block; otherwise ``past``
+    (traced or Python bool) says the K/V block's rank is below mine.
 
     * diagonal: every q tile up to its diagonal (masked there);
     * past rank: every q tile against the low K/V chunk (its high chunk
@@ -221,24 +258,95 @@ def _zigzag_tiles(diag: bool, past, nq: int, nk: int, tq: int, tk: int):
       precedes both of its chunks).
     """
     if diag:
-        return (0, lambda i: (i * tq + tq + tk - 1) // tk, nk,
-                lambda j: (j * tk) // tq)
+        return _Tiles(0, nq, lambda i: (0, (i * tq + tq + tk - 1) // tk),
+                      0, nk, lambda j: ((j * tk) // tq, nq))
     q_lo = jnp.where(past, 0, nq // 2)
     k_hi = jnp.where(past, nk // 2, nk)
-    return q_lo, lambda i: k_hi, k_hi, lambda j: q_lo
+    return _Tiles(q_lo, nq, lambda i: (0, k_hi), 0, k_hi,
+                  lambda j: (q_lo, nq))
 
 
 def _zigzag_arrival(a: int, my, size: int, sb: int, tq: int, tk: int):
     """Inside a kernel: ``_zigzag_tiles`` of arrival ``a`` (the K/V block
-    of rank (my - a) mod size), plus ``mask_of(i, j)``, the tile pair's
+    of rank (my - a) mod size) and ``mask_of(i, j)``, the tile pair's
     causal mask — only the diagonal arrival a == 0 needs one, every pair
     another arrival visits is causal."""
-    bounds = _zigzag_tiles(a == 0, lax.rem(my - a + size, size) < my,
-                           sb // tq, sb // tk, tq, tk)
+    tiles = _zigzag_tiles(a == 0, lax.rem(my - a + size, size) < my,
+                          sb // tq, sb // tk, tq, tk)
     if a > 0:
-        return bounds + (lambda i, j: None,)
-    return bounds + (lambda i, j: _zigzag_mask(my, sb // 2, size, i * tq,
-                                               j * tk, tq, tk),)
+        return tiles, lambda i, j: None
+    return tiles, lambda i, j: _zigzag_mask(my, sb // 2, size, i * tq,
+                                            j * tk, tq, tk)
+
+
+def _full_tiles(sb: int, tq: int, tk: int):
+    """:class:`_Tiles` of full (non-causal) attention: every pair."""
+    nq, nk = sb // tq, sb // tk
+    return _Tiles(0, nq, lambda i: (0, nk), 0, nk, lambda j: (0, nq))
+
+
+def _clamp0(x):
+    """max(x, 0) of a Python int or a traced scalar."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _band_tiles(off, window: Optional[int], sb: int, tq: int, tk: int):
+    """:class:`_Tiles` of one causal arrival on the contiguous layout: my
+    query block starts ``off`` (>= 0; Python int or traced) positions
+    after the K/V block, and query position p attends key position p'
+    iff ``0 <= p - p' < window`` (``window=None``: no lower bound).  A
+    pair of tiles is visited iff it holds such an element; the window's
+    lower bound cuts the k tiles wholly before a q tile's window (and
+    the q tiles after a k tile's), the causal bound those past it.
+
+    With query row r and key row c of the blocks, a tile pair (i, j)
+    holds ``0 <= off + r - c < window`` for some element iff
+    ``j*tk <= off + i*tq + tq - 1`` and ``off + i*tq - window + 1 <=
+    j*tk + tk - 1``; the bounds below solve each for j, then for i."""
+    nq, nk = sb // tq, sb // tk
+
+    def k_of(i):
+        hi = jnp.minimum(nk, (off + i * tq + tq - 1) // tk + 1)
+        if window is None:
+            return 0, hi
+        return _clamp0(off + i * tq - window + 1) // tk, hi
+
+    def q_of(j):
+        lo = _clamp0(j * tk - off) // tq
+        if window is None:
+            return lo, nq
+        return lo, jnp.minimum(nq, _clamp0(j * tk + tk + window - 2 - off
+                                           + tq) // tq)
+
+    if window is None:
+        return _Tiles(0, nq, k_of, 0, nk, q_of)
+    q_hi = _clamp0(sb - 2 + window - off + tq) // tq
+    q_hi = min(nq, q_hi) if isinstance(q_hi, int) else jnp.minimum(nq, q_hi)
+    return _Tiles(0, q_hi, k_of, _clamp0(off - window + 1) // tk, nk, q_of)
+
+
+def _before_window(a: int, sb: int, window: Optional[int]) -> bool:
+    """Contiguous layout: the K/V block ``a`` blocks before mine lies
+    wholly before every query's window (its newest key is ``window`` or
+    more positions before my oldest query), so that arrival folds
+    nothing."""
+    return window is not None and a * sb >= sb - 1 + window
+
+
+def _band_arrival(a: int, my, sb: int, tq: int, tk: int,
+                  window: Optional[int]):
+    """Inside a kernel: ``_band_tiles`` of causal arrival ``a`` on the
+    contiguous layout — the K/V block ``a`` blocks before mine (callers
+    skip future blocks, so the offset ``a * sb`` is static) — and
+    ``mask_of(i, j)``, the tile pair's mask by global position: the
+    diagonal arrival and, with a window, every arrival need one (a tile
+    may hold the window's edge); the pairs of a past arrival without a
+    window are all causal."""
+    tiles = _band_tiles(a * sb, window, sb, tq, tk)
+    if a > 0 and window is None:
+        return tiles, lambda i, j: None
+    return tiles, lambda i, j: _causal_mask(my, my - a, sb, i * tq, j * tk,
+                                            tq, tk, window)
 
 
 def _zigzag_perms(size: int):
@@ -499,7 +607,8 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
             scale: float, pipelined: bool, mesh_ids: bool,
             causal: bool = False, hq: int = 1, hkv: int = 1,
             tiles: Optional[Tuple[int, int]] = None,
-            with_lse: bool = False, zigzag: bool = False):
+            with_lse: bool = False, zigzag: bool = False,
+            window: Optional[int] = None):
     """See module docstring for the step/slot/credit schedule.
 
     Multi-head layout (``hq`` query heads, ``hkv`` K/V heads — GQA when
@@ -516,38 +625,41 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     docstring).  The protocol events are identical in both modes.
 
     ``zigzag`` (causal, tiled): the blocks are in the zigzag layout and
-    each arrival folds the tile ranges of ``_zigzag_tiles``.
+    each arrival folds the tile ranges of ``_zigzag_tiles``; otherwise a
+    causal arrival folds those of ``_band_tiles`` (``window``: the
+    sliding window's lower bound too).
+
+    ``size == 1`` is a ring of one: the step-0 fold alone, with no
+    landing slots, RDMA, barrier or credit.
 
     ``with_lse`` adds a second output ref carrying L = m + log l (the
     fused backward's residual); inference/fallback-backward paths skip
     its VMEM broadcast and HBM write entirely."""
-    if with_lse:
-        out_hbm, lse_hbm = refs[0], refs[1]
-        refs = refs[2:]
-    else:
-        out_hbm, lse_hbm = refs[0], None
-        refs = refs[1:]
+    P = size
+    n_out = 2 if with_lse else 1
+    out_hbm, lse_hbm = refs[0], refs[1] if with_lse else None
+    comm_hbm = refs[n_out] if P > 1 else None  # landing slots
+    refs = refs[n_out + (P > 1):]
     if tiles is None:
         if with_lse:
-            (comm_hbm, q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem, lse_vmem,
+            (q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem, lse_vmem,
              copy_sem, send_sem, recv_sem, credit_sem,
              par_sems) = refs
         else:
-            (comm_hbm, q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem,
+            (q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem,
              copy_sem, send_sem, recv_sem, credit_sem,
              par_sems) = refs
     else:
-        (comm_hbm, m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
+        (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
          mt_vmem, lt_vmem, ot_vmem,
          copy_sem, send_sem, recv_sem, credit_sem, par_sems) = refs
         tq, tk = tiles
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
-    P = size
     g = hq // hkv  # query heads per K/V head (GQA group size)
     dev_kw = _mk_dev_kw(mesh_ids, axis_name)
-    neighbor_barrier = _mk_barrier(pipelined, dev_kw, left, right)
+    neighbor_barrier = _mk_barrier(pipelined and P > 1, dev_kw, left, right)
     copy_sync = _mk_copy_sync(copy_sem)
     copy_par = _mk_copy_par(par_sems)
     # send u (0..P-2): the block computed at step u moves on
@@ -560,17 +672,20 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
 
     def fold_resident(a):
         def body(mask):
-            for h in range(hq):
-                kvh = h // g
-                rows = pl.ds(h * sb, sb)
-                k = kv_vmem[pl.ds(kvh * sb, sb), :]
-                v = kv_vmem[pl.ds((hkv + kvh) * sb, sb), :]
+            def head(h, _):
+                rows = pl.ds(_head_row(h, sb), sb)
+                kr = _head_row(h // g, sb)
+                k = kv_vmem[pl.ds(kr, sb), :]
+                v = kv_vmem[pl.ds(hkv * sb + kr, sb), :]
                 m, l, o = _online_fold(q_vmem[rows, :], k, v,
                                        m_vmem[rows, :], l_vmem[rows, :],
                                        o_vmem[rows, :], scale, mask)
                 m_vmem[rows, :] = m
                 l_vmem[rows, :] = l
                 o_vmem[rows, :] = o
+                return 0
+
+            lax.fori_loop(0, hq, head, 0)
 
         if not causal:
             body(None)
@@ -580,12 +695,15 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
         # running max is finite from step 0 on.  Blocks entirely in the
         # future (kv_idx > my) contribute exactly zero — skip their MXU
         # passes outright (the circulation/credit schedule above is
-        # untouched, so the model-checked protocol is unchanged).
+        # untouched, so the model-checked protocol is unchanged); so do
+        # past blocks wholly before every query's window.
+        if _before_window(a, sb, window):
+            return
         kv_idx = lax.rem(my - a + P, P)
 
         @pl.when(kv_idx <= my)
         def _():
-            body(_causal_mask(my, kv_idx, sb))
+            body(_causal_mask(my, kv_idx, sb, window=window))
 
     # -- tiled fold: state in HBM, flash-style [tq, tk] inner loop ----------
 
@@ -595,17 +713,15 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
         forwarding RDMA (read/read); the credit still follows both the
         fold and wait_send in program order, so slot recycling is
         exactly the resident protocol."""
-        nq, nk = sb // tq, sb // tk
 
-        def run(q_lo, k_hi, mask_of):
-            """Fold q tiles [q_lo, nq), q tile i against k tiles
-            [0, k_hi(i)); ``mask_of(i, j)``: the tile pair's mask, or
-            None."""
-            for h in range(hq):
+        def run(t, mask_of):
+            """Fold the tile pairs of ``t`` (:class:`_Tiles`);
+            ``mask_of(i, j)``: the tile pair's mask, or None."""
+            def head(h, _):
                 kvh = h // g
-                base = h * sb
+                base = _head_row(h, sb)
 
-                def q_body(i, _, h=h, kvh=kvh, base=base):
+                def q_body(i, _):
                     r0 = base + i * tq
                     if a == 0:
                         copy_sync(q_hbm.at[pl.ds(r0, tq)], qt_vmem)
@@ -633,7 +749,7 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                                             vt_vmem[:], m, l, o, scale,
                                             mask_of(i, j))
 
-                    m, l, o = lax.fori_loop(0, k_hi(i), k_body,
+                    m, l, o = lax.fori_loop(*t.k_of(i), k_body,
                                             (m0, l0, o0))
                     mt_vmem[:] = jnp.broadcast_to(m, (tq, _LANES))
                     lt_vmem[:] = jnp.broadcast_to(l, (tq, _LANES))
@@ -643,35 +759,23 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                              (ot_vmem, o_hbm.at[pl.ds(r0, tq)]))
                     return 0
 
-                lax.fori_loop(q_lo, nq, q_body, 0)
+                lax.fori_loop(t.q_lo, t.q_hi, q_body, 0)
+                return 0
 
+            lax.fori_loop(0, hq, head, 0)
+
+        # q tiles an arrival leaves out keep their m/l/o state in HBM
+        # untouched; the diagonal arrival a == 0 visits every q tile
         if zigzag:
-            # q tiles left out keep their m/l/o state in HBM untouched
-            q_lo, k_hi, _, _, mask_of = _zigzag_arrival(a, my, P, sb, tq, tk)
-            run(q_lo, k_hi, mask_of)
-            return
-        if not causal:
-            run(0, lambda i: nk, lambda i, j: None)
-            return
-
-        def run_causal(kv_idx):
-            # on the DIAGONAL block (kv_idx == my) k-tiles past a q-tile's
-            # last row are fully masked — skip their DMAs and MXU passes
-            # (roughly half the tile grid; review round 5).  Earlier
-            # blocks (kv_idx < my) need every tile.
-            run(0, lambda i: jnp.where(kv_idx == my,
-                                       (i * tq + tq + tk - 1) // tk, nk),
-                lambda i, j: _causal_mask(my, kv_idx, sb, i * tq, j * tk,
-                                          tq, tk))
-
-        if a > 0:
-            kv_idx = lax.rem(my - a + P, P)
-
-            @pl.when(kv_idx <= my)
-            def _():
-                run_causal(kv_idx)
-        else:
-            run_causal(my)
+            run(*_zigzag_arrival(a, my, P, sb, tq, tk))
+        elif not causal:
+            run(_full_tiles(sb, tq, tk), lambda i, j: None)
+        elif a == 0:
+            run(*_band_arrival(0, my, sb, tq, tk, window))
+        elif not _before_window(a, sb, window):
+            # past blocks only: a future block (my < a) folds nothing
+            pl.when(my >= a)(
+                lambda: run(*_band_arrival(a, my, sb, tq, tk, window)))
 
     def fold(a, src):
         if tiles is None:
@@ -756,13 +860,12 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
 
 
 def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
-                dq_hbm, dkv_hbm, own_kv_hbm, own_dkv_hbm,
-                comm_kv_hbm, comm_dkv_hbm, *refs,
+                dq_hbm, dkv_hbm, *refs,
                 axis_name: str, size: int, sb: int, d: int, scale: float,
                 pipelined: bool, mesh_ids: bool, causal: bool,
                 hq: int, hkv: int,
                 tiles: Optional[Tuple[int, int]] = None,
-                zigzag: bool = False):
+                zigzag: bool = False, window: Optional[int] = None):
     """Fused ring-attention backward: [K, V] and [dK, dV] circulate for
     a FULL cycle of P sends; dQ accumulates locally; dK/dV accumulate
     in the circulating payload and land home at arrival P.
@@ -801,7 +904,20 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     Q-tiles (inner, residuals + dQ staged per tile) — the circulation
     protocol is byte-identical in both modes.  ``zigzag`` (causal,
     tiled): the blocks are in the zigzag layout and each arrival folds
-    the tile ranges of ``_zigzag_tiles``."""
+    the tile ranges of ``_zigzag_tiles``; otherwise a causal arrival
+    folds those of ``_band_tiles`` (``window``: with the sliding
+    window's bound).
+
+    ``size == 1`` is a ring of one: fold 0 writes dK/dV home directly,
+    with no own/landing buffers, RDMA, barrier or credit."""
+    P = size
+    if P > 1:
+        own_kv_hbm, own_dkv_hbm, comm_kv_hbm, comm_dkv_hbm = refs[:4]
+        refs = refs[4:]
+    else:
+        # my own contribution is the whole result; nothing circulates
+        own_kv_hbm = comm_kv_hbm = comm_dkv_hbm = None
+        own_dkv_hbm = dkv_hbm
     if tiles is None:
         (q_vmem, do_vmem, lse_vmem, delta_vmem, kv_vmem, dkv_vmem,
          dq_vmem, copy_sem, send_sem, recv_sem, credit_sem,
@@ -814,11 +930,10 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
-    P = size
     g = hq // hkv
     kv_rows = 2 * hkv * sb  # K+V planes; dK+dV planes follow
     dev_kw = _mk_dev_kw(mesh_ids, axis_name)
-    neighbor_barrier = _mk_barrier(pipelined, dev_kw, left, right)
+    neighbor_barrier = _mk_barrier(pipelined and P > 1, dev_kw, left, right)
     copy_sync = _mk_copy_sync(copy_sem)
     copy_par = _mk_copy_par(par_sems)
 
@@ -836,43 +951,43 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     def pair_grads(kv_idx, masked):
         """dQ/dK/dV contributions of my Q rows against the K/V block in
         kv_vmem; dK/dV accumulate into dkv_vmem (all heads).  ``masked``
-        (static) applies the causal mask — only the DIAGONAL block
-        (kv_idx == my) needs it: strictly-past blocks are all-True and
-        future blocks are skipped by the caller's pl.when, so the mask
-        materialization stays off the P-2 hot arrivals (review round
-        5)."""
-        for h in range(hq):
-            kvh = h // g
-            rows = pl.ds(h * sb, sb)
-            mask = _causal_mask(my, kv_idx, sb) if masked else None
+        (static) applies the causal mask (with the window's bound) — on
+        the DIAGONAL block (kv_idx == my), or at a window's edge: without
+        a window strictly-past blocks are all-True and future blocks are
+        skipped by the caller's pl.when, so the mask materialization
+        stays off the P-2 hot arrivals."""
+        mask = (_causal_mask(my, kv_idx, sb, window=window) if masked
+                else None)
+
+        def head(h, _):
+            rows = pl.ds(_head_row(h, sb), sb)
+            kr = _head_row(h // g, sb)
+            krows = pl.ds(kr, sb)
+            vrows = pl.ds(hkv * sb + kr, sb)
             dq_c, dk_c, dv_c = _pair_grad_tile(
                 q_vmem[rows, :].astype(jnp.float32),
                 do_vmem[rows, :].astype(jnp.float32),
                 lse_vmem[rows, :][:, :1], delta_vmem[rows, :][:, :1],
-                kv_vmem[pl.ds(kvh * sb, sb), :].astype(jnp.float32),
-                kv_vmem[pl.ds((hkv + kvh) * sb, sb), :]
-                .astype(jnp.float32), scale, mask)
+                kv_vmem[krows, :].astype(jnp.float32),
+                kv_vmem[vrows, :].astype(jnp.float32), scale, mask)
             dq_vmem[rows, :] = dq_vmem[rows, :] + dq_c
-            krows = pl.ds(kvh * sb, sb)
-            vrows = pl.ds((hkv + kvh) * sb, sb)
             dkv_vmem[krows, :] = dkv_vmem[krows, :] + dk_c
             dkv_vmem[vrows, :] = dkv_vmem[vrows, :] + dv_c
+            return 0
+
+        lax.fori_loop(0, hq, head, 0)
 
     def tiled_bounds(a):
-        """(k_end, q_lo_of, mask_of) of arrival ``a``'s tiled fold: k
-        tiles [0, k_end), k tile j against q tiles [q_lo_of(j), nq),
-        ``mask_of(i, j)`` the tile pair's mask or None.  Off the zigzag
-        layout a causal arrival a > 0 is strictly past (mask provably
-        all-True) or skipped by the caller's pl.when."""
+        """(:class:`_Tiles`, mask_of) of arrival ``a``'s tiled fold,
+        ``mask_of(i, j)`` the tile pair's mask or None — the forward's
+        tile ranges and masks (``_zigzag_arrival`` / ``_band_arrival``).
+        Off the zigzag layout a causal arrival a > 0 is strictly past or
+        skipped by the caller's pl.when."""
         if zigzag:
-            return _zigzag_arrival(a, my, P, sb, tq, tk)[2:]
-        nk = sb // tk
-        if causal and a == 0:
-            # on the DIAGONAL block, q-tiles strictly above a k-tile are
-            # fully masked — skip them (mirrors the forward's tile-skip)
-            return nk, lambda j: (j * tk) // tq, lambda i, j: _causal_mask(
-                my, my, sb, i * tq, j * tk, tq, tk)
-        return nk, lambda j: 0, lambda i, j: None
+            return _zigzag_arrival(a, my, P, sb, tq, tk)
+        if causal:
+            return _band_arrival(a, my, sb, tq, tk, window)
+        return _full_tiles(sb, tq, tk), lambda i, j: None
 
     def pair_grads_tiled(kv_at, dkv_at, init_zero, bounds):
         """Flash-tiled pair gradients: dK/dV tiles ride the inner-loop
@@ -883,34 +998,43 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
         ``kv_at(row0, n)`` addresses the arrived K/V planes; ``bounds``
         are ``tiled_bounds``'.  The protocol sees the exact same consume
         window as the resident fold."""
-        nq = sb // tq
-        k_end, q_lo_of, mask_of = bounds
-        for h in range(hq):
+        t, mask_of = bounds
+
+        def head(h, _):
             kvh = h // g
 
-            # zero the dK/dV tiles only for the FIRST query head of
-            # each K/V group: later heads of the group must accumulate
-            # into (not overwrite) what earlier heads stored — review
-            # round 5 caught the per-head re-zeroing dropping all but
-            # the last head's own-block contribution under GQA
-            zero_here = init_zero and (h % g == 0)
-
-            def j_body(j, _, h=h, kvh=kvh, zero_here=zero_here):
-                kr = kvh * sb + j * tk
+            def j_body(j, _):
+                kr = _head_row(kvh, sb) + j * tk
                 copy_par((kv_at(kr, tk), kt_vmem),
                          (kv_at(hkv * sb + kr, tk), vt_vmem))
-                if zero_here:
-                    dk0 = jnp.zeros((tk, d), jnp.float32)
-                    dv0 = jnp.zeros((tk, d), jnp.float32)
-                else:
+
+                def load():
                     copy_par((dkv_at(kr, tk), accb_vmem),
                              (dkv_at(hkv * sb + kr, tk), accb2_vmem))
-                    dk0 = accb_vmem[:]
-                    dv0 = accb2_vmem[:]
 
-                def i_body(i, carry, h=h):
+                def zero():
+                    accb_vmem[:] = jnp.zeros((tk, d), jnp.float32)
+                    accb2_vmem[:] = jnp.zeros((tk, d), jnp.float32)
+
+                # zero the dK/dV tiles only for the FIRST query head of
+                # each K/V group: later heads of the group must
+                # accumulate into (not overwrite) what earlier heads
+                # stored (re-zeroing per head would drop all but the
+                # last head's own-block contribution under GQA)
+                if not init_zero:
+                    load()
+                elif g == 1:
+                    zero()
+                else:
+                    first = lax.rem(h, g) == 0
+                    pl.when(first)(zero)
+                    pl.when(jnp.logical_not(first))(load)
+                dk0 = accb_vmem[:]
+                dv0 = accb2_vmem[:]
+
+                def i_body(i, carry):
                     dk, dv = carry
-                    r0 = h * sb + i * tq
+                    r0 = _head_row(h, sb) + i * tq
                     copy_par((q_hbm.at[pl.ds(r0, tq)], qt_vmem),
                              (do_hbm.at[pl.ds(r0, tq)], dot_vmem),
                              (lse_hbm.at[pl.ds(r0, tq)], lset_vmem),
@@ -927,14 +1051,17 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                     copy_sync(dqt_vmem, dq_hbm.at[pl.ds(r0, tq)])
                     return dk + dk_c, dv + dv_c
 
-                dk, dv = lax.fori_loop(q_lo_of(j), nq, i_body, (dk0, dv0))
+                dk, dv = lax.fori_loop(*t.q_of(j), i_body, (dk0, dv0))
                 accb_vmem[:] = dk
                 accb2_vmem[:] = dv
                 copy_par((accb_vmem, dkv_at(kr, tk)),
                          (accb2_vmem, dkv_at(hkv * sb + kr, tk)))
                 return 0
 
-            lax.fori_loop(0, k_end, j_body, 0)
+            lax.fori_loop(t.k_lo, t.k_hi, j_body, 0)
+            return 0
+
+        lax.fori_loop(0, hq, head, 0)
 
     if tiles is None:
         # stage the rank-local residuals once (independent → parallel)
@@ -954,7 +1081,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     # planes straight from the input (IN the input/wire dtype), dK/dV
     # planes = my own f32 contribution (every other rank's accumulates
     # en route)
-    copy_sync(kv_hbm, own_kv_hbm)
+    if P > 1:
+        copy_sync(kv_hbm, own_kv_hbm)
     if tiles is None:
         copy_sync(kv_hbm, kv_vmem)
         dkv_vmem[:] = jnp.zeros((kv_rows, d), jnp.float32)
@@ -973,7 +1101,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
         if not pipelined:
             snd(0).wait()
 
-    for a in range(1, P + 1):
+    # arrivals 1..P (the last my own block coming home); none at P = 1
+    for a in (range(1, P + 1) if P > 1 else ()):
         slot = a % 2
         if pipelined:
             snd(a - 1).wait_recv()  # arrival a lands in comm[slot]
@@ -986,7 +1115,7 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                 if tiles is None:
                     copy_sync(comm_kv_hbm.at[slot], kv_vmem)
                     copy_sync(comm_dkv_hbm.at[slot], dkv_vmem)
-                    pair_grads(kv_idx, masked=False)
+                    pair_grads(kv_idx, masked=window is not None)
                     copy_sync(dkv_vmem, comm_dkv_hbm.at[slot])
                 else:
                     pair_grads_tiled(
@@ -999,9 +1128,12 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             if causal and not zigzag:
                 # the diagonal block is always arrival 0 (kv_idx == my
                 # iff a ≡ 0 mod P), so arrivals 1..P-1 are either
-                # strictly past (mask provably all-True — skip its
-                # materialization) or future (skip everything)
-                pl.when(kv_idx < my)(consume)
+                # strictly past (mask provably all-True without a
+                # window — skip its materialization) or future (skip
+                # everything); a past block wholly before the window
+                # folds nothing either
+                if not _before_window(a, sb, window):
+                    pl.when(kv_idx < my)(consume)
             else:
                 # full attention, or zigzag: every arrival folds (half
                 # a block on the zigzag layout)
@@ -1040,7 +1172,7 @@ def _ring_neighbors(axis_name: str, size: int) -> jnp.ndarray:
 
 
 def _fallback_attention(q, k, v, axis_name: str, size: int, scale: float,
-                        causal: bool = False):
+                        causal: bool = False, window: Optional[int] = None):
     """The same online-softmax ring as jax ops over ppermute — the
     vma/multi-axis interpreter path, and the recompute body of the
     out-of-budget custom-vjp backward.  Accepts both layouts ([Sb, d]
@@ -1066,7 +1198,8 @@ def _fallback_attention(q, k, v, axis_name: str, size: int, scale: float,
         mask = None
         if causal:
             kv_idx = lax.rem(my - step + size, size)
-            mask = _causal_mask(my, kv_idx, sb)  # shared by every head
+            # shared by every head
+            mask = _causal_mask(my, kv_idx, sb, window=window)
         for h in range(hq):
             m[h], l[h], o[h] = _online_fold(q3[h], kb[h // g], vb[h // g],
                                             m[h], l[h], o[h], scale, mask)
@@ -1080,11 +1213,13 @@ def _fallback_attention(q, k, v, axis_name: str, size: int, scale: float,
 def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           axis_name: str, size: int, *,
                           scale: float = None, causal: bool = False,
+                          window: Optional[int] = None,
                           interpret: bool = False,
                           vmem_limit_bytes: Optional[int] = None
                           ) -> jnp.ndarray:
-    """Exact attention (full, or causal with ``causal=True``) over a
-    sequence-sharded axis.  Two shapes:
+    """Exact attention (full, causal with ``causal=True``, or causal
+    within a sliding ``window``) over a sequence-sharded axis.  Two
+    shapes:
 
     * single-head: ``q``/``k``/``v`` = this device's [Sb, dh] blocks;
     * multi-head / GQA: ``q`` = [Hq, Sb, dh], ``k``/``v`` =
@@ -1108,12 +1243,28 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     in resident or flash-tiled mode per its VMEM plan; only an
     impossible budget recomputes through the pure-jax ring.
 
-    Causal layout: with ``causal=True``, ``size >= 2`` and both folds
-    tiled from tiles that divide Sb/2, the kernels run on the zigzag
-    layout (module docstring), which gives every rank the same causal
-    work; two ppermutes per array move the halves in and out.  The
-    blocks this function takes and returns stay contiguous in rank
-    order, and the circulation protocol is the same on either layout."""
+    Sliding window (``causal=True, window=W``): the query at global
+    position i attends key j iff ``i - W < j <= i`` — W keys, itself
+    included, as Hugging Face's ``sliding_window`` means.  The tiled
+    folds skip the k tiles wholly before a query tile's window and mask
+    its edge tile as they mask the diagonal; an arrival wholly before
+    every local query's window folds nothing (it still circulates).
+    Windowed kernels are named ``mpi_tpu_swa_attention_fwd`` / ``_bwd``,
+    and the ``attention_window`` mpit pvar counts the traces that take
+    the windowed tile ranges.
+
+    ``size == 1`` runs the same kernels as a ring of one: the step-0
+    fold alone, with no landing slots, RDMA, barrier or credit, and
+    none of a collective's compiler parameters.
+
+    Causal layout: with ``causal=True``, no window, ``size >= 2`` and
+    both folds tiled from tiles that divide Sb/2, the kernels run on
+    the zigzag layout (module docstring), which gives every rank the
+    same causal work; two ppermutes per array move the halves in and
+    out.  The blocks this function takes and returns stay contiguous in
+    rank order, and the circulation protocol is the same on either
+    layout.  A windowed call keeps the contiguous layout, which is
+    already balanced for W <= Sb."""
     if q.ndim not in (2, 3):
         raise ValueError(
             f"ring attention wants [Sb, dh] or [H, Sb, dh] blocks, got "
@@ -1147,6 +1298,10 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise NotImplementedError(
             f"block rows must be a multiple of {sub} ({jnp.dtype(q.dtype)} "
             f"sublane tile), got {sb}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"a sliding window wants causal=True and window >= 1, got "
+            f"causal={causal} window={window}")
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     # shared dtype/vma/mesh probing with the ring collectives (f32/bf16)
@@ -1159,7 +1314,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # axis interpreter fallback, instead of the former
     # NotImplementedError that made an over-tight budget fatal.  A
     # causal ring prefers tiles that divide Sb/2 (the zigzag layout's).
-    halves = causal and size >= 2
+    halves = causal and window is None and size >= 2
     try:
         _, tiles = _vmem_plan(sb, d, hq, hkv, q.dtype, vmem_limit_bytes,
                               False, halves)
@@ -1173,49 +1328,33 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"ppermute ring fallback; timings will not reflect the RDMA "
             f"kernel. ({e})", RuntimeWarning, stacklevel=2)
         mpit.count(attention_oob=1)
-        return _fallback_attention(q, k, v, axis_name, size, scale, causal)
+        return _fallback_attention(q, k, v, axis_name, size, scale, causal,
+                                   window)
     bwd_mode, bwd_tiles = _vmem_plan(sb, d, hq, hkv, q.dtype,
                                      vmem_limit_bytes, True, halves)
     bwd_fused = bwd_mode in ("resident", "tiled")
 
-    def _per_head(fn, q_, k_, v_):
-        """Apply a [Sb,dh]-block function per query head (GQA maps
-        query head h to K/V head h // (Hq//Hkv))."""
-        if not multihead:
-            return fn(q_, k_, v_)
-        return jnp.stack([fn(q_[h], k_[h // (hq // hkv)],
-                             v_[h // (hq // hkv)]) for h in range(hq)])
-
-    def _local_one(qh, kh, vh):
-        m0 = jnp.full((sb, 1), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((sb, 1), jnp.float32)
-        o0 = jnp.zeros((sb, d), jnp.float32)
-        mask = (_causal_mask(jnp.int32(0), jnp.int32(0), sb)
-                if causal else None)
-        _, l1, o1 = _online_fold(qh, kh, vh, m0, l0, o0, scale, mask)
-        return (o1 / l1).astype(q.dtype)
-
     def _reference(q_, k_, v_):
         """Pure-jax ring (differentiable) — primal-identical to the
         kernel; the out-of-budget custom-vjp backward recomputes
-        through it.  Only reached with size >= 2 (size == 1 returns
-        below, before any _reference call site)."""
+        through it."""
         return _fallback_attention(q_, k_, v_, axis_name, size, scale,
-                                   causal)
+                                   causal, window)
 
-    if size == 1:
-        return _per_head(_local_one, q, k, v)
-    if (vma_on or multi_axis) and interpret:
+    if (vma_on or (multi_axis and size > 1)) and interpret:
         _fallback("ring_attention", axis_name, vma_on, multi_axis)
         return _reference(q, k, v)
     # causal layout (module docstring): zigzag where both folds are
     # tiled from half blocks; every other case keeps contiguous blocks
     zigzag = (halves and tiles is not None and bwd_mode == "tiled"
               and all((sb // 2) % t == 0 for t in tiles + bwd_tiles))
-    if zigzag:
+    if zigzag or (window is not None and tiles is not None):
         from .. import mpit
 
-        mpit.count(attention_zigzag=1)
+        mpit.count(attention_zigzag=int(zigzag),
+                   attention_window=int(window is not None))
+    ring = size > 1
+    kind = "ring" if window is None else "swa"
 
     vma = (frozenset(jax.typeof(q).vma) | {axis_name} if vma_on
            else frozenset())
@@ -1236,9 +1375,11 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
                             for _ in outs),
             scratch_shapes=scratch,
+            # a ring of one is a local kernel: no collective id, no side
+            # effects, so its executable caches like any other
             compiler_params=None if interpret else pltpu.CompilerParams(
-                collective_id=collective_id, has_side_effects=True,
-                vmem_limit_bytes=vmem_limit_bytes),
+                collective_id=collective_id if ring else None,
+                has_side_effects=ring, vmem_limit_bytes=vmem_limit_bytes),
             interpret=interpret,
             name=name,
         )(params, *args)
@@ -1259,11 +1400,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             _kernel, axis_name=axis_name, size=size, sb=sb, d=d,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=tiles,
-            with_lse=with_lse, zigzag=zigzag)
+            with_lse=with_lse, zigzag=zigzag, window=window)
         outs = [((hq * sb, d), jnp.float32)]
         if with_lse:
             outs.append(((hq * sb, _LANES), jnp.float32))
-        outs.append(((2, 2 * hkv * sb, d), q.dtype))     # landing slots
+        if ring:
+            outs.append(((2, 2 * hkv * sb, d), q.dtype))  # landing slots
         if tiles is None:
             scratch = [
                 pltpu.VMEM((hq * sb, d), q.dtype),       # Q (all heads)
@@ -1295,8 +1437,8 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        res = _hbm_call("mpi_tpu_ring_attention_fwd", kern, outs, scratch,
-                        16, params, qf, kv)
+        res = _hbm_call(f"mpi_tpu_{kind}_attention_fwd", kern, outs,
+                        scratch, 16, params, qf, kv)
         out = res[0].astype(q_.dtype)
         out = out.reshape(hq, sb, d) if multihead else out
         return (out, res[1]) if with_lse else out
@@ -1320,14 +1462,16 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kern = functools.partial(
             _bwd_kernel, axis_name=axis_name, size=size, sb=sb, d=d,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
-            causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles, zigzag=zigzag)
+            causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles, zigzag=zigzag,
+            window=window)
         kv_rows = 2 * hkv * sb
         outs = [((hq * sb, d), jnp.float32),             # dQ
-                ((kv_rows, d), jnp.float32),             # dK/dV (home)
-                ((kv_rows, d), q.dtype),                 # own [K,V] (wire)
-                ((kv_rows, d), jnp.float32),             # own [dK,dV]
-                ((2, kv_rows, d), q.dtype),              # K/V landing slots
-                ((2, kv_rows, d), jnp.float32)]          # dK/dV landing slots
+                ((kv_rows, d), jnp.float32)]             # dK/dV (home)
+        if ring:
+            outs += [((kv_rows, d), q.dtype),            # own [K,V] (wire)
+                     ((kv_rows, d), jnp.float32),        # own [dK,dV]
+                     ((2, kv_rows, d), q.dtype),         # K/V landing slots
+                     ((2, kv_rows, d), jnp.float32)]     # dK/dV landing slots
         if bwd_tiles is None:
             scratch = [
                 pltpu.VMEM((hq * sb, d), q.dtype),           # Q
@@ -1358,7 +1502,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        dq, dkv = _hbm_call("mpi_tpu_ring_attention_bwd", kern, outs,
+        dq, dkv = _hbm_call(f"mpi_tpu_{kind}_attention_bwd", kern, outs,
                             scratch, 17, params, qf, kv, dof, lse,
                             delta)[:2]
         dq = dq.astype(q_.dtype)

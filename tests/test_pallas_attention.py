@@ -1001,18 +1001,21 @@ def test_causal_tiles_prefer_halves(sb, sub, first, same):
     assert ts[:len(half)] == half == sorted(half, reverse=True)
 
 
-def _tile_pairs(diag, past, nq, nk, tq, tk):
-    """Tile pairs (i, j) one arrival folds, from the forward's bounds and,
-    separately, from the backward's: the two must agree."""
-    from mpi_tpu.tpu.pallas_attention import _zigzag_tiles
-
-    q_lo, k_hi, k_end, q_lo_of = _zigzag_tiles(diag, past, nq, nk, tq, tk)
-    fwd = {(i, j) for i in range(int(q_lo), nq)
-           for j in range(int(k_hi(i)))}
-    bwd = {(i, j) for j in range(int(k_end))
-           for i in range(int(q_lo_of(j)), nq)}
+def _pairs_of(t):
+    """Tile pairs (i, j) one arrival's ``_Tiles`` fold, from the forward's
+    bounds and, separately, from the backward's: the two must agree."""
+    span = lambda lo_hi: range(*(int(x) for x in lo_hi))
+    fwd = {(i, j) for i in span((t.q_lo, t.q_hi)) for j in span(t.k_of(i))}
+    bwd = {(i, j) for j in span((t.k_lo, t.k_hi)) for i in span(t.q_of(j))}
     assert fwd == bwd
     return fwd
+
+
+def _tile_pairs(diag, past, nq, nk, tq, tk):
+    """Tile pairs (i, j) one zigzag arrival folds (forward == backward)."""
+    from mpi_tpu.tpu.pallas_attention import _zigzag_tiles
+
+    return _pairs_of(_zigzag_tiles(diag, past, nq, nk, tq, tk))
 
 
 def test_zigzag_balances_the_cell():
@@ -1061,3 +1064,256 @@ def test_zigzag_tiles_cover_causal_pairs_once(Pn, Sb, tq, tk):
     S = Pn * Sb
     assert set(seen.values()) == {1}
     assert set(seen) == {(a, b) for a in range(S) for b in range(a + 1)}
+
+
+# -- sliding window, and P = 1 on the kernels --------------------------------
+
+
+def _dense_window(q, k, v, window=None):
+    """Dense causal GQA attention in float32 on the whole sequence, each
+    query attending the ``window`` newest keys up to itself (all of them
+    where ``window`` is None)."""
+    g = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
+    s = jnp.einsum("hqd,hkd->hqk", q, k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    n = q.shape[1]
+    i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    keep = (j <= i) if window is None else (j <= i) & (j > i - window)
+    s = jnp.where(keep, s, -jnp.inf)
+    return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0):
+    """(kernel out, dq, dk, dv), (dense out, dq, dk, dv) and the
+    ``attention_window`` pvar's rise, for causal attention over ``Pn``
+    ranks of ``Sb`` rows through ``jax.grad``."""
+    from mpi_tpu import mpit
+
+    rng = np.random.RandomState(seed)
+    q, k, v, ct = (rng.randn(h, Pn * Sb, 128).astype(np.float32)
+                   for h in (Hq, Hkv, Hkv, Hq))
+
+    def loss(qb, kb, vb, cb):
+        out = pallas_ring_attention(qb, kb, vb, "world", Pn, causal=True,
+                                    window=window, interpret=True,
+                                    vmem_limit_bytes=limit)
+        return jnp.sum(out * cb), out
+
+    spec = P(None, "world")
+    before = mpit.pvar_read("attention_window")
+    (dq, dk, dv), out = jax.jit(jax.shard_map(
+        jax.grad(loss, argnums=(0, 1, 2), has_aux=True),
+        mesh=default_mesh(Pn), in_specs=(spec,) * 4,
+        out_specs=((spec,) * 3, spec), check_vma=False))(q, k, v, ct)
+    rise = mpit.pvar_read("attention_window") - before
+
+    def dense_loss(q_, k_, v_):
+        return jnp.sum(_dense_window(q_, k_, v_, window) * ct)
+
+    want = [_dense_window(q, k, v, window)] + list(
+        jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v))
+    return [np.asarray(x) for x in (out, dq, dk, dv)], \
+        [np.asarray(x) for x in want], rise
+
+
+# tiled at Sb 32 with 8-row tiles (the budget admits no larger forward or
+# backward tile); W < tile, W not a multiple of the tile, W = Sb, W >= S
+_TILE8 = 60_000
+
+
+@pytest.mark.parametrize("Pn,window,Hq,Hkv", [
+    (1, 5, 6, 1), (1, 13, 1, 1), (1, 32, 6, 1), (1, 100, 2, 2),
+    (2, 5, 2, 2), (2, 13, 6, 1), (2, 32, 1, 1), (2, 64, 6, 1),
+    (4, 5, 6, 1), (4, 13, 2, 2), (4, 32, 6, 1), (4, 200, 1, 1)])
+def test_window_parity(Pn, window, Hq, Hkv):
+    """Sliding-window causal attention on the tiled kernels (8-row tiles
+    of 32-row blocks) at P = 1, 2 and 4: the output and the three
+    gradients match dense windowed attention, GQA groups of 6 and 1; a
+    window of the whole sequence or more is plain causal attention."""
+    from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
+
+    for bwd in (False, True):
+        assert attention_vmem_plan(32, 128, Hq, Hkv, jnp.float32, _TILE8,
+                                   for_backward=bwd) == ("tiled", (8, 8))
+    got, want, rise = _window_grads(Pn, 32, Hq, Hkv, window, _TILE8,
+                                    seed=Pn * 100 + window)
+    assert rise == 1
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+    if window >= Pn * 32:
+        causal, _, _ = _window_grads(Pn, 32, Hq, Hkv, None, _TILE8,
+                                     seed=Pn * 100 + window)
+        for g, c in zip(got, causal):
+            np.testing.assert_allclose(g, c, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("Pn,window", [(1, 7), (2, 13), (4, 9)])
+def test_window_parity_resident(Pn, window):
+    """The resident folds (whole blocks in VMEM) mask the window too, and
+    a block wholly before every query's window folds nothing."""
+    got, want, rise = _window_grads(Pn, 16, 6, 1, window, None, seed=window)
+    assert rise == 0  # no tile ranges on the resident plan
+    for g, r in zip(got, want):
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+
+
+@pytest.mark.parametrize("limit", [None, _TILE8], ids=["resident", "tiled"])
+@pytest.mark.parametrize("Hq,Hkv", [(1, 1), (4, 2), (6, 1)])
+def test_size_one_causal_runs_the_kernels(Hq, Hkv, limit):
+    """P = 1 causal attention runs the fused kernels as a ring of one —
+    resident or tiled, forward and fused backward — and matches dense
+    causal attention and its gradients."""
+    got, want, _ = _window_grads(1, 32, Hq, Hkv, None, limit, seed=Hq)
+    for g, r in zip(got, want):
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+
+
+@pytest.mark.parametrize("Pn", [1, 4])
+def test_size_one_emits_no_ring(Pn):
+    """A ring of one emits no RDMA, neighbour barrier or credit, and its
+    kernels are not collectives (no side effects, no collective id), so
+    the executable caches like any local kernel; a ring of four has all
+    of them."""
+    mesh = AbstractMesh((Pn,), ("s",))
+
+    def loss(q, k, v):
+        out = pallas_ring_attention(q, k, v, "s", Pn, causal=True,
+                                    window=24, interpret=False,
+                                    vmem_limit_bytes=_TILE8)
+        return jnp.sum(out ** 2)
+
+    jf = jax.jit(jax.shard_map(
+        lambda q, k, v: jax.grad(loss, argnums=(0, 1, 2))(q, k, v),
+        mesh=mesh, in_specs=(P(None, "s"),) * 3,
+        out_specs=(P(None, "s"),) * 3, check_vma=False))
+    q = jax.ShapeDtypeStruct((6, Pn * 32, 128), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, Pn * 32, 128), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(jf)(q, kv, kv))
+    ring = Pn > 1
+    for prim in ("semaphore_signal", "get_barrier_semaphore", "device_id"):
+        assert (prim in jaxpr) == ring, prim
+    assert ("has_side_effects=True" in jaxpr) == ring
+    assert jaxpr.count("mpi_tpu_swa_attention_") == 2
+
+
+@pytest.mark.parametrize("off,window,sb,tq,tk", [
+    (0, None, 32, 8, 8), (0, 5, 32, 8, 8), (0, 13, 32, 8, 4),
+    (0, 9, 32, 4, 8), (0, 32, 32, 8, 8), (0, 100, 32, 8, 8),
+    (32, 5, 32, 8, 8), (32, 13, 32, 4, 8), (32, 33, 32, 8, 8),
+    (64, 33, 32, 8, 8), (64, 34, 32, 8, 8), (96, 40, 32, 8, 4),
+    (32, None, 32, 8, 8), (16, 20, 48, 16, 8)])
+def test_window_tiles_visit_exactly_unmasked_pairs(off, window, sb, tq, tk):
+    """By brute force: the forward's and the backward's tile ranges on the
+    contiguous layout visit the same tile pairs, and exactly those that
+    hold an element the causal window keeps (query row r at ``off + r``
+    against key row c: ``0 <= off + r - c < window``)."""
+    from mpi_tpu.tpu.pallas_attention import _band_tiles
+
+    visited = _pairs_of(_band_tiles(off, window, sb, tq, tk))
+    r = np.arange(sb)[:, None] + off
+    c = np.arange(sb)[None, :]
+    keep = (c <= r) & ((c > r - window) if window else True)
+    want = {(i, j) for i in range(sb // tq) for j in range(sb // tk)
+            if keep[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk].any()}
+    assert visited == want
+
+
+def _window_case(case):
+    """(a shard_map'd call of ring attention, its input aval) for one way
+    of calling it; tracing it counts the windowed tile ranges or not."""
+    Pn, Sb, d = (1, 32, 128) if case.startswith("size1") else (4, 32, 128)
+    kw = dict(causal=True, window=13, interpret=True,
+              vmem_limit_bytes=_TILE8)
+    check_vma = False
+    if case == "causal":
+        kw["window"] = None
+    elif case == "resident":
+        kw.pop("vmem_limit_bytes")
+    elif case == "vma_fallback":
+        check_vma = True
+    elif case == "forward_oob":
+        kw["vmem_limit_bytes"] = 1
+
+    def loss(qb):
+        return jnp.sum(pallas_ring_attention(qb, qb, qb, "world", Pn, **kw))
+
+    aval = jax.ShapeDtypeStruct((Pn * Sb, d), jnp.float32)
+    return jax.shard_map(jax.grad(loss), mesh=default_mesh(Pn),
+                         in_specs=P("world"), out_specs=P("world"),
+                         check_vma=check_vma), aval
+
+
+@pytest.mark.parametrize("case,engaged", [
+    ("windowed", 1), ("size1_windowed", 1), ("causal", 0), ("resident", 0),
+    ("vma_fallback", 0), ("forward_oob", 0)])
+def test_window_counted_once_per_trace(case, engaged):
+    """``attention_window`` rises once per trace where windowed attention
+    takes the tiled kernels' windowed tile ranges, and on no other path;
+    a windowed call never takes the zigzag layout."""
+    import warnings
+
+    from mpi_tpu import mpit
+
+    fn, aval = _window_case(case)
+    before = {p: mpit.pvar_read(p)
+              for p in ("attention_window", "attention_zigzag")}
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # the fallbacks'
+        jax.jit(fn).trace(aval)
+    assert mpit.pvar_read("attention_window") - \
+        before["attention_window"] == engaged
+    assert mpit.pvar_read("attention_zigzag") - \
+        before["attention_zigzag"] == int(case == "causal")
+
+
+def test_window_diagnosed():
+    """A window needs causal attention and at least one key."""
+    mesh = default_mesh(1)
+
+    def run(**kw):
+        def f(x):
+            q = jnp.zeros((8, 128), jnp.float32)
+            return pallas_ring_attention(q, q, q, "world", 1,
+                                         interpret=True, **kw)
+
+        jax.jit(jax.shard_map(lambda x: f(x)[:0], mesh=mesh,
+                              in_specs=P("world"), out_specs=P("world"),
+                              check_vma=False))(jnp.zeros(1, jnp.float32))
+
+    with pytest.raises(ValueError, match="sliding window"):
+        run(window=4)
+    with pytest.raises(ValueError, match="sliding window"):
+        run(causal=True, window=0)
+
+
+def test_size_one_on_a_multi_axis_mesh_runs_the_kernel():
+    """A ring of one over the ``sp`` axis of a 2-D (dp×sp) mesh sends no
+    RDMA, so the interpreter runs the kernel itself — no fallback."""
+    import warnings
+
+    from jax.sharding import Mesh
+
+    from mpi_tpu import mpit
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1), ("dp", "sp"))
+    rng = np.random.RandomState(59)
+    q = rng.randn(4, 16, 128).astype(np.float32)  # one block per dp slice
+
+    def f(qb):
+        return pallas_ring_attention(qb[0], qb[0], qb[0], "sp", 1,
+                                     causal=True, window=5,
+                                     interpret=True)[None]
+
+    before = mpit.pvar_read("pallas_ring_fallbacks")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        got = np.asarray(jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P("dp", "sp", None),
+            out_specs=P("dp", "sp", None), check_vma=False))(q))
+    assert mpit.pvar_read("pallas_ring_fallbacks") == before
+    for sl in range(4):
+        want = _dense_window(q[sl][None], q[sl][None], q[sl][None], 5)[0]
+        np.testing.assert_allclose(got[sl], want, rtol=2e-4, atol=2e-5)

@@ -155,6 +155,33 @@ def test_ring_attention_compiles(mesh4, plan, backward):
     assert ("mpi_tpu_ring_attention_bwd" in text) == backward
 
 
+@pytest.mark.parametrize("window", [None, 1024], ids=["causal", "window"])
+def test_attention_ring_of_one_compiles_for_one_chip(topo, window):
+    """Causal and sliding-window attention, forward and fused backward, on
+    one chip: a ring of one, whose kernels are local (no side effects, no
+    collective), GQA with a group of 6, tiled at Sb 4096."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("sp",))
+    spec = P(None, "sp")
+    q = jax.ShapeDtypeStruct((6, 4096, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    kv = jax.ShapeDtypeStruct((1, 4096, 128), jnp.bfloat16,
+                              sharding=NamedSharding(mesh, spec))
+
+    def fn(qb, kb, vb):
+        _, vjp = jax.vjp(lambda a, b, c: pallas_ring_attention(
+            a, b, c, "sp", 1, causal=True, window=window), qb, kb, vb)
+        return vjp(jnp.ones_like(qb))
+
+    text = _compile(jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                                  out_specs=(spec,) * 3), q, kv, kv).as_text()
+    kind = "ring" if window is None else "swa"
+    assert f"mpi_tpu_{kind}_attention_fwd" in text
+    assert f"mpi_tpu_{kind}_attention_bwd" in text
+    assert text.count("tpu_custom_call") == 2
+    assert "has_side_effect=true" not in text
+    assert "collective-permute" not in text
+
+
 def test_jacobi_step_8192_compiles_for_one_chip(topo):
     """The single-chip entry program (``__graft_entry__.entry``'s step) at
     the chip_smoke size: an 8192² f32 grid on one described v5e."""
