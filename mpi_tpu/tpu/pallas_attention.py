@@ -47,7 +47,11 @@ VMEM budget:
   in HBM; each arrival loops over [tq]-row query tiles and
   [tk]-row K/V tiles (``lax.fori_loop``), staging each tile through
   small VMEM buffers, so the live score block is [tq, tk] and the
-  block size is bounded by HBM, not VMEM.  Tile sizes are the largest
+  block size is bounded by HBM, not VMEM.  The innermost loops of both
+  folds are software-pipelined (``_mk_tile_loop``): two buffer slots,
+  the next tile pair's copies running while the MXU folds this one
+  (``attention_prefetch`` mpit pvar counts the traces that build
+  them).  Tile sizes are the largest
   sublane-aligned divisors of Sb, at most 512, that fit the budget (a
   causal ring takes those that divide Sb/2 first, for the zigzag
   layout below).
@@ -429,10 +433,11 @@ def _mk_copy_sync(copy_sem):
 
 def _mk_copy_par(par_sems):
     """Start INDEPENDENT local DMAs together, then wait them all — the
-    tiled folds stage several disjoint tiles per step (k+v; the m/l/o
-    state; the backward's five residuals) and serializing them exposes
-    every transfer's full HBM latency on chip (round 5).  Each copy
-    gets its own semaphore by POSITION (all indices Python-static)."""
+    folds stage several disjoint buffers at once (the m/l/o state; the
+    backward's k/v and dk/dv tiles; the resident residuals) and
+    serializing them exposes every transfer's full HBM latency on chip
+    (round 5).  Each copy gets its own semaphore by POSITION (all
+    indices Python-static)."""
     def copy_par(*pairs):
         cps = [pltpu.make_async_copy(src, dst, par_sems.at[i])
                for i, (src, dst) in enumerate(pairs)]
@@ -442,6 +447,66 @@ def _mk_copy_par(par_sems):
             cp.wait()
 
     return copy_par
+
+
+def _mk_tile_loop(sems):
+    """The tiled folds' innermost loop, software-pipelined over two VMEM
+    buffer slots: iteration n stages its tiles into slot n % 2 while
+    iteration n - 1 folds out of the other slot.  ``sems`` is a [2, c]
+    DMA semaphore array, one row per slot, so copies in flight into one
+    slot never share a semaphore with the other slot's; the last column
+    carries the write-back.  Every copy a loop starts is waited before
+    the loop returns, so each fold still completes, in program order,
+    before the circulation's next protocol event."""
+    store_col = sems.shape[1] - 1
+
+    def copies(pairs, slot, col0=0):
+        return [pltpu.make_async_copy(src, dst, sems.at[slot, col0 + c])
+                for c, (src, dst) in enumerate(pairs)]
+
+    def start(cps):
+        for cp in cps:
+            cp.start()
+
+    def wait(cps):
+        for cp in cps:
+            cp.wait()
+
+    def loop(lo, hi, loads, body, carry, store=None):
+        """``lax.fori_loop(lo, hi, ...)`` (bounds Python ints or traced)
+        in which iteration n folds the tiles ``loads(n, slot)`` — (src,
+        dst) pairs, each dst in buffer slot ``slot`` — by ``body(n, slot,
+        carry)``.  Iteration n + 1's copies start before iteration n
+        waits for its own.  ``store(n, slot)``: one (src, dst) write-back
+        started after the fold and waited one iteration later, before
+        any copy into its slot starts again, or at the loop's end."""
+        def slot_of(n):
+            return lax.rem(n, 2)
+
+        def write_back(n):
+            return copies([store(n, slot_of(n))], slot_of(n), store_col)
+
+        pl.when(lo < hi)(
+            lambda: start(copies(loads(lo, slot_of(lo)), slot_of(lo))))
+
+        def step(n, carry):
+            slot, other = slot_of(n), 1 - slot_of(n)
+            if store is not None:
+                pl.when(n > lo)(lambda: wait(write_back(n - 1)))
+            pl.when(n + 1 < hi)(
+                lambda: start(copies(loads(n + 1, other), other)))
+            wait(copies(loads(n, slot), slot))
+            carry = body(n, slot, carry)
+            if store is not None:
+                start(write_back(n))
+            return carry
+
+        carry = lax.fori_loop(lo, hi, step, carry)
+        if store is not None:
+            pl.when(hi > lo)(lambda: wait(write_back(hi - 1)))
+        return carry
+
+    return loop
 
 
 def _pair_grad_tile(qh, doh, lse1, delta1, kb, vb, scale, mask=None):
@@ -563,11 +628,11 @@ def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
         if resident <= limit:
             return ("resident", None)
         for t in _tile_sizes(sb, sub, halves):
-            tiled = (2 * t * d * esz           # q/do tiles
-                     + 2 * t * _LANES * 4      # lse/delta tiles
+            tiled = (2 * (2 * t * d * esz      # q/do tiles, two slots
+                          + 2 * t * _LANES * 4  # lse/delta tiles
+                          + t * d * 4)         # dq tile
                      + 2 * t * d * esz         # k/v tiles (wire dtype)
                      + 2 * t * d * 4           # dk/dv staging buffers
-                     + t * d * 4               # dq tile
                      + 2 * t * d * 4           # dk/dv loop carries
                      + 4 * t * t * 4           # s/p/dp/ds temporaries
                      + 2 * t * d * 4)          # fold temporaries
@@ -584,20 +649,21 @@ def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                 + 2 * sb * d * 4)              # fold temporaries
     if resident <= limit:
         return ("resident", None)
+
+    def tiled(t):
+        return (t * d * esz                    # q tile
+                + 2 * 2 * t * d * esz          # k/v tiles, two slots
+                + 2 * t * _LANES * 4           # m, l tiles
+                + t * d * 4                    # o tile
+                + 2 * t * t * 4                # score tile temporaries
+                + 2 * t * d * 4)               # fold temporaries
+
     for t in _tile_sizes(sb, sub, halves):
-        tiled = (3 * t * d * esz               # q/k/v tiles
-                 + 2 * t * _LANES * 4          # m, l tiles
-                 + t * d * 4                   # o tile
-                 + 2 * t * t * 4               # score tile temporaries
-                 + 2 * t * d * 4)              # fold temporaries
-        if tiled <= limit:
+        if tiled(t) <= limit:
             return ("tiled", (t, t))
-    t = sub
-    need = 3 * t * d * esz + 2 * t * _LANES * 4 + t * d * 4 \
-        + 2 * t * t * 4 + 2 * t * d * 4
     raise NotImplementedError(
         f"ring attention cannot fit VMEM budget {limit} bytes: even the "
-        f"minimal {t}-row tile at d={d} needs ~{need} bytes "
+        f"minimal {sub}-row tile at d={d} needs ~{tiled(sub)} bytes "
         f"(Sb={sb}, Hq={hq}, Hkv={hkv}, {jnp.dtype(dtype).name}). "
         f"Raise vmem_limit_bytes or shrink the head dim.")
 
@@ -651,9 +717,10 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
              par_sems) = refs
     else:
         (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
-         mt_vmem, lt_vmem, ot_vmem,
+         mt_vmem, lt_vmem, ot_vmem, tile_sems,
          copy_sem, send_sem, recv_sem, credit_sem, par_sems) = refs
         tq, tk = tiles
+        tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
@@ -739,18 +806,20 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                         l0 = lt_vmem[:, :1]
                         o0 = ot_vmem[:]
 
-                    def k_body(j, carry):
+                    def kv_tiles(j, slot):
+                        r0 = kvh * sb + j * tk
+                        return ((src.at[pl.ds(r0, tk)], kt_vmem.at[slot]),
+                                (src.at[pl.ds(hkv * sb + r0, tk)],
+                                 vt_vmem.at[slot]))
+
+                    def k_body(j, slot, carry):
                         m, l, o = carry
-                        copy_par((src.at[pl.ds(kvh * sb + j * tk, tk)],
-                                  kt_vmem),
-                                 (src.at[pl.ds((hkv + kvh) * sb + j * tk,
-                                               tk)], vt_vmem))
-                        return _online_fold(qt_vmem[:], kt_vmem[:],
-                                            vt_vmem[:], m, l, o, scale,
+                        return _online_fold(qt_vmem[:], kt_vmem[slot],
+                                            vt_vmem[slot], m, l, o, scale,
                                             mask_of(i, j))
 
-                    m, l, o = lax.fori_loop(*t.k_of(i), k_body,
-                                            (m0, l0, o0))
+                    m, l, o = tile_loop(*t.k_of(i), kv_tiles, k_body,
+                                        (m0, l0, o0))
                     mt_vmem[:] = jnp.broadcast_to(m, (tq, _LANES))
                     lt_vmem[:] = jnp.broadcast_to(l, (tq, _LANES))
                     ot_vmem[:] = o
@@ -901,12 +970,13 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     backward must not fall off to the ppermute recompute exactly where
     long contexts need it): dQ accumulates in its HBM output, each
     arrival loops K/V-tiles (outer, dK/dV tile carried as values) over
-    Q-tiles (inner, residuals + dQ staged per tile) — the circulation
-    protocol is byte-identical in both modes.  ``zigzag`` (causal,
-    tiled): the blocks are in the zigzag layout and each arrival folds
-    the tile ranges of ``_zigzag_tiles``; otherwise a causal arrival
-    folds those of ``_band_tiles`` (``window``: with the sliding
-    window's bound).
+    Q-tiles (inner, residuals + dQ staged per tile, the next tile's
+    copies in flight during this one's fold, its dQ written back while
+    the next folds) — the circulation protocol is byte-identical in
+    both modes.  ``zigzag`` (causal, tiled): the blocks are in the
+    zigzag layout and each arrival folds the tile ranges of
+    ``_zigzag_tiles``; otherwise a causal arrival folds those of
+    ``_band_tiles`` (``window``: with the sliding window's bound).
 
     ``size == 1`` is a ring of one: fold 0 writes dK/dV home directly,
     with no own/landing buffers, RDMA, barrier or credit."""
@@ -924,9 +994,10 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
          par_sems) = refs
     else:
         (qt_vmem, dot_vmem, lset_vmem, deltat_vmem, kt_vmem, vt_vmem,
-         accb_vmem, accb2_vmem, dqt_vmem, copy_sem, send_sem, recv_sem,
-         credit_sem, par_sems) = refs
+         accb_vmem, accb2_vmem, dqt_vmem, tile_sems, copy_sem, send_sem,
+         recv_sem, credit_sem, par_sems) = refs
         tq, tk = tiles
+        tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
@@ -1032,26 +1103,36 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                 dk0 = accb_vmem[:]
                 dv0 = accb2_vmem[:]
 
-                def i_body(i, carry):
+                def q_rows(i):
+                    return pl.ds(_head_row(h, sb) + i * tq, tq)
+
+                def q_tiles(i, slot):
+                    rows = q_rows(i)
+                    return ((q_hbm.at[rows], qt_vmem.at[slot]),
+                            (do_hbm.at[rows], dot_vmem.at[slot]),
+                            (lse_hbm.at[rows], lset_vmem.at[slot]),
+                            (delta_hbm.at[rows], deltat_vmem.at[slot]),
+                            (dq_hbm.at[rows], dqt_vmem.at[slot]))
+
+                def dq_store(i, slot):
+                    return dqt_vmem.at[slot], dq_hbm.at[q_rows(i)]
+
+                def i_body(i, slot, carry):
                     dk, dv = carry
-                    r0 = _head_row(h, sb) + i * tq
-                    copy_par((q_hbm.at[pl.ds(r0, tq)], qt_vmem),
-                             (do_hbm.at[pl.ds(r0, tq)], dot_vmem),
-                             (lse_hbm.at[pl.ds(r0, tq)], lset_vmem),
-                             (delta_hbm.at[pl.ds(r0, tq)], deltat_vmem),
-                             (dq_hbm.at[pl.ds(r0, tq)], dqt_vmem))
                     dq_c, dk_c, dv_c = _pair_grad_tile(
-                        qt_vmem[:].astype(jnp.float32),
-                        dot_vmem[:].astype(jnp.float32),
-                        lset_vmem[:, :1], deltat_vmem[:, :1],
+                        qt_vmem[slot].astype(jnp.float32),
+                        dot_vmem[slot].astype(jnp.float32),
+                        lset_vmem[slot, :, :1], deltat_vmem[slot, :, :1],
                         kt_vmem[:].astype(jnp.float32),
                         vt_vmem[:].astype(jnp.float32), scale,
                         mask_of(i, j))
-                    dqt_vmem[:] = dqt_vmem[:] + dq_c
-                    copy_sync(dqt_vmem, dq_hbm.at[pl.ds(r0, tq)])
+                    dqt_vmem[slot] = dqt_vmem[slot] + dq_c
                     return dk + dk_c, dv + dv_c
 
-                dk, dv = lax.fori_loop(*t.q_of(j), i_body, (dk0, dv0))
+                # the last dQ write-back is waited at the loop's end, so
+                # the next k tile's first dQ read sees it
+                dk, dv = tile_loop(*t.q_of(j), q_tiles, i_body, (dk0, dv0),
+                                   store=dq_store)
                 accb_vmem[:] = dk
                 accb2_vmem[:] = dv
                 copy_par((accb_vmem, dkv_at(kr, tk)),
@@ -1071,8 +1152,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     else:
         # dQ accumulates in its output ref: zero it tile by tile
         def zq_body(i, _):
-            dqt_vmem[:] = jnp.zeros((tq, d), jnp.float32)
-            copy_sync(dqt_vmem, dq_hbm.at[pl.ds(i * tq, tq)])
+            dqt_vmem[0] = jnp.zeros((tq, d), jnp.float32)
+            copy_sync(dqt_vmem.at[0], dq_hbm.at[pl.ds(i * tq, tq)])
             return 0
 
         lax.fori_loop(0, (hq * sb) // tq, zq_body, 0)
@@ -1236,7 +1317,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     by ``attention_vmem_plan`` from ``vmem_limit_bytes`` (default ~12
     MiB); ``interpret=True`` (the CPU tier) runs the serial same-kernel
     path, or — under vma typing / a multi-axis mesh — the ppermute
-    fallback with the shared loud warning.
+    fallback with the shared loud warning.  ``interpret`` may also be a
+    ``pltpu.InterpretParams``: Pallas's TPU interpreter, which models
+    the local DMAs and their semaphores (a ring of one needs no more).
 
     Differentiable: the forward emits the logsumexp residual and the
     backward runs its own fused ring kernel ([K,V,dK,dV] circulation)
@@ -1348,11 +1431,14 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # tiled from half blocks; every other case keeps contiguous blocks
     zigzag = (halves and tiles is not None and bwd_mode == "tiled"
               and all((sb // 2) % t == 0 for t in tiles + bwd_tiles))
-    if zigzag or (window is not None and tiles is not None):
+    # every tiled fold runs the prefetching tile loop (_mk_tile_loop)
+    if tiles is not None or bwd_mode == "tiled":
         from .. import mpit
 
         mpit.count(attention_zigzag=int(zigzag),
-                   attention_window=int(window is not None))
+                   attention_window=int(window is not None
+                                        and tiles is not None),
+                   attention_prefetch=1)
     ring = size > 1
     kind = "ring" if window is None else "swa"
 
@@ -1424,11 +1510,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      ((hq * sb, d), jnp.float32)]         # o state
             scratch = [
                 pltpu.VMEM((tq, d), q.dtype),            # q tile
-                pltpu.VMEM((tk, d), q.dtype),            # k tile
-                pltpu.VMEM((tk, d), q.dtype),            # v tile
+                pltpu.VMEM((2, tk, d), q.dtype),         # k tiles (slots)
+                pltpu.VMEM((2, tk, d), q.dtype),         # v tiles (slots)
                 pltpu.VMEM((tq, _LANES), jnp.float32),   # m tile
                 pltpu.VMEM((tq, _LANES), jnp.float32),   # l tile
                 pltpu.VMEM((tq, d), jnp.float32),        # o tile
+                pltpu.SemaphoreType.DMA((2, 2)),         # k/v tiles (slot)
             ]
         scratch += [
             pltpu.SemaphoreType.DMA(()),
@@ -1485,15 +1572,16 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         else:
             tqb, tkb = bwd_tiles
             scratch = [
-                pltpu.VMEM((tqb, d), q.dtype),               # q tile
-                pltpu.VMEM((tqb, d), q.dtype),               # dOut tile
-                pltpu.VMEM((tqb, _LANES), jnp.float32),      # lse tile
-                pltpu.VMEM((tqb, _LANES), jnp.float32),      # delta tile
+                pltpu.VMEM((2, tqb, d), q.dtype),            # q tiles (slots)
+                pltpu.VMEM((2, tqb, d), q.dtype),            # dOut tiles
+                pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # lse tiles
+                pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # delta tiles
                 pltpu.VMEM((tkb, d), q.dtype),               # k tile
                 pltpu.VMEM((tkb, d), q.dtype),               # v tile
                 pltpu.VMEM((tkb, d), jnp.float32),           # dk buffer
                 pltpu.VMEM((tkb, d), jnp.float32),           # dv buffer
-                pltpu.VMEM((tqb, d), jnp.float32),           # dq tile
+                pltpu.VMEM((2, tqb, d), jnp.float32),        # dq tiles
+                pltpu.SemaphoreType.DMA((2, 6)),             # 5 loads, dq out
             ]
         scratch += [
             pltpu.SemaphoreType.DMA(()),

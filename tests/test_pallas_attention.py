@@ -703,7 +703,7 @@ def test_bwd_fallback_out_of_budget():
                                               attention_vmem_plan)
 
     Pn, Sb, d = 2, 32, 128
-    limit = 40_000  # tiled forward fits; no backward tile does
+    limit = 50_000  # tiled forward fits; no backward tile does
     assert attention_vmem_plan(Sb, d, 1, 1, jnp.float32,
                                vmem_limit_bytes=limit)[0] == "tiled"
     assert attention_vmem_plan(Sb, d, 1, 1, jnp.float32,
@@ -861,7 +861,7 @@ def test_zigzag_parity(Pn, Hq, Hkv, dtype):
     sequence, with the caller's blocks contiguous in rank order."""
     from mpi_tpu import mpit
 
-    Sb, d, limit = 32, 128, 100_000
+    Sb, d, limit = 32, 128, 130_000
     rng = np.random.RandomState(50 + Pn * 10 + Hq)
     # inputs rounded to the dtype, so the oracle sees what the kernel sees
     q, k, v, ct = (np.asarray(jnp.asarray(rng.randn(h, Pn * Sb, d), dtype),
@@ -913,7 +913,7 @@ def _zigzag_case(case):
     elif case == "forward_oob":
         kw["vmem_limit_bytes"] = 1
     elif case == "backward_oob":
-        kw["vmem_limit_bytes"] = 40_000  # tiled forward, no backward tile
+        kw["vmem_limit_bytes"] = 50_000  # tiled forward, no backward tile
 
     def loss(qb):
         return jnp.sum(pallas_ring_attention(qb, qb, qb, "world", Pn, **kw))
@@ -930,17 +930,23 @@ def _zigzag_case(case):
     ("halves_untileable", 0)])
 def test_zigzag_engages_only_causal_tiled(case, engaged):
     """``attention_zigzag`` rises once per trace where causal attention
-    runs both tiled kernels, and on no other path."""
+    runs both tiled kernels, and on no other path; ``attention_prefetch``
+    once per trace where either kernel takes the tiled plan."""
     import warnings
 
     from mpi_tpu import mpit
 
     fn, aval = _zigzag_case(case)
-    before = mpit.pvar_read("attention_zigzag")
+    before = {p: mpit.pvar_read(p)
+              for p in ("attention_zigzag", "attention_prefetch")}
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)  # the fallbacks'
         jax.jit(fn).trace(aval)
-    assert mpit.pvar_read("attention_zigzag") - before == engaged
+    assert mpit.pvar_read("attention_zigzag") - \
+        before["attention_zigzag"] == engaged
+    tiled = case not in ("resident", "vma_fallback", "forward_oob")
+    assert mpit.pvar_read("attention_prefetch") - \
+        before["attention_prefetch"] == int(tiled)
 
 
 def test_causal_tiled_contiguous_where_halves_do_not_tile():
@@ -1069,10 +1075,10 @@ def test_zigzag_tiles_cover_causal_pairs_once(Pn, Sb, tq, tk):
 # -- sliding window, and P = 1 on the kernels --------------------------------
 
 
-def _dense_window(q, k, v, window=None):
+def _dense_window(q, k, v, window=None, causal=True):
     """Dense causal GQA attention in float32 on the whole sequence, each
     query attending the ``window`` newest keys up to itself (all of them
-    where ``window`` is None)."""
+    where ``window`` is None; every key where not ``causal``)."""
     g = q.shape[0] // k.shape[0]
     k, v = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
     s = jnp.einsum("hqd,hkd->hqk", q, k,
@@ -1080,15 +1086,16 @@ def _dense_window(q, k, v, window=None):
     n = q.shape[1]
     i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
     keep = (j <= i) if window is None else (j <= i) & (j > i - window)
-    s = jnp.where(keep, s, -jnp.inf)
+    s = jnp.where(keep | (not causal), s, -jnp.inf)
     return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), v,
                       precision="highest")
 
 
-def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0):
-    """(kernel out, dq, dk, dv), (dense out, dq, dk, dv) and the
-    ``attention_window`` pvar's rise, for causal attention over ``Pn``
-    ranks of ``Sb`` rows through ``jax.grad``."""
+def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0, causal=True,
+                  interpret=True, pvar="attention_window"):
+    """(kernel out, dq, dk, dv), (dense out, dq, dk, dv) and the ``pvar``
+    mpit pvar's rise, for causal (or full) attention over ``Pn`` ranks of
+    ``Sb`` rows through ``jax.grad``."""
     from mpi_tpu import mpit
 
     rng = np.random.RandomState(seed)
@@ -1096,23 +1103,23 @@ def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0):
                    for h in (Hq, Hkv, Hkv, Hq))
 
     def loss(qb, kb, vb, cb):
-        out = pallas_ring_attention(qb, kb, vb, "world", Pn, causal=True,
-                                    window=window, interpret=True,
+        out = pallas_ring_attention(qb, kb, vb, "world", Pn, causal=causal,
+                                    window=window, interpret=interpret,
                                     vmem_limit_bytes=limit)
         return jnp.sum(out * cb), out
 
     spec = P(None, "world")
-    before = mpit.pvar_read("attention_window")
+    before = mpit.pvar_read(pvar)
     (dq, dk, dv), out = jax.jit(jax.shard_map(
         jax.grad(loss, argnums=(0, 1, 2), has_aux=True),
         mesh=default_mesh(Pn), in_specs=(spec,) * 4,
         out_specs=((spec,) * 3, spec), check_vma=False))(q, k, v, ct)
-    rise = mpit.pvar_read("attention_window") - before
+    rise = mpit.pvar_read(pvar) - before
 
     def dense_loss(q_, k_, v_):
-        return jnp.sum(_dense_window(q_, k_, v_, window) * ct)
+        return jnp.sum(_dense_window(q_, k_, v_, window, causal) * ct)
 
-    want = [_dense_window(q, k, v, window)] + list(
+    want = [_dense_window(q, k, v, window, causal)] + list(
         jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v))
     return [np.asarray(x) for x in (out, dq, dk, dv)], \
         [np.asarray(x) for x in want], rise
@@ -1120,7 +1127,7 @@ def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0):
 
 # tiled at Sb 32 with 8-row tiles (the budget admits no larger forward or
 # backward tile); W < tile, W not a multiple of the tile, W = Sb, W >= S
-_TILE8 = 60_000
+_TILE8 = 80_000
 
 
 @pytest.mark.parametrize("Pn,window,Hq,Hkv", [
@@ -1317,3 +1324,164 @@ def test_size_one_on_a_multi_axis_mesh_runs_the_kernel():
     for sl in range(4):
         want = _dense_window(q[sl][None], q[sl][None], q[sl][None], 5)[0]
         np.testing.assert_allclose(got[sl], want, rtol=2e-4, atol=2e-5)
+
+
+# -- the prefetching tile loop -----------------------------------------------
+
+
+@pytest.mark.parametrize("Pn,Sb,Hq,Hkv,causal,window", [
+    (2, 48, 2, 2, False, None),   # full: 6 tiles a loop
+    (2, 48, 2, 2, True, None),    # zigzag: traced bounds, 1 to 6 a loop
+    (2, 48, 2, 1, True, 21),      # a window: 1 to 4 a loop
+    (1, 48, 6, 1, True, None),    # 6 heads share dK/dV tiles; 1 to 6
+    (2, 8, 4, 1, True, None),     # one 8-row tile a block: 1 a loop
+], ids=["full", "zigzag_p2", "window", "gqa6", "one_tile"])
+def test_tiled_prefetch_parity(Pn, Sb, Hq, Hkv, causal, window):
+    """Both tiled folds stage the next tile pair while they fold this one
+    (8-row tiles): the output and the three gradients match dense
+    attention, and ``attention_prefetch`` rises once per trace."""
+    from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
+
+    for bwd in (False, True):
+        assert attention_vmem_plan(Sb, 128, Hq, Hkv, jnp.float32, _TILE8,
+                                   for_backward=bwd) == ("tiled", (8, 8))
+    got, want, rise = _window_grads(Pn, Sb, Hq, Hkv, window, _TILE8,
+                                    seed=Sb + Hq, causal=causal,
+                                    pvar="attention_prefetch")
+    assert rise == 1
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+
+
+@pytest.mark.parametrize("window", [None, 13], ids=["causal", "window"])
+def test_tiled_folds_under_the_tpu_interpreter(window):
+    """Under Pallas's TPU interpreter a DMA runs only when it is waited
+    for: a tile folded before its copy is waited reads a buffer its data
+    has not reached, and a dQ write-back never waited is lost.  A ring of
+    one, a GQA group of 6, 8-row tiles."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    try:
+        got, want, _ = _window_grads(1, 32, 6, 1, window, _TILE8, seed=61,
+                                     interpret=pltpu.InterpretParams())
+    finally:
+        pltpu.reset_tpu_interpret_mode_state()
+    for g, r in zip(got, want):
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+
+
+class _TileLoopModel:
+    """Runs ``_mk_tile_loop``'s loop in Python, each DMA landing at any
+    moment between its start and its wait, and checks its schedule: one
+    copy in flight per semaphore; no two copies in flight on one buffer
+    where either writes it; a fold reads its own slot's tiles, with
+    nothing in flight into or out of that slot; nothing in flight when a
+    loop returns.  Stands in for the semaphore array and for the parts of
+    ``pl``, ``lax`` and ``pltpu`` that the loop uses."""
+
+    shape = (2, 3)  # the semaphore array: 2 slots, 2 loads + the store
+
+    def __init__(self):
+        self.inflight = {}  # semaphore -> (src, dst)
+        self.data = {}      # buffer -> what it holds
+
+    @property
+    def at(self):
+        return self
+
+    def __getitem__(self, slot_col):
+        return ("sem",) + slot_col
+
+    @staticmethod
+    def when(cond):
+        return lambda f: f() if cond else None
+
+    @staticmethod
+    def fori_loop(lo, hi, body, carry):
+        for n in range(lo, hi):
+            carry = body(n, carry)
+        return carry
+
+    @staticmethod
+    def rem(n, m):
+        return n % m
+
+    def make_async_copy(self, src, dst, sem):
+        model = self
+
+        class Copy:
+            def start(self):
+                assert sem not in model.inflight
+                for s, d in model.inflight.values():
+                    assert dst not in (s, d) and src != d, (src, dst, s, d)
+                model.inflight[sem] = (src, dst)
+
+            def wait(self):
+                assert model.inflight.pop(sem) == (src, dst)
+                model.data[dst] = model.data.get(src, src)
+
+        return Copy()
+
+    def run(self, lo, hi, store, version):
+        """One loop over tiles [lo, hi) that loads q and dq rows, folds
+        and (``store``) writes dq back, as the backward's inner loop
+        does; ``version`` tells this loop's dq writes apart."""
+        from mpi_tpu.tpu import pallas_attention as pa
+
+        def loads(n, slot):
+            return ((("hbm", "q", n), ("vmem", "q", slot)),
+                    (("hbm", "dq", n), ("vmem", "dq", slot)))
+
+        def body(n, slot, folded):
+            assert all(b[0] != "vmem" or b[2] != slot
+                       for cp in self.inflight.values() for b in cp)
+            assert self.data[("vmem", "q", slot)] == ("hbm", "q", n)
+            # the dq tile holds what the previous loop wrote back
+            assert self.data[("vmem", "dq", slot)] == \
+                self.data.get(("hbm", "dq", n), ("hbm", "dq", n))
+            self.data[("vmem", "dq", slot)] = ("dq", n, version)
+            return folded + [n]
+
+        def dq_store(n, slot):
+            return ("vmem", "dq", slot), ("hbm", "dq", n)
+
+        folded = pa._mk_tile_loop(self)(lo, hi, loads, body, [],
+                                        store=dq_store if store else None)
+        assert folded == list(range(lo, hi))
+        assert not self.inflight
+        if store:
+            for n in range(lo, hi):
+                assert self.data[("hbm", "dq", n)] == ("dq", n, version)
+
+
+@pytest.mark.parametrize("store", [False, True], ids=["loads", "write_back"])
+@pytest.mark.parametrize("lo,hi", [(0, 0), (3, 2), (0, 1), (5, 6), (0, 2),
+                                   (1, 4), (2, 9)])
+def test_tile_loop_schedule(lo, hi, store, monkeypatch):
+    """The prefetching loop's DMA schedule, for empty loops, loops of one
+    tile and longer ones, run twice over the same rows (one k tile's
+    q-tile loop, then the next's)."""
+    from types import SimpleNamespace
+
+    from mpi_tpu.tpu import pallas_attention as pa
+
+    model = _TileLoopModel()
+    monkeypatch.setattr(pa, "pl", SimpleNamespace(when=model.when))
+    monkeypatch.setattr(pa, "lax", model)
+    monkeypatch.setattr(pa, "pltpu", model)
+    model.run(lo, hi, store, version=0)
+    model.run(lo, hi, store, version=1)
+
+
+@pytest.mark.parametrize("sb,hq,hkv,halves", [
+    (8192, 32, 2, False), (8192, 32, 2, True), (16384, 48, 8, False)],
+    ids=["attn.sp1.s8k", "attn.sp4.s32k", "attn.swa4k.sp1.s16k"])
+def test_cells_keep_512_tiles(sb, hq, hkv, halves):
+    """The second tile buffers still leave both folds of the benchmark's
+    attention cells on 512 x 512 tiles under the default budget."""
+    from mpi_tpu.tpu.pallas_attention import _vmem_plan
+
+    for bwd in (False, True):
+        assert _vmem_plan(sb, 128, hq, hkv, jnp.bfloat16, None, bwd,
+                          halves) == ("tiled", (512, 512))
