@@ -43,7 +43,7 @@ class _Counters:
                  "pallas_fallbacks", "bytes_raw", "bytes_pickled", "copies",
                  "proc_failed", "revokes", "shrinks",
                  "faulty_dropped", "faulty_duplicated", "attention_oob",
-                 "attention_zz", "attention_win", "attention_pf",
+                 "attention_zz", "attention_win",
                  "sm_hits", "sm_bytes", "sm_fallbacks",
                  "v_deadlocks", "v_mismatches", "v_leaked", "v_double_waits",
                  "v_buf_overlaps", "v_comms_unfreed",
@@ -78,7 +78,6 @@ class _Counters:
         self.attention_oob = 0
         self.attention_zz = 0
         self.attention_win = 0
-        self.attention_pf = 0
         self.sm_hits = 0
         self.sm_bytes = 0
         self.sm_fallbacks = 0
@@ -134,8 +133,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           proc_failed: int = 0, revokes: int = 0, shrinks: int = 0,
           faulty_dropped: int = 0, faulty_duplicated: int = 0,
           attention_oob: int = 0, attention_zigzag: int = 0,
-          attention_window: int = 0, attention_prefetch: int = 0,
-          coll_sm_hits: int = 0,
+          attention_window: int = 0, coll_sm_hits: int = 0,
           coll_sm_bytes: int = 0, coll_sm_fallbacks: int = 0,
           verify_deadlocks: int = 0, verify_mismatches: int = 0,
           verify_requests_leaked: int = 0, verify_double_waits: int = 0,
@@ -193,7 +191,6 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.attention_oob += attention_oob
         counters.attention_zz += attention_zigzag
         counters.attention_win += attention_window
-        counters.attention_pf += attention_prefetch
         counters.sm_hits += coll_sm_hits
         counters.sm_bytes += coll_sm_bytes
         counters.sm_fallbacks += coll_sm_fallbacks
@@ -283,10 +280,6 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # ranges (k tiles before a query tile's window skipped;
     # tpu/pallas_attention.py): says whether the window engaged
     "attention_window": lambda: counters.attention_win,
-    # ring-attention traces whose tiled folds run the prefetching tile
-    # loop (the next tile pair's DMAs overlap this one's fold;
-    # tpu/pallas_attention.py): says whether the pipelining engaged
-    "attention_prefetch": lambda: counters.attention_pf,
     # shared-memory collective arena (mpi_tpu/coll_sm.py): collectives
     # served entirely by arena load/store (zero ring frames), per-rank
     # payload bytes moved through it, and eligible requests that fell

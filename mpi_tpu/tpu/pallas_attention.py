@@ -36,30 +36,22 @@ for bf16 inputs.  Full OR causal attention (``causal=True`` masks by
 global position — block indices come from the SMEM params, so the same
 compiled kernel serves every rank); scale = 1/sqrt(d) by default.
 
-**VMEM planning** (``attention_vmem_plan`` — VERDICT r4 missing #2):
-the fold is executed in one of two modes chosen at trace time from a
-VMEM budget:
+**The tiled fold**: flash-attention-style inner tiling.  The m/l/o
+state lives in HBM; each arrival loops over [tq]-row query tiles and
+[tk]-row K/V tiles (``lax.fori_loop``), staging each tile through small
+VMEM buffers, so the live score block is [tq, tk] and the block size is
+bounded by HBM, not VMEM.  The innermost loops of both folds are
+software-pipelined (``_mk_tile_loop``): two buffer slots, the next tile
+pair's copies running while the MXU folds this one.  The fold is a
+local subroutine between protocol events, so ``AttentionSim``'s
+verification of the circulation covers it.
 
-* *resident* — Q, the K/V staging buffer, and the m/l/o state all live
-  in VMEM and each fold materializes one [Sb, Sb] score block.  The
-  fast path for blocks up to ~1-2k rows at d=128/f32.
-* *tiled* — flash-attention-style inner tiling: the m/l/o state lives
-  in HBM; each arrival loops over [tq]-row query tiles and
-  [tk]-row K/V tiles (``lax.fori_loop``), staging each tile through
-  small VMEM buffers, so the live score block is [tq, tk] and the
-  block size is bounded by HBM, not VMEM.  The innermost loops of both
-  folds are software-pipelined (``_mk_tile_loop``): two buffer slots,
-  the next tile pair's copies running while the MXU folds this one
-  (``attention_prefetch`` mpit pvar counts the traces that build
-  them).  Tile sizes are the largest
-  sublane-aligned divisors of Sb, at most 512, that fit the budget (a
-  causal ring takes those that divide Sb/2 first, for the zigzag
-  layout below).
-
-Either way the RDMA circulation (slots, credits, barriers) is
-IDENTICAL — the fold is a local subroutine between protocol events, so
-``AttentionSim``'s verification covers both modes.  An impossible
-budget (no tile fits) is diagnosed at trace time with the math shown.
+**VMEM plan** (``attention_vmem_plan``): the plan only picks the tile —
+the largest sublane-aligned divisor of Sb, at most 512 rows, whose
+buffers fit a VMEM budget (a causal ring takes those that divide Sb/2
+first, for the zigzag layout below).  Where no tile fits, the forward
+is diagnosed at trace time with the math shown and the backward plans
+"fallback".
 
 **Fused backward** (``_bwd_kernel`` — VERDICT r4 missing #3): under
 differentiation the forward also emits the per-row logsumexp
@@ -75,17 +67,17 @@ locally, adds its dK/dV contribution into the circulating payload, and
 forwards; after P hops the accumulators land back home.  Fold-before-forward ordering
 (the payload is mutated before it moves on) with the same
 double-buffer + credit discipline — model-checked separately by
-``ring_model.AttentionBwdSim``.  The backward fold is VMEM-planned
-like the forward: resident, or flash-tiled (dQ accumulating in its
-HBM output; a K/V-tile outer loop carries dK/dV accumulators over a
-Q-tile inner loop), so long-context training stays on the fused
-kernels; only an impossible budget falls back to recomputing through
-the pure-jax ppermute ring (correct at any size).
+``ring_model.AttentionBwdSim``.  The backward fold is tiled like the
+forward (dQ accumulating in its HBM output; a K/V-tile outer loop
+carries dK/dV accumulators over a Q-tile inner loop), so long-context
+training stays on the fused kernels; where its plan is "fallback" (no
+tile fits the budget) the backward recomputes through the pure-jax
+ppermute ring (correct at any size).
 
 **Causal layout** (``_zigzag_tiles``): with the blocks contiguous,
 rank r's causal work is its diagonal block plus r past blocks, and
 the ring paces every rank to the last one.  So where causal attention
-runs both folds tiled, with tiles that divide Sb/2, the wrapper
+runs both fused kernels, with tiles that divide Sb/2, the wrapper
 re-lays the sequence out zigzag: 2P chunks of Sb/2 rows, rank r
 computing on chunk r and chunk 2P-1-r.  Every off-diagonal arrival
 then costs every rank half a block (``attention_zigzag`` mpit pvar
@@ -94,7 +86,7 @@ halves before the kernels and back after them, outside the custom
 VJP, so the caller's contiguous blocks and the kernels' circulation
 protocol are unchanged; only the tile ranges each fold visits differ.
 Every other case (full attention, windowed attention, P = 1, the
-resident fold, the fallbacks) keeps the contiguous blocks.
+fallbacks) keeps the contiguous blocks.
 
 **Sliding window** (``_band_tiles``): with ``window=W`` a query attends
 the W newest keys up to itself.  On the contiguous layout each tiled
@@ -120,8 +112,7 @@ warning.  The compiled multi-axis path addresses neighbors by mesh
 coordinate exactly like pallas_ring.
 
 Restrictions (diagnosed): f32/bf16; head dim ``d`` a multiple of 128
-(lane width); block rows ``Sb`` a multiple of 8 (sublane tile); a
-VMEM budget no tile size can satisfy raises with the numbers.
+(lane width); block rows ``Sb`` a multiple of 8 (sublane tile).
 """
 
 from __future__ import annotations
@@ -434,7 +425,7 @@ def _mk_copy_sync(copy_sem):
 def _mk_copy_par(par_sems):
     """Start INDEPENDENT local DMAs together, then wait them all — the
     folds stage several disjoint buffers at once (the m/l/o state; the
-    backward's k/v and dk/dv tiles; the resident residuals) and
+    backward's k/v and dk/dv tiles) and
     serializing them exposes every transfer's full HBM latency on chip
     (round 5).  Each copy gets its own semaphore by POSITION (all
     indices Python-static)."""
@@ -510,9 +501,8 @@ def _mk_tile_loop(sems):
 
 
 def _pair_grad_tile(qh, doh, lse1, delta1, kb, vb, scale, mask=None):
-    """ONE copy of the flash-backward algebra (review round 5: the
-    resident and tiled folds must not carry separate copies of it):
-    given f32 Q/dO rows, their lse/delta columns, and a K/V tile,
+    """The flash-backward algebra of one tile pair: given f32 Q/dO
+    rows, their lse/delta columns, and a K/V tile,
     return (dq, dk, dv) contributions.  ``mask``: optional [rows, cols]
     bool, True = attend (probabilities zeroed elsewhere)."""
     s = jnp.dot(qh, kb.T, preferred_element_type=jnp.float32) * scale
@@ -585,48 +575,28 @@ class _SndPair:
 
 def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                         vmem_limit_bytes: Optional[int] = None,
-                        for_backward: bool = False):
-    """Choose the fold execution mode from a VMEM budget (trace time).
+                        for_backward: bool = False, halves: bool = False):
+    """Pick the fold's tile from a VMEM budget (trace time).
 
-    Returns ``("resident", None)`` when the whole-block fold fits, or
-    ``("tiled", (tq, tk))`` with the largest sublane-aligned divisor
-    tile (at most ``_MAX_TILE`` rows) that fits — for the forward AND
-    (round 5) the backward.  A backward no tile can satisfy returns ``("fallback", None)`` (→
-    ppermute recompute, correct at any size); the forward instead
-    raises NotImplementedError with the arithmetic — which the caller
-    (pallas_ring_attention) converts into the loud ppermute-ring
-    fallback (warning + ``attention_fallbacks`` pvar), so an over-tight
-    budget degrades instead of failing (ROADMAP r5 #4).
+    Returns ``("tiled", (t, t))`` with the largest sublane-aligned
+    divisor tile of ``sb`` (at most ``_MAX_TILE`` rows) whose buffers
+    fit; ``halves`` prefers tiles that divide ``sb // 2``
+    (``_tile_sizes``), as the causal ring's zigzag layout needs.  Where
+    no tile fits, the backward returns ``("fallback", None)`` (→
+    ppermute recompute, correct at any size) and the forward raises
+    NotImplementedError with the arithmetic, which the caller
+    (pallas_ring_attention) turns into the loud ppermute-ring fallback
+    (warning + ``attention_fallbacks`` pvar).
 
     The estimates are deliberately generous (temporaries counted at
-    f32, a spare plane for Mosaic's fusions) so a "resident" or
-    "tiled" verdict holds on real hardware with headroom."""
-    return _vmem_plan(sb, d, hq, hkv, dtype, vmem_limit_bytes,
-                      for_backward, halves=False)
-
-
-def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
-               vmem_limit_bytes: Optional[int], for_backward: bool,
-               halves: bool):
-    """:func:`attention_vmem_plan`; ``halves`` prefers tiles that divide
-    ``sb // 2`` (``_tile_sizes``), as the causal ring's zigzag layout
-    needs — the same verdict, a smaller tile at most."""
+    f32, a spare plane for Mosaic's fusions) so a tile that fits here
+    fits on real hardware with headroom."""
     from .pallas_ring import _SUBLANES
 
     esz = jnp.dtype(dtype).itemsize
     limit = _VMEM_BUDGET if vmem_limit_bytes is None else vmem_limit_bytes
     sub = _SUBLANES.get(jnp.dtype(dtype), 8)
     if for_backward:
-        resident = (hq * sb * d * esz          # Q
-                    + hq * sb * d * esz        # dOut
-                    + 2 * hq * sb * _LANES * 4  # lse, delta staging
-                    + 2 * hkv * sb * d * esz   # K/V staging (wire dtype)
-                    + 2 * hkv * sb * d * 4     # dK/dV staging
-                    + hq * sb * d * 4          # dQ accumulator
-                    + 4 * sb * sb * 4          # s/p/dp/ds temporaries
-                    + 2 * sb * d * 4)          # fold temporaries
-        if resident <= limit:
-            return ("resident", None)
         for t in _tile_sizes(sb, sub, halves):
             tiled = (2 * (2 * t * d * esz      # q/do tiles, two slots
                           + 2 * t * _LANES * 4  # lse/delta tiles
@@ -639,16 +609,6 @@ def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
             if tiled <= limit:
                 return ("tiled", (t, t))
         return ("fallback", None)  # recompute always works
-    resident = (hq * sb * d * esz              # Q
-                + 2 * hkv * sb * d * esz       # K/V staging
-                + 2 * hq * sb * _LANES * 4     # m, l ([.., 1] buffers are
-                #                                lane-padded to 128 lanes)
-                + hq * sb * _LANES * 4         # lse staging
-                + hq * sb * d * 4              # o accumulator
-                + 2 * sb * sb * 4              # score + exp temporaries
-                + 2 * sb * d * 4)              # fold temporaries
-    if resident <= limit:
-        return ("resident", None)
 
     def tiled(t):
         return (t * d * esz                    # q tile
@@ -671,9 +631,8 @@ def _vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
 def _kernel(params_smem, q_hbm, kv_hbm, *refs,
             axis_name: str, size: int, sb: int, d: int,
             scale: float, pipelined: bool, mesh_ids: bool,
-            causal: bool = False, hq: int = 1, hkv: int = 1,
-            tiles: Optional[Tuple[int, int]] = None,
-            with_lse: bool = False, zigzag: bool = False,
+            tiles: Tuple[int, int], causal: bool = False, hq: int = 1,
+            hkv: int = 1, with_lse: bool = False, zigzag: bool = False,
             window: Optional[int] = None):
     """See module docstring for the step/slot/credit schedule.
 
@@ -686,11 +645,10 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     single-head case (pure payload relabeling — verified by the GQA
     AttentionSim runs, tests/test_pallas_protocol.py).
 
-    ``tiles=None`` → resident fold; ``tiles=(tq, tk)`` → flash-style
-    inner tiling with the m/l/o state in HBM (module
-    docstring).  The protocol events are identical in both modes.
+    ``tiles=(tq, tk)``: the fold's query and K/V tile rows; the m/l/o
+    state lives in HBM between arrivals (module docstring).
 
-    ``zigzag`` (causal, tiled): the blocks are in the zigzag layout and
+    ``zigzag`` (causal): the blocks are in the zigzag layout and
     each arrival folds the tile ranges of ``_zigzag_tiles``; otherwise a
     causal arrival folds those of ``_band_tiles`` (``window``: the
     sliding window's lower bound too).
@@ -705,22 +663,12 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     n_out = 2 if with_lse else 1
     out_hbm, lse_hbm = refs[0], refs[1] if with_lse else None
     comm_hbm = refs[n_out] if P > 1 else None  # landing slots
-    refs = refs[n_out + (P > 1):]
-    if tiles is None:
-        if with_lse:
-            (q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem, lse_vmem,
-             copy_sem, send_sem, recv_sem, credit_sem,
-             par_sems) = refs
-        else:
-            (q_vmem, kv_vmem, m_vmem, l_vmem, o_vmem,
-             copy_sem, send_sem, recv_sem, credit_sem,
-             par_sems) = refs
-    else:
-        (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
-         mt_vmem, lt_vmem, ot_vmem, tile_sems,
-         copy_sem, send_sem, recv_sem, credit_sem, par_sems) = refs
-        tq, tk = tiles
-        tile_loop = _mk_tile_loop(tile_sems)
+    (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
+     mt_vmem, lt_vmem, ot_vmem, tile_sems,
+     copy_sem, send_sem, recv_sem, credit_sem,
+     par_sems) = refs[n_out + (P > 1):]
+    tq, tk = tiles
+    tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
@@ -732,54 +680,14 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     # send u (0..P-2): the block computed at step u moves on
     fwd_rdma = _mk_snd(kv_hbm, comm_hbm, send_sem, recv_sem, dev_kw, right)
 
-    # -- resident fold: whole block staged in VMEM --------------------------
+    # -- the fold: state in HBM, flash-style [tq, tk] inner loop ------------
 
-    def load_kv(src_ref):
-        copy_sync(src_ref, kv_vmem)
-
-    def fold_resident(a):
-        def body(mask):
-            def head(h, _):
-                rows = pl.ds(_head_row(h, sb), sb)
-                kr = _head_row(h // g, sb)
-                k = kv_vmem[pl.ds(kr, sb), :]
-                v = kv_vmem[pl.ds(hkv * sb + kr, sb), :]
-                m, l, o = _online_fold(q_vmem[rows, :], k, v,
-                                       m_vmem[rows, :], l_vmem[rows, :],
-                                       o_vmem[rows, :], scale, mask)
-                m_vmem[rows, :] = m
-                l_vmem[rows, :] = l
-                o_vmem[rows, :] = o
-                return 0
-
-            lax.fori_loop(0, hq, head, 0)
-
-        if not causal:
-            body(None)
-            return
-        # arrival a carries K/V block (my - a) mod P; the first fold
-        # (a=0, own block) always has its diagonal unmasked, so the
-        # running max is finite from step 0 on.  Blocks entirely in the
-        # future (kv_idx > my) contribute exactly zero — skip their MXU
-        # passes outright (the circulation/credit schedule above is
-        # untouched, so the model-checked protocol is unchanged); so do
-        # past blocks wholly before every query's window.
-        if _before_window(a, sb, window):
-            return
-        kv_idx = lax.rem(my - a + P, P)
-
-        @pl.when(kv_idx <= my)
-        def _():
-            body(_causal_mask(my, kv_idx, sb, window=window))
-
-    # -- tiled fold: state in HBM, flash-style [tq, tk] inner loop ----------
-
-    def fold_tiled(a, src):
+    def fold(a, src):
         """Fold arrival ``a`` whose K/V block sits in HBM ref ``src``
         ([2*hkv*sb, d]).  Reads never conflict with the concurrent
-        forwarding RDMA (read/read); the credit still follows both the
-        fold and wait_send in program order, so slot recycling is
-        exactly the resident protocol."""
+        forwarding RDMA (read/read); the credit follows both the fold
+        and wait_send in program order, so a slot is recycled only after
+        its block is folded and forwarded."""
 
         def run(t, mask_of):
             """Fold the tile pairs of ``t`` (:class:`_Tiles`);
@@ -846,25 +754,11 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
             pl.when(my >= a)(
                 lambda: run(*_band_arrival(a, my, sb, tq, tk, window)))
 
-    def fold(a, src):
-        if tiles is None:
-            fold_resident(a)
-        else:
-            fold_tiled(a, src)
-
-    # init: Q to VMEM; online-softmax state (resident mode only — the
-    # tiled state is written by the a=0 fold, which loads no prior state)
-    if tiles is None:
-        copy_sync(q_hbm, q_vmem)
-        m_vmem[:] = jnp.full((hq * sb, 1), -jnp.inf, jnp.float32)
-        l_vmem[:] = jnp.zeros((hq * sb, 1), jnp.float32)
-        o_vmem[:] = jnp.zeros((hq * sb, d), jnp.float32)
-
+    # the online-softmax state is written by the a=0 fold, which loads
+    # no prior state
     neighbor_barrier()
 
     # step 0: my own block computes and starts circulating
-    if tiles is None:
-        load_kv(kv_hbm)
     fold(0, kv_hbm)
     if P >= 2:
         fwd_rdma(0).start()
@@ -877,8 +771,6 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
         slot = a % 2
         if pipelined:
             fwd_rdma(a - 1).wait_recv()  # arrival a lands in comm[slot]
-        if tiles is None:
-            load_kv(comm_hbm.at[slot])
         if a <= P - 2:
             # forward while the fold below runs; send a >= 2 first
             # waits for the credit arming its destination slot
@@ -900,30 +792,19 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
 
     # output: out = o / l and (with_lse) the logsumexp L = m + log l —
     # the fused backward kernel's residual
-    if tiles is None:
-        out = o_vmem[:] / l_vmem[:]
+    def out_body(i, _):
+        r0 = i * tq
+        copy_par((m_hbm.at[pl.ds(r0, tq)], mt_vmem),
+                 (l_hbm.at[pl.ds(r0, tq)], lt_vmem),
+                 (o_hbm.at[pl.ds(r0, tq)], ot_vmem))
+        ot_vmem[:] = ot_vmem[:] / lt_vmem[:, :1]
+        copy_sync(ot_vmem, out_hbm.at[pl.ds(r0, tq)])
         if with_lse:
-            lse_vmem[:] = jnp.broadcast_to(
-                m_vmem[:] + jnp.log(l_vmem[:]), (hq * sb, _LANES))
-        o_vmem[:] = out
-        if with_lse:
-            copy_par((o_vmem, out_hbm), (lse_vmem, lse_hbm))
-        else:
-            copy_sync(o_vmem, out_hbm)
-    else:
-        def out_body(i, _):
-            r0 = i * tq
-            copy_par((m_hbm.at[pl.ds(r0, tq)], mt_vmem),
-                     (l_hbm.at[pl.ds(r0, tq)], lt_vmem),
-                     (o_hbm.at[pl.ds(r0, tq)], ot_vmem))
-            ot_vmem[:] = ot_vmem[:] / lt_vmem[:, :1]
-            copy_sync(ot_vmem, out_hbm.at[pl.ds(r0, tq)])
-            if with_lse:
-                mt_vmem[:] = mt_vmem[:] + jnp.log(lt_vmem[:])
-                copy_sync(mt_vmem, lse_hbm.at[pl.ds(r0, tq)])
-            return 0
+            mt_vmem[:] = mt_vmem[:] + jnp.log(lt_vmem[:])
+            copy_sync(mt_vmem, lse_hbm.at[pl.ds(r0, tq)])
+        return 0
 
-        lax.fori_loop(0, (hq * sb) // tq, out_body, 0)
+    lax.fori_loop(0, (hq * sb) // tq, out_body, 0)
 
     neighbor_barrier()
 
@@ -932,8 +813,7 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                 dq_hbm, dkv_hbm, *refs,
                 axis_name: str, size: int, sb: int, d: int, scale: float,
                 pipelined: bool, mesh_ids: bool, causal: bool,
-                hq: int, hkv: int,
-                tiles: Optional[Tuple[int, int]] = None,
+                hq: int, hkv: int, tiles: Tuple[int, int],
                 zigzag: bool = False, window: Optional[int] = None):
     """Fused ring-attention backward: [K, V] and [dK, dV] circulate for
     a FULL cycle of P sends; dQ accumulates locally; dK/dV accumulate
@@ -965,16 +845,12 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     regardless of the circulation dtype (staged K/V tiles upcast at the
     matmul).
 
-    ``tiles=None`` → resident fold (everything staged whole in VMEM);
-    ``tiles=(tq, tk)`` → flash-style tiling (round 5: the fused
-    backward must not fall off to the ppermute recompute exactly where
-    long contexts need it): dQ accumulates in its HBM output, each
-    arrival loops K/V-tiles (outer, dK/dV tile carried as values) over
-    Q-tiles (inner, residuals + dQ staged per tile, the next tile's
-    copies in flight during this one's fold, its dQ written back while
-    the next folds) — the circulation protocol is byte-identical in
-    both modes.  ``zigzag`` (causal, tiled): the blocks are in the
-    zigzag layout and each arrival folds the tile ranges of
+    ``tiles=(tq, tk)``: flash-style tiling.  dQ accumulates in its HBM
+    output; each arrival loops K/V-tiles (outer, dK/dV tile carried as
+    values) over Q-tiles (inner, residuals + dQ staged per tile, the
+    next tile's copies in flight during this one's fold, its dQ written
+    back while the next folds).  ``zigzag`` (causal): the blocks are in
+    the zigzag layout and each arrival folds the tile ranges of
     ``_zigzag_tiles``; otherwise a causal arrival folds those of
     ``_band_tiles`` (``window``: with the sliding window's bound).
 
@@ -988,21 +864,15 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
         # my own contribution is the whole result; nothing circulates
         own_kv_hbm = comm_kv_hbm = comm_dkv_hbm = None
         own_dkv_hbm = dkv_hbm
-    if tiles is None:
-        (q_vmem, do_vmem, lse_vmem, delta_vmem, kv_vmem, dkv_vmem,
-         dq_vmem, copy_sem, send_sem, recv_sem, credit_sem,
-         par_sems) = refs
-    else:
-        (qt_vmem, dot_vmem, lset_vmem, deltat_vmem, kt_vmem, vt_vmem,
-         accb_vmem, accb2_vmem, dqt_vmem, tile_sems, copy_sem, send_sem,
-         recv_sem, credit_sem, par_sems) = refs
-        tq, tk = tiles
-        tile_loop = _mk_tile_loop(tile_sems)
+    (qt_vmem, dot_vmem, lset_vmem, deltat_vmem, kt_vmem, vt_vmem,
+     accb_vmem, accb2_vmem, dqt_vmem, tile_sems, copy_sem, send_sem,
+     recv_sem, credit_sem, par_sems) = refs
+    tq, tk = tiles
+    tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
     right = params_smem[1]
     my = params_smem[2]
     g = hq // hkv
-    kv_rows = 2 * hkv * sb  # K+V planes; dK+dV planes follow
     dev_kw = _mk_dev_kw(mesh_ids, axis_name)
     neighbor_barrier = _mk_barrier(pipelined and P > 1, dev_kw, left, right)
     copy_sync = _mk_copy_sync(copy_sem)
@@ -1019,35 +889,6 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     def snd(u):
         return _SndPair(snd_kv(u), snd_dkv(u))
 
-    def pair_grads(kv_idx, masked):
-        """dQ/dK/dV contributions of my Q rows against the K/V block in
-        kv_vmem; dK/dV accumulate into dkv_vmem (all heads).  ``masked``
-        (static) applies the causal mask (with the window's bound) — on
-        the DIAGONAL block (kv_idx == my), or at a window's edge: without
-        a window strictly-past blocks are all-True and future blocks are
-        skipped by the caller's pl.when, so the mask materialization
-        stays off the P-2 hot arrivals."""
-        mask = (_causal_mask(my, kv_idx, sb, window=window) if masked
-                else None)
-
-        def head(h, _):
-            rows = pl.ds(_head_row(h, sb), sb)
-            kr = _head_row(h // g, sb)
-            krows = pl.ds(kr, sb)
-            vrows = pl.ds(hkv * sb + kr, sb)
-            dq_c, dk_c, dv_c = _pair_grad_tile(
-                q_vmem[rows, :].astype(jnp.float32),
-                do_vmem[rows, :].astype(jnp.float32),
-                lse_vmem[rows, :][:, :1], delta_vmem[rows, :][:, :1],
-                kv_vmem[krows, :].astype(jnp.float32),
-                kv_vmem[vrows, :].astype(jnp.float32), scale, mask)
-            dq_vmem[rows, :] = dq_vmem[rows, :] + dq_c
-            dkv_vmem[krows, :] = dkv_vmem[krows, :] + dk_c
-            dkv_vmem[vrows, :] = dkv_vmem[vrows, :] + dv_c
-            return 0
-
-        lax.fori_loop(0, hq, head, 0)
-
     def tiled_bounds(a):
         """(:class:`_Tiles`, mask_of) of arrival ``a``'s tiled fold,
         ``mask_of(i, j)`` the tile pair's mask or None — the forward's
@@ -1060,15 +901,14 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             return _band_arrival(a, my, sb, tq, tk, window)
         return _full_tiles(sb, tq, tk), lambda i, j: None
 
-    def pair_grads_tiled(kv_at, dkv_at, init_zero, bounds):
+    def fold_grads(kv_at, dkv_at, init_zero, bounds):
         """Flash-tiled pair gradients: dK/dV tiles ride the inner-loop
         carry (loaded from — or, ``init_zero``, started at zero in —
         the dK/dV planes addressed by ``dkv_at(row0, n)``), residuals
         and the dQ accumulator stage per Q-tile straight from/to their
         HBM refs (dQ lives in its OUTPUT ref between arrivals).
         ``kv_at(row0, n)`` addresses the arrived K/V planes; ``bounds``
-        are ``tiled_bounds``'.  The protocol sees the exact same consume
-        window as the resident fold."""
+        are ``tiled_bounds``'."""
         t, mask_of = bounds
 
         def head(h, _):
@@ -1144,19 +984,13 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
 
         lax.fori_loop(0, hq, head, 0)
 
-    if tiles is None:
-        # stage the rank-local residuals once (independent → parallel)
-        copy_par((q_hbm, q_vmem), (do_hbm, do_vmem),
-                 (lse_hbm, lse_vmem), (delta_hbm, delta_vmem))
-        dq_vmem[:] = jnp.zeros((hq * sb, d), jnp.float32)
-    else:
-        # dQ accumulates in its output ref: zero it tile by tile
-        def zq_body(i, _):
-            dqt_vmem[0] = jnp.zeros((tq, d), jnp.float32)
-            copy_sync(dqt_vmem.at[0], dq_hbm.at[pl.ds(i * tq, tq)])
-            return 0
+    # dQ accumulates in its output ref: zero it tile by tile
+    def zq_body(i, _):
+        dqt_vmem[0] = jnp.zeros((tq, d), jnp.float32)
+        copy_sync(dqt_vmem.at[0], dq_hbm.at[pl.ds(i * tq, tq)])
+        return 0
 
-        lax.fori_loop(0, (hq * sb) // tq, zq_body, 0)
+    lax.fori_loop(0, (hq * sb) // tq, zq_body, 0)
 
     # fold 0 (own block) and assemble the circulating payload: K/V
     # planes straight from the input (IN the input/wire dtype), dK/dV
@@ -1164,16 +998,9 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     # en route)
     if P > 1:
         copy_sync(kv_hbm, own_kv_hbm)
-    if tiles is None:
-        copy_sync(kv_hbm, kv_vmem)
-        dkv_vmem[:] = jnp.zeros((kv_rows, d), jnp.float32)
-        pair_grads(my, masked=causal)  # a=0 is the diagonal block
-        copy_sync(dkv_vmem, own_dkv_hbm)
-    else:
-        pair_grads_tiled(
-            kv_at=lambda r0, n: kv_hbm.at[pl.ds(r0, n)],
-            dkv_at=lambda r0, n: own_dkv_hbm.at[pl.ds(r0, n)],
-            init_zero=True, bounds=tiled_bounds(0))
+    fold_grads(kv_at=lambda r0, n: kv_hbm.at[pl.ds(r0, n)],
+               dkv_at=lambda r0, n: own_dkv_hbm.at[pl.ds(r0, n)],
+               init_zero=True, bounds=tiled_bounds(0))
 
     neighbor_barrier()
 
@@ -1193,18 +1020,10 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             kv_idx = lax.rem(my - a + P, P)
 
             def consume(a=a, slot=slot):
-                if tiles is None:
-                    copy_sync(comm_kv_hbm.at[slot], kv_vmem)
-                    copy_sync(comm_dkv_hbm.at[slot], dkv_vmem)
-                    pair_grads(kv_idx, masked=window is not None)
-                    copy_sync(dkv_vmem, comm_dkv_hbm.at[slot])
-                else:
-                    pair_grads_tiled(
-                        kv_at=lambda r0, n: comm_kv_hbm.at[
-                            slot, pl.ds(r0, n)],
-                        dkv_at=lambda r0, n: comm_dkv_hbm.at[
-                            slot, pl.ds(r0, n)],
-                        init_zero=False, bounds=tiled_bounds(a))
+                fold_grads(
+                    kv_at=lambda r0, n: comm_kv_hbm.at[slot, pl.ds(r0, n)],
+                    dkv_at=lambda r0, n: comm_dkv_hbm.at[slot, pl.ds(r0, n)],
+                    init_zero=False, bounds=tiled_bounds(a))
 
             if causal and not zigzag:
                 # the diagonal block is always arrival 0 (kv_idx == my
@@ -1240,8 +1059,6 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                 snd(a - 1).wait_send()
             copy_sync(comm_dkv_hbm.at[slot], dkv_hbm)
 
-    if tiles is None:
-        copy_sync(dq_vmem, dq_hbm)  # tiled mode accumulated in place
     neighbor_barrier()
 
 
@@ -1313,18 +1130,18 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the concatenation of the blocks in rank order.
 
     The compiled path is the in-kernel RDMA circulation described in
-    the module docstring, with the fold mode (resident / tiled) chosen
-    by ``attention_vmem_plan`` from ``vmem_limit_bytes`` (default ~12
-    MiB); ``interpret=True`` (the CPU tier) runs the serial same-kernel
+    the module docstring, its folds tiled by ``attention_vmem_plan``
+    from ``vmem_limit_bytes`` (default ~12 MiB); where no tile fits,
+    the forward runs the loud ppermute-ring fallback and the backward
+    recomputes through the pure-jax ring.  ``interpret=True`` (the CPU
+    tier) runs the serial same-kernel
     path, or — under vma typing / a multi-axis mesh — the ppermute
     fallback with the shared loud warning.  ``interpret`` may also be a
     ``pltpu.InterpretParams``: Pallas's TPU interpreter, which models
     the local DMAs and their semaphores (a ring of one needs no more).
 
     Differentiable: the forward emits the logsumexp residual and the
-    backward runs its own fused ring kernel ([K,V,dK,dV] circulation)
-    in resident or flash-tiled mode per its VMEM plan; only an
-    impossible budget recomputes through the pure-jax ring.
+    backward runs its own fused ring kernel ([K,V,dK,dV] circulation).
 
     Sliding window (``causal=True, window=W``): the query at global
     position i attends key j iff ``i - W < j <= i`` — W keys, itself
@@ -1340,8 +1157,8 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     fold alone, with no landing slots, RDMA, barrier or credit, and
     none of a collective's compiler parameters.
 
-    Causal layout: with ``causal=True``, no window, ``size >= 2`` and
-    both folds tiled from tiles that divide Sb/2, the kernels run on
+    Causal layout: with ``causal=True``, no window, ``size >= 2``, a
+    fused backward and tiles that divide Sb/2, the kernels run on
     the zigzag layout (module docstring), which gives every rank the
     same causal work; two ppermutes per array move the halves in and
     out.  The blocks this function takes and returns stay contiguous in
@@ -1389,7 +1206,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scale = 1.0 / float(np.sqrt(d))
     # shared dtype/vma/mesh probing with the ring collectives (f32/bf16)
     vma_on, multi_axis = _check_args(q, axis_name, size, sub, "sum")
-    # Fold mode from the VMEM budget.  A forward NO tile can satisfy
+    # Tiles from the VMEM budget.  A forward NO tile can satisfy
     # degrades to the ppermute ring (graceful degradation, ROADMAP r5
     # #4): primal-identical and correct at any size, just without the
     # kernel's RDMA overlap — so the substitution is LOUD (warning +
@@ -1397,14 +1214,14 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # axis interpreter fallback, instead of the former
     # NotImplementedError that made an over-tight budget fatal.  A
     # causal ring prefers tiles that divide Sb/2 (the zigzag layout's).
+    from .. import mpit
+
     halves = causal and window is None and size >= 2
     try:
-        _, tiles = _vmem_plan(sb, d, hq, hkv, q.dtype, vmem_limit_bytes,
-                              False, halves)
+        _, tiles = attention_vmem_plan(sb, d, hq, hkv, q.dtype,
+                                       vmem_limit_bytes, False, halves)
     except NotImplementedError as e:
         import warnings
-
-        from .. import mpit
 
         warnings.warn(
             f"ring attention forward out of VMEM budget — executing the "
@@ -1413,9 +1230,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         mpit.count(attention_oob=1)
         return _fallback_attention(q, k, v, axis_name, size, scale, causal,
                                    window)
-    bwd_mode, bwd_tiles = _vmem_plan(sb, d, hq, hkv, q.dtype,
-                                     vmem_limit_bytes, True, halves)
-    bwd_fused = bwd_mode in ("resident", "tiled")
+    _, bwd_tiles = attention_vmem_plan(sb, d, hq, hkv, q.dtype,
+                                       vmem_limit_bytes, True, halves)
+    bwd_fused = bwd_tiles is not None
 
     def _reference(q_, k_, v_):
         """Pure-jax ring (differentiable) — primal-identical to the
@@ -1427,18 +1244,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if (vma_on or (multi_axis and size > 1)) and interpret:
         _fallback("ring_attention", axis_name, vma_on, multi_axis)
         return _reference(q, k, v)
-    # causal layout (module docstring): zigzag where both folds are
-    # tiled from half blocks; every other case keeps contiguous blocks
-    zigzag = (halves and tiles is not None and bwd_mode == "tiled"
+    # causal layout (module docstring): zigzag where both kernels fold
+    # tiles of half blocks; every other case keeps contiguous blocks
+    zigzag = (halves and bwd_fused
               and all((sb // 2) % t == 0 for t in tiles + bwd_tiles))
-    # every tiled fold runs the prefetching tile loop (_mk_tile_loop)
-    if tiles is not None or bwd_mode == "tiled":
-        from .. import mpit
-
-        mpit.count(attention_zigzag=int(zigzag),
-                   attention_window=int(window is not None
-                                        and tiles is not None),
-                   attention_prefetch=1)
+    mpit.count(attention_zigzag=int(zigzag),
+               attention_window=int(window is not None))
     ring = size > 1
     kind = "ring" if window is None else "swa"
 
@@ -1492,32 +1303,18 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             outs.append(((hq * sb, _LANES), jnp.float32))
         if ring:
             outs.append(((2, 2 * hkv * sb, d), q.dtype))  # landing slots
-        if tiles is None:
-            scratch = [
-                pltpu.VMEM((hq * sb, d), q.dtype),       # Q (all heads)
-                pltpu.VMEM((2 * hkv * sb, d), q.dtype),  # K/V staging
-                pltpu.VMEM((hq * sb, 1), jnp.float32),   # m
-                pltpu.VMEM((hq * sb, 1), jnp.float32),   # l
-                pltpu.VMEM((hq * sb, d), jnp.float32),   # o
-            ]
-            if with_lse:
-                scratch.append(
-                    pltpu.VMEM((hq * sb, _LANES), jnp.float32))  # lse
-        else:
-            tq, tk = tiles
-            outs += [((hq * sb, _LANES), jnp.float32),    # m state
-                     ((hq * sb, _LANES), jnp.float32),    # l state
-                     ((hq * sb, d), jnp.float32)]         # o state
-            scratch = [
-                pltpu.VMEM((tq, d), q.dtype),            # q tile
-                pltpu.VMEM((2, tk, d), q.dtype),         # k tiles (slots)
-                pltpu.VMEM((2, tk, d), q.dtype),         # v tiles (slots)
-                pltpu.VMEM((tq, _LANES), jnp.float32),   # m tile
-                pltpu.VMEM((tq, _LANES), jnp.float32),   # l tile
-                pltpu.VMEM((tq, d), jnp.float32),        # o tile
-                pltpu.SemaphoreType.DMA((2, 2)),         # k/v tiles (slot)
-            ]
-        scratch += [
+        tq, tk = tiles
+        outs += [((hq * sb, _LANES), jnp.float32),        # m state
+                 ((hq * sb, _LANES), jnp.float32),        # l state
+                 ((hq * sb, d), jnp.float32)]             # o state
+        scratch = [
+            pltpu.VMEM((tq, d), q.dtype),                # q tile
+            pltpu.VMEM((2, tk, d), q.dtype),             # k tiles (slots)
+            pltpu.VMEM((2, tk, d), q.dtype),             # v tiles (slots)
+            pltpu.VMEM((tq, _LANES), jnp.float32),       # m tile
+            pltpu.VMEM((tq, _LANES), jnp.float32),       # l tile
+            pltpu.VMEM((tq, d), jnp.float32),            # o tile
+            pltpu.SemaphoreType.DMA((2, 2)),             # k/v tiles (slot)
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2,)),               # send (parity)
             pltpu.SemaphoreType.DMA((2,)),               # recv (parity)
@@ -1531,8 +1328,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return (out, res[1]) if with_lse else out
 
     def _bwd_kernel_call(q_, k_, v_, out, lse, ct):
-        """Fused backward (resident or tiled mode): → (dq, dk, dv)
-        like q/k/v."""
+        """Fused backward: → (dq, dk, dv) like q/k/v."""
         qf = q_.reshape(hq * sb, d) if multihead else q_
         kf = k_.reshape(hkv * sb, d) if multihead else k_
         vf = v_.reshape(hkv * sb, d) if multihead else v_
@@ -1559,31 +1355,18 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      ((kv_rows, d), jnp.float32),        # own [dK,dV]
                      ((2, kv_rows, d), q.dtype),         # K/V landing slots
                      ((2, kv_rows, d), jnp.float32)]     # dK/dV landing slots
-        if bwd_tiles is None:
-            scratch = [
-                pltpu.VMEM((hq * sb, d), q.dtype),           # Q
-                pltpu.VMEM((hq * sb, d), q.dtype),           # dOut
-                pltpu.VMEM((hq * sb, _LANES), jnp.float32),  # lse
-                pltpu.VMEM((hq * sb, _LANES), jnp.float32),  # delta
-                pltpu.VMEM((kv_rows, d), q.dtype),           # K/V staging
-                pltpu.VMEM((kv_rows, d), jnp.float32),       # dK/dV staging
-                pltpu.VMEM((hq * sb, d), jnp.float32),       # dQ accum
-            ]
-        else:
-            tqb, tkb = bwd_tiles
-            scratch = [
-                pltpu.VMEM((2, tqb, d), q.dtype),            # q tiles (slots)
-                pltpu.VMEM((2, tqb, d), q.dtype),            # dOut tiles
-                pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # lse tiles
-                pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # delta tiles
-                pltpu.VMEM((tkb, d), q.dtype),               # k tile
-                pltpu.VMEM((tkb, d), q.dtype),               # v tile
-                pltpu.VMEM((tkb, d), jnp.float32),           # dk buffer
-                pltpu.VMEM((tkb, d), jnp.float32),           # dv buffer
-                pltpu.VMEM((2, tqb, d), jnp.float32),        # dq tiles
-                pltpu.SemaphoreType.DMA((2, 6)),             # 5 loads, dq out
-            ]
-        scratch += [
+        tqb, tkb = bwd_tiles
+        scratch = [
+            pltpu.VMEM((2, tqb, d), q.dtype),            # q tiles (slots)
+            pltpu.VMEM((2, tqb, d), q.dtype),            # dOut tiles
+            pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # lse tiles
+            pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # delta tiles
+            pltpu.VMEM((tkb, d), q.dtype),               # k tile
+            pltpu.VMEM((tkb, d), q.dtype),               # v tile
+            pltpu.VMEM((tkb, d), jnp.float32),           # dk buffer
+            pltpu.VMEM((tkb, d), jnp.float32),           # dv buffer
+            pltpu.VMEM((2, tqb, d), jnp.float32),        # dq tiles
+            pltpu.SemaphoreType.DMA((2, 6)),             # 5 loads, dq out
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2, 2)),             # send (parity, plane)
             pltpu.SemaphoreType.DMA((2, 2)),             # recv (parity, plane)
@@ -1606,7 +1389,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     # Differentiable wrapper: jax cannot autodiff through the kernel's
     # remote DMAs, so the backward is either the fused [K,V,dK,dV]
-    # ring kernel above (resident or tiled plan) or a recompute
+    # ring kernel above or a recompute
     # through the pure-jax ring (out-of-budget fallback; ppermutes
     # transpose to the inverse rotation) — either way the fused kernel
     # stays the forward hot path and training can jax.grad straight

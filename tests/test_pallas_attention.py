@@ -464,13 +464,13 @@ def test_zero_kv_heads_diagnosed():
 
 
 def test_vmem_plan_modes():
-    """attention_vmem_plan: small blocks → resident; big blocks → the
-    largest sublane-aligned divisor tile that fits; impossible budgets
-    → a diagnostic with the arithmetic."""
+    """attention_vmem_plan: small blocks → one tile a block; big blocks →
+    the largest sublane-aligned divisor tile that fits; impossible
+    budgets → a diagnostic with the arithmetic."""
     from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
 
-    mode, tiles = attention_vmem_plan(64, 128, 1, 1, jnp.float32)
-    assert mode == "resident" and tiles is None
+    assert attention_vmem_plan(64, 128, 1, 1, jnp.float32) == \
+        ("tiled", (64, 64))
     mode, tiles = attention_vmem_plan(4096, 128, 1, 1, jnp.float32)
     assert mode == "tiled"
     tq, tk = tiles
@@ -505,8 +505,7 @@ def test_tiled_parity_forced(causal):
     mesh = default_mesh(Pn)
     from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
 
-    limit = 100_000  # forces tiling at Sb=32 (score = 32*32*4 fits, but
-    # resident staging of Q+KV+o does not)
+    limit = 100_000  # 16-row forward tiles at Sb=32: two a block
     mode, tiles = attention_vmem_plan(Sb, d, 1, 1, jnp.float32,
                                       vmem_limit_bytes=limit)
     assert mode == "tiled" and tiles[0] < Sb, (mode, tiles)
@@ -548,9 +547,9 @@ def test_tiled_parity_gqa_bf16():
 
 def test_tiled_parity_large_block():
     """The VERDICT r4 ask: Sb >= 4096 f32 green on the interpreter —
-    the default budget picks the tiled fold (the resident score matrix
-    alone would be 64 MB) and the result still matches the dense
-    oracle.  P=2, global sequence 8192."""
+    the default budget tiles the fold (a whole-block score matrix alone
+    would be 64 MB) and the result still matches the dense oracle.
+    P=2, global sequence 8192."""
     Pn, Sb, d = 2, 4096, 128
     from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
 
@@ -572,8 +571,8 @@ def test_tiled_parity_large_block():
 
 def test_tiled_export_tpu():
     """The tiled fold (fori_loop over HBM-state tiles) lowers through
-    Mosaic for TPU at a block size the resident mode could never hold
-    (Sb=8192 per device: a 256 MB score matrix resident)."""
+    Mosaic for TPU at a block size no whole-block fold could hold in
+    VMEM (Sb=8192 per device: a 256 MB score matrix)."""
     mesh = AbstractMesh((8,), ("s",))
 
     def f(q, k, v):
@@ -606,9 +605,10 @@ def test_bwd_kernel_export_tpu():
     aval = jax.ShapeDtypeStruct((8 * 32, 128), jnp.float32)
     exp = jax.export.export(jf, platforms=["tpu"])(aval, aval, aval)
     assert exp.mlir_module().count("tpu_custom_call") >= 2
-    # no ppermute ring in the backward: the recompute fallback would
-    # show up as collective-permute ops
-    assert "collective_permute" not in exp.mlir_module()
+    # no ppermute ring in the backward: the only collective-permutes are
+    # the zigzag exchange's (two per array for q, k, v and the output,
+    # and their transposes); the recompute fallback would add 28 more
+    assert exp.mlir_module().count("stablehlo.collective_permute") == 16
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -651,8 +651,8 @@ def test_bwd_kernel_matches_reference_multihead(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_bwd_tiled_parity(causal):
-    """Forced-tiled FUSED backward (round 5): with a budget that rules
-    out the resident plan but admits backward tiles, grads from the
+    """Tiled FUSED backward (round 5): with a budget that admits only
+    backward tiles smaller than a block, grads from the
     tiled [K,V,dK,dV] ring kernel (dQ in HBM, per-tile staging, dK/dV
     carried through the inner loop, diagonal tile-skip) match the
     differentiated reference, full and causal."""
@@ -741,7 +741,7 @@ def test_bwd_fallback_out_of_budget():
 
 def test_bwd_tiled_export_tpu():
     """The TILED fused backward lowers through Mosaic at a block size
-    the resident plan could never hold (Sb=2048/device: s/p/dp/ds
+    no whole-block fold could hold in VMEM (Sb=2048/device: s/p/dp/ds
     temporaries alone would be 64 MB) — long-context training stays on
     the fused ring kernels, no ppermute recompute in the module."""
     from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
@@ -902,12 +902,13 @@ def _zigzag_case(case):
     Pn, Sb, d = (1, 32, 128) if case == "size1" else (4, 32, 128)
     if case == "halves_untileable":
         Sb = 24  # Sb/2 = 12 rows: no 8-row tile divides a chunk
+    # 16-row forward and 8-row backward tiles
     kw = dict(causal=True, interpret=True, vmem_limit_bytes=100_000)
     check_vma, grad = False, True
     if case == "full":
         kw["causal"] = False
-    elif case == "resident":
-        kw.pop("vmem_limit_bytes")
+    elif case == "default_budget":
+        kw.pop("vmem_limit_bytes")  # 16-row tiles in both kernels
     elif case == "vma_fallback":
         check_vma = True
     elif case == "forward_oob":
@@ -925,28 +926,23 @@ def _zigzag_case(case):
 
 
 @pytest.mark.parametrize("case,engaged", [
-    ("causal_tiled", 1), ("full", 0), ("resident", 0), ("size1", 0),
+    ("causal_tiled", 1), ("full", 0), ("default_budget", 1), ("size1", 0),
     ("vma_fallback", 0), ("forward_oob", 0), ("backward_oob", 0),
     ("halves_untileable", 0)])
 def test_zigzag_engages_only_causal_tiled(case, engaged):
     """``attention_zigzag`` rises once per trace where causal attention
-    runs both tiled kernels, and on no other path; ``attention_prefetch``
-    once per trace where either kernel takes the tiled plan."""
+    runs both fused kernels on tiles of half blocks, and on no other
+    path."""
     import warnings
 
     from mpi_tpu import mpit
 
     fn, aval = _zigzag_case(case)
-    before = {p: mpit.pvar_read(p)
-              for p in ("attention_zigzag", "attention_prefetch")}
+    before = mpit.pvar_read("attention_zigzag")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)  # the fallbacks'
         jax.jit(fn).trace(aval)
-    assert mpit.pvar_read("attention_zigzag") - \
-        before["attention_zigzag"] == engaged
-    tiled = case not in ("resident", "vma_fallback", "forward_oob")
-    assert mpit.pvar_read("attention_prefetch") - \
-        before["attention_prefetch"] == int(tiled)
+    assert mpit.pvar_read("attention_zigzag") - before == engaged
 
 
 def test_causal_tiled_contiguous_where_halves_do_not_tile():
@@ -1158,21 +1154,27 @@ def test_window_parity(Pn, window, Hq, Hkv):
 
 
 @pytest.mark.parametrize("Pn,window", [(1, 7), (2, 13), (4, 9)])
-def test_window_parity_resident(Pn, window):
-    """The resident folds (whole blocks in VMEM) mask the window too, and
-    a block wholly before every query's window folds nothing."""
+def test_window_parity_one_tile(Pn, window):
+    """At the default budget a 16-row block is one tile: the diagonal
+    tile masks the window too, and a block wholly before every query's
+    window folds nothing."""
+    from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
+
+    for bwd in (False, True):
+        assert attention_vmem_plan(16, 128, 6, 1, jnp.float32,
+                                   for_backward=bwd) == ("tiled", (16, 16))
     got, want, rise = _window_grads(Pn, 16, 6, 1, window, None, seed=window)
-    assert rise == 0  # no tile ranges on the resident plan
+    assert rise == 1
     for g, r in zip(got, want):
         assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
 
 
-@pytest.mark.parametrize("limit", [None, _TILE8], ids=["resident", "tiled"])
+@pytest.mark.parametrize("limit", [None, _TILE8], ids=["one_tile", "tiled"])
 @pytest.mark.parametrize("Hq,Hkv", [(1, 1), (4, 2), (6, 1)])
 def test_size_one_causal_runs_the_kernels(Hq, Hkv, limit):
     """P = 1 causal attention runs the fused kernels as a ring of one —
-    resident or tiled, forward and fused backward — and matches dense
-    causal attention and its gradients."""
+    one tile a block or 8-row tiles, forward and fused backward — and
+    matches dense causal attention and its gradients."""
     got, want, _ = _window_grads(1, 32, Hq, Hkv, None, limit, seed=Hq)
     for g, r in zip(got, want):
         assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
@@ -1237,7 +1239,7 @@ def _window_case(case):
     check_vma = False
     if case == "causal":
         kw["window"] = None
-    elif case == "resident":
+    elif case == "default_budget":
         kw.pop("vmem_limit_bytes")
     elif case == "vma_fallback":
         check_vma = True
@@ -1254,11 +1256,11 @@ def _window_case(case):
 
 
 @pytest.mark.parametrize("case,engaged", [
-    ("windowed", 1), ("size1_windowed", 1), ("causal", 0), ("resident", 0),
-    ("vma_fallback", 0), ("forward_oob", 0)])
+    ("windowed", 1), ("size1_windowed", 1), ("causal", 0),
+    ("default_budget", 1), ("vma_fallback", 0), ("forward_oob", 0)])
 def test_window_counted_once_per_trace(case, engaged):
     """``attention_window`` rises once per trace where windowed attention
-    takes the tiled kernels' windowed tile ranges, and on no other path;
+    takes the fused kernels' windowed tile ranges, and on no other path;
     a windowed call never takes the zigzag layout."""
     import warnings
 
@@ -1339,7 +1341,7 @@ def test_size_one_on_a_multi_axis_mesh_runs_the_kernel():
 def test_tiled_prefetch_parity(Pn, Sb, Hq, Hkv, causal, window):
     """Both tiled folds stage the next tile pair while they fold this one
     (8-row tiles): the output and the three gradients match dense
-    attention, and ``attention_prefetch`` rises once per trace."""
+    attention, and no fallback runs."""
     from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
 
     for bwd in (False, True):
@@ -1347,8 +1349,8 @@ def test_tiled_prefetch_parity(Pn, Sb, Hq, Hkv, causal, window):
                                    for_backward=bwd) == ("tiled", (8, 8))
     got, want, rise = _window_grads(Pn, Sb, Hq, Hkv, window, _TILE8,
                                     seed=Sb + Hq, causal=causal,
-                                    pvar="attention_prefetch")
-    assert rise == 1
+                                    pvar="attention_fallbacks")
+    assert rise == 0
     for g, r in zip(got, want):
         assert g.shape == r.shape
         assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
@@ -1480,8 +1482,8 @@ def test_tile_loop_schedule(lo, hi, store, monkeypatch):
 def test_cells_keep_512_tiles(sb, hq, hkv, halves):
     """The second tile buffers still leave both folds of the benchmark's
     attention cells on 512 x 512 tiles under the default budget."""
-    from mpi_tpu.tpu.pallas_attention import _vmem_plan
+    from mpi_tpu.tpu.pallas_attention import attention_vmem_plan
 
     for bwd in (False, True):
-        assert _vmem_plan(sb, 128, hq, hkv, jnp.bfloat16, None, bwd,
-                          halves) == ("tiled", (512, 512))
+        assert attention_vmem_plan(sb, 128, hq, hkv, jnp.bfloat16, None, bwd,
+                                   halves) == ("tiled", (512, 512))

@@ -108,30 +108,31 @@ def test_pallas_ring_reduce_scatter_64mib_compiles(mesh4, bidirectional):
     assert "%mpi_tpu_ring_reduce_scatter" in compiled.as_text()
 
 
-# (plan, Sb per chip, vmem_limit_bytes): the resident plan at Sb 2048
-# needs a raised VMEM limit, which the kernels hand to Mosaic; the tiled
-# plan is forced at Sb 8192 by a tight one
-_ATTENTION = {"resident": (2048, 100 * MIB), "tiled": (8192, 8 * MIB)}
+# (Sb per chip, vmem_limit_bytes, causal): full attention at Sb 2048 on
+# the default budget, and causal attention tiled at Sb 8192 under a
+# tight limit, which the kernels hand to Mosaic
+_ATTENTION = {"full": (2048, None, False), "tiled": (8192, 8 * MIB, True)}
 
 
 @pytest.mark.parametrize("backward", [False, True],
                          ids=["forward", "fused_backward"])
 @pytest.mark.parametrize("plan", sorted(_ATTENTION))
 def test_ring_attention_compiles(mesh4, plan, backward):
-    """Causal attention over the 4 chips; the tiled plan takes the zigzag
-    layout, whose exchange is the program's only collective-permutes."""
+    """Attention over the 4 chips on the tiled kernels; causal attention
+    takes the zigzag layout, whose exchange is the program's only
+    collective-permutes, and full attention keeps contiguous blocks."""
     from mpi_tpu import mpit
 
-    sb, limit = _ATTENTION[plan]
+    sb, limit, causal = _ATTENTION[plan]
     d = 128
     assert attention_vmem_plan(sb, d, 1, 1, jnp.bfloat16, limit,
-                               for_backward=backward)[0] == plan
+                               for_backward=backward)[0] == "tiled"
     spec = P(None, "world")
     q = jax.ShapeDtypeStruct((1, 4 * sb, d), jnp.bfloat16,
                              sharding=NamedSharding(mesh4, spec))
 
     def attn(qb, kb, vb):
-        return pallas_ring_attention(qb, kb, vb, "world", 4, causal=True,
+        return pallas_ring_attention(qb, kb, vb, "world", 4, causal=causal,
                                      vmem_limit_bytes=limit)
 
     if backward:
@@ -146,7 +147,7 @@ def test_ring_attention_compiles(mesh4, plan, backward):
                                       out_specs=out_specs), q, q, q)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    zigzag = plan == "tiled"
+    zigzag = causal
     assert mpit.pvar_read("attention_zigzag") - before == int(zigzag)
     assert ("collective-permute" in text) == zigzag
     # under autodiff the instruction names carry the transforms too
