@@ -219,7 +219,11 @@ def run(
       list of per-rank results.
     * ``backend='tpu'``: traces ``fn`` once as an SPMD program over a device
       mesh (shard_map) and executes it on all devices; returns the stacked
-      per-rank results (SURVEY.md §7 Milestone 1).
+      per-rank results (SURVEY.md §7 Milestone 1).  The compiled program
+      is kept: a later call with the same ``fn``, keyword values, mesh and
+      argument types runs it again without tracing, so, as with
+      ``jax.jit``, ``fn``'s Python side effects and the mutable globals it
+      reads take effect on a trace only (``tpu.run_spmd``).
     """
     backend = backend or os.environ.get(_ENV_BACKEND) or (
         "socket" if _ENV_RANK in os.environ else "local"

@@ -56,7 +56,7 @@ class _Counters:
                  "link_retained", "link_cow_snaps", "link_cow_bytes",
                  "link_syscalls", "link_rsyscalls", "link_torn",
                  "nbc_threads", "nbc_sms", "persist_starts",
-                 "trace_events", "tpu_traces",
+                 "trace_events", "tpu_traces", "tpu_reuses",
                  "rp_hits", "rp_misses", "rp_rdv", "rp_steered",
                  "rp_fold", "rp_user_in", "rp_user_fb",
                  "store_elections", "store_truncated", "store_dropped")
@@ -112,6 +112,7 @@ class _Counters:
         self.persist_starts = 0
         self.trace_events = 0
         self.tpu_traces = 0
+        self.tpu_reuses = 0
         self.rp_hits = 0
         self.rp_misses = 0
         self.rp_rdv = 0
@@ -162,6 +163,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           persistent_starts: int = 0,
           trace_events: int = 0,
           tpu_program_traces: int = 0,
+          tpu_program_reuses: int = 0,
           recv_pool_hits: int = 0,
           recv_pool_misses: int = 0,
           recv_pool_rendezvous: int = 0,
@@ -225,6 +227,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.persist_starts += persistent_starts
         counters.trace_events += trace_events
         counters.tpu_traces += tpu_program_traces
+        counters.tpu_reuses += tpu_program_reuses
         counters.rp_hits += recv_pool_hits
         counters.rp_misses += recv_pool_misses
         counters.rp_rdv += recv_pool_rendezvous
@@ -397,10 +400,13 @@ _PVARS: Dict[str, Callable[[], int]] = {
     "trace_events": lambda: counters.trace_events,
     # TPU entry (mpi_tpu/tpu/runner.py run_spmd): traces of the SPMD
     # program, counted in the traced body, so an executed call costs
-    # nothing.  Always on: a count that rises with every mpi_tpu.run
-    # call means each call re-traces (and re-lowers and re-compiles or
-    # re-loads) its program.
+    # nothing, and calls served by a program kept from an earlier call
+    # (no trace, lower or compile).  Always on; per process, traces +
+    # reuses = jit calls.  Traces that rise with every mpi_tpu.run call
+    # mean each call's key is new (a new function object, shape, dtype
+    # or keyword value) or unhashable.
     "tpu_program_traces": lambda: counters.tpu_traces,
+    "tpu_program_reuses": lambda: counters.tpu_reuses,
     # receive-side zero-copy (mpi_tpu/recvpool.py, ISSUE 17): pool
     # requests served by a recycled size-class buffer vs fresh
     # allocations (the page-fault pass the pool removes), frames the

@@ -10,15 +10,18 @@ come back stacked on a leading axis (rank order), mirroring
 
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 import os
+import threading
 from typing import Any, Callable, Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax._src.config import trace_context
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import mpit, telemetry
@@ -27,6 +30,53 @@ from .communicator import TpuCommunicator
 # per-process sequence number of run_spmd calls: a ``run`` span's
 # ``seq``, and its stage spans' ``parent``
 _RUN_SEQ = itertools.count(1)
+
+# compiled programs kept across run_spmd calls, by _program_key, least
+# recently used first; evicting one frees its executable
+_PROGRAMS_MAX = 64
+_PROGRAMS: collections.OrderedDict = collections.OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def clear_programs() -> None:
+    """Drop every kept program: the next call of each traces anew."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
+def _program_key(fn, args, kwargs, mesh, axis_name, check_vma):
+    """Everything the trace of ``fn`` reads: the function, its keyword
+    values (closed over as constants), the mesh, the arguments' tree and
+    each leaf's type and placement, and JAX's trace-time config (x64,
+    matmul precision, ...), as ``jax.jit`` keys its own cache.  None
+    where a part is unhashable or a leaf is no JAX type: that call keeps
+    nothing."""
+    try:
+        leaves, tree = jax.tree.flatten(args)
+        key = (fn, tuple((k, type(v), v) for k, v in sorted(kwargs.items())),
+               mesh, axis_name, check_vma, tree,
+               tuple((jax.typeof(x), getattr(x, "sharding", None))
+                     for x in leaves),
+               trace_context())
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _kept(key):
+    with _PROGRAMS_LOCK:
+        compiled = _PROGRAMS.get(key)
+        if compiled is not None:
+            _PROGRAMS.move_to_end(key)
+        return compiled
+
+
+def _keep(key, compiled) -> None:
+    with _PROGRAMS_LOCK:
+        _PROGRAMS[key] = compiled
+        if len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
 
 
 def default_mesh(nranks: Optional[int] = None, axis_name: str = "world") -> Mesh:
@@ -98,27 +148,45 @@ def run_spmd(
 
     With ``jit=True`` the program is traced, lowered and compiled (or
     loaded from the persistent cache) as explicit stages, then the
-    executable is called.  With tracing on (``telemetry.span``) the call
-    is a ``run`` span (``seq``, ``nranks``) and each stage a child span
-    (``trace``, ``lower``, ``compile``) carrying ``parent=seq``; the
-    dispatch, and the release of the program after it, are the ``run``
-    span's self time.  The ``tpu_program_traces``
-    pvar counts the program's traces, whether tracing is on or not."""
+    executable is called and kept.  A later call with the same ``fn``,
+    keyword values, mesh, ``axis_name``, ``check_vma``, argument tree,
+    leaf shapes, dtypes, weak types and shardings, and JAX trace config
+    calls the kept executable and does nothing else: as on a ``jax.jit``
+    cache hit, ``fn``'s Python body does not run, so its side effects,
+    and any mutable global it reads while tracing, take effect on a
+    trace only.  The last ``_PROGRAMS_MAX`` programs are kept, the least
+    recently used evicted first; a call with an unhashable keyword value
+    traces every time and keeps nothing.  With ``jit=False`` nothing is
+    kept.
+
+    With tracing on (``telemetry.span``) every call is a ``run`` span
+    (``seq``, ``nranks``); a call that traces has each stage as a child
+    span (``trace``, ``lower``, ``compile``) carrying ``parent=seq``, and
+    one that reuses a kept program has none.  The dispatch, and an
+    eviction, are the ``run`` span's self time.  The always-on pvars
+    ``tpu_program_traces`` and ``tpu_program_reuses`` count the calls
+    that traced and those a kept program served."""
     _trace_from_env()
     if mesh is None:
         mesh = default_mesh(nranks, axis_name)
-    comm = TpuCommunicator(axis_name, mesh)
-
-    def shard_fn(*a):
-        mpit.count(tpu_program_traces=1)  # runs once per trace, not per call
-        res = fn(comm, *a, **kwargs)
-        return jax.tree.map(lambda r: jnp.asarray(r)[None], res)
-
-    in_specs = tuple(P() for _ in args)
-    f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=P(axis_name), check_vma=check_vma)
+    key = (_program_key(fn, args, kwargs, mesh, axis_name, check_vma)
+           if jit else None)
     seq = next(_RUN_SEQ)
-    with telemetry.span("run", seq=seq, nranks=comm.size):
+    with telemetry.span("run", seq=seq, nranks=mesh.shape[axis_name]):
+        compiled = None if key is None else _kept(key)
+        if compiled is not None:
+            mpit.count(tpu_program_reuses=1)
+            return compiled(*args)
+        comm = TpuCommunicator(axis_name, mesh)
+
+        def shard_fn(*a):
+            mpit.count(tpu_program_traces=1)  # once per trace, not per call
+            res = fn(comm, *a, **kwargs)
+            return jax.tree.map(lambda r: jnp.asarray(r)[None], res)
+
+        in_specs = tuple(P() for _ in args)
+        f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=P(axis_name), check_vma=check_vma)
         if not jit:
             return f(*args)
         with telemetry.span("trace", parent=seq):
@@ -128,7 +196,9 @@ def run_spmd(
         with telemetry.span("compile", parent=seq):
             compiled = lowered.compile()
         out = compiled(*args)
-        # the one-shot program is freed (its executable unloaded) here,
+        if key is not None:
+            _keep(key, compiled)
+        # a program not kept is freed (its executable unloaded) here,
         # inside the span, and not after it where no span would see it
         del f, traced, lowered, compiled
     return out

@@ -1,8 +1,9 @@
 """Host spans of the TPU entry (``run_spmd``: ``run`` → ``trace``,
 ``lower``, ``compile``), their two switches (the flight recorder and a
 JAX profiler session), their clock (the profiler trace's), the always-on
-``tpu_program_traces`` pvar, and the names the program gives its
-collectives on the device side.  CPU, 8 virtual devices (conftest)."""
+``tpu_program_traces`` and ``tpu_program_reuses`` pvars, and the names
+the program gives its collectives on the device side.  CPU, 8 virtual
+devices (conftest)."""
 
 import glob
 
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 
 import mpi_tpu
 from mpi_tpu import mpit, ops, telemetry
+from mpi_tpu.tpu import runner
 
 STAGES = ("trace", "lower", "compile")
 
@@ -48,11 +50,14 @@ def _window(rec):
             for r in rec.find("tpu", "run")]
 
 
-def _profiled(tmp_path, calls=1):
-    """Run ``calls`` programs under a profiler session → its ProfileData."""
+def _profiled(tmp_path, calls=1, fresh=False):
+    """Run ``calls`` programs under a profiler session → its ProfileData.
+    ``fresh``: the kept program is dropped, so the first call traces."""
     from jax.profiler import ProfileData
 
     _run()  # compile outside the session: what is traced is the entry
+    if fresh:
+        runner.clear_programs()
     jax.profiler.start_trace(str(tmp_path))
     try:
         for _ in range(calls):
@@ -88,17 +93,20 @@ def test_recorder_run_spans_with_stage_children():
     assert len(runs) == 3
     seqs = [r["attrs"]["seq"] for r, _ in runs]
     assert seqs == sorted(set(seqs))  # a per-process counter
-    for run, kids in runs:
-        assert run["attrs"]["nranks"] == 4
-        assert set(kids) == set(STAGES)
-        t0, t1 = run["t_ns"], run["t_ns"] + run["dur_ns"]
-        for k in kids.values():
-            assert k["attrs"]["parent"] == run["attrs"]["seq"]
-            assert t0 <= k["t_ns"] and k["t_ns"] + k["dur_ns"] <= t1
-        order = [kids[s]["t_ns"] for s in STAGES]
-        assert order == sorted(order)
-    # one trace of the program per call, counted whether or not traced
-    assert ses.read("tpu_program_traces") == 3
+    assert all(run["attrs"]["nranks"] == 4 for run, _ in runs)
+    (run, kids), reused = runs[0], runs[1:]
+    assert set(kids) == set(STAGES)
+    t0, t1 = run["t_ns"], run["t_ns"] + run["dur_ns"]
+    for k in kids.values():
+        assert k["attrs"]["parent"] == run["attrs"]["seq"]
+        assert t0 <= k["t_ns"] and k["t_ns"] + k["dur_ns"] <= t1
+    order = [kids[s]["t_ns"] for s in STAGES]
+    assert order == sorted(order)
+    # the later calls run the kept program: a run span with no stages
+    assert all(kids == {} for _, kids in reused)
+    # one trace, then two reuses, counted whether or not traced
+    assert ses.read("tpu_program_traces") == 1
+    assert ses.read("tpu_program_reuses") == 2
 
 
 def test_traces_counted_with_tracing_off():
@@ -106,7 +114,8 @@ def test_traces_counted_with_tracing_off():
     ses.reset_all()
     for _ in range(2):
         jax.block_until_ready(_run())
-    assert ses.read("tpu_program_traces") == 2
+    assert ses.read("tpu_program_traces") == 1
+    assert ses.read("tpu_program_reuses") == 1
     assert telemetry.REC is None
 
 
@@ -132,14 +141,16 @@ def test_env_switch_is_read_at_the_first_call(monkeypatch):
 
 def test_profiler_session_alone_turns_spans_on(tmp_path):
     """A profiler session with no recorder: the first span starts one, and
-    the spans also lie on the trace's host plane."""
-    pd = _profiled(tmp_path, calls=2)
+    the spans also lie on the trace's host plane: a run span for each
+    call, stages for the one that traced."""
+    pd = _profiled(tmp_path, calls=2, fresh=True)
     rec = telemetry.recorder()
     assert rec is not None
     runs = _window(rec)
-    assert len(runs) == 2 and all(set(k) == set(STAGES) for _, k in runs)
-    for name in ("mpi_tpu.run",) + tuple("mpi_tpu." + s for s in STAGES):
-        assert len(_host_events(pd, name)) == 2, name
+    assert [set(k) for _, k in runs] == [set(STAGES), set()]
+    assert len(_host_events(pd, "mpi_tpu.run")) == 2
+    for name in ("mpi_tpu." + s for s in STAGES):
+        assert len(_host_events(pd, name)) == 1, name
 
 
 def test_recorder_and_profiler_share_a_clock(tmp_path):
