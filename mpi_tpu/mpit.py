@@ -43,7 +43,8 @@ class _Counters:
                  "pallas_fallbacks", "bytes_raw", "bytes_pickled", "copies",
                  "proc_failed", "revokes", "shrinks",
                  "faulty_dropped", "faulty_duplicated", "attention_oob",
-                 "attention_zz", "attention_win",
+                 "attention_zz", "attention_win", "attention_sink",
+                 "attention_band",
                  "sm_hits", "sm_bytes", "sm_fallbacks",
                  "v_deadlocks", "v_mismatches", "v_leaked", "v_double_waits",
                  "v_buf_overlaps", "v_comms_unfreed",
@@ -78,6 +79,8 @@ class _Counters:
         self.attention_oob = 0
         self.attention_zz = 0
         self.attention_win = 0
+        self.attention_sink = 0
+        self.attention_band = 0
         self.sm_hits = 0
         self.sm_bytes = 0
         self.sm_fallbacks = 0
@@ -134,7 +137,8 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           proc_failed: int = 0, revokes: int = 0, shrinks: int = 0,
           faulty_dropped: int = 0, faulty_duplicated: int = 0,
           attention_oob: int = 0, attention_zigzag: int = 0,
-          attention_window: int = 0, coll_sm_hits: int = 0,
+          attention_window: int = 0, attention_sink: int = 0,
+          attention_band_pairs: int = 0, coll_sm_hits: int = 0,
           coll_sm_bytes: int = 0, coll_sm_fallbacks: int = 0,
           verify_deadlocks: int = 0, verify_mismatches: int = 0,
           verify_requests_leaked: int = 0, verify_double_waits: int = 0,
@@ -193,6 +197,8 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.attention_oob += attention_oob
         counters.attention_zz += attention_zigzag
         counters.attention_win += attention_window
+        counters.attention_sink += attention_sink
+        counters.attention_band += attention_band_pairs
         counters.sm_hits += coll_sm_hits
         counters.sm_bytes += coll_sm_bytes
         counters.sm_fallbacks += coll_sm_fallbacks
@@ -283,6 +289,14 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # ranges (k tiles before a query tile's window skipped;
     # tpu/pallas_attention.py): says whether the window engaged
     "attention_window": lambda: counters.attention_win,
+    # ring-attention traces that folded a per-head sink logit into the
+    # softmax denominator (tpu/pallas_attention.py ``sinks=``)
+    "attention_sink": lambda: counters.attention_sink,
+    # query-key pairs (one query head) that the windowed kernels' tile
+    # plans fold on the ring's fullest rank, summed over the traces that
+    # take the windowed tile ranges: against the pairs the window keeps,
+    # what the tile plan wastes
+    "attention_band_pairs": lambda: counters.attention_band,
     # shared-memory collective arena (mpi_tpu/coll_sm.py): collectives
     # served entirely by arena load/store (zero ring frames), per-rank
     # payload bytes moved through it, and eligible requests that fell

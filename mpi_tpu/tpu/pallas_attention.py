@@ -111,8 +111,27 @@ multi-axis mesh the interpreter executes a ppermute ring fallback
 warning.  The compiled multi-axis path addresses neighbors by mesh
 coordinate exactly like pallas_ring.
 
-Restrictions (diagnosed): f32/bf16; head dim ``d`` a multiple of 128
-(lane width); block rows ``Sb`` a multiple of 8 (sublane tile).
+**Head widths**: q/k heads may be wider than v heads (``d_qk != d_v``;
+the output takes v's width).  Where the two widths agree, the K/V
+buffers stack all K planes then all V planes, one RDMA a hop; where they
+differ, K and V are buffers of their own, one RDMA each a hop on
+per-plane semaphore columns (``_SndGroup``), the protocol unchanged.  A
+q/k width that is not a whole number of lanes (192) is zero-padded to one
+(256) before the kernels, since a DMA slice must cover whole lanes; the
+zeros add nothing to QK^T, and autodiff slices dq/dk back.  A 128-wide
+MXU takes a 192 contraction in the passes of 256 either way.
+
+**Sinks** (``sinks=[Hq]`` float32): one learnable logit per query head
+in every row's softmax denominator, carrying no value.  The forward
+folds it in as the online softmax's initial state (m = s_h, l = 1,
+o = 0) at the step-0 fold, so it enters each row once whatever the ring
+size, and the logsumexp residual includes it; the backward kernel is
+unchanged (its probabilities come from that residual), and dsinks_h =
+-sum_i e^{s_h - L_i} (dO_i . O_i) is formed from the residuals outside
+it.  ``attention_sink`` (mpit pvar) counts the traces that take it.
+
+Restrictions (diagnosed): f32/bf16; v's head width ``d_v`` a multiple of
+128 (lane width); block rows ``Sb`` a multiple of 8 (sublane tile).
 """
 
 from __future__ import annotations
@@ -189,11 +208,17 @@ def _head_row(h, sb: int):
     return pl.multiple_of(h * sb, sb)
 
 
-def _tile_sizes(sb: int, sub: int, halves: bool = False):
-    """Sublane-aligned divisors of ``sb`` up to ``_MAX_TILE``, descending;
+def _lane_pad(d: int) -> int:
+    """``d`` rounded up to a whole number of lanes."""
+    return -(-d // _LANES) * _LANES
+
+
+def _tile_sizes(sb: int, sub: int, halves: bool = False,
+                cap: int = _MAX_TILE):
+    """Sublane-aligned divisors of ``sb`` up to ``cap``, descending;
     with ``halves``, those that also divide ``sb // 2`` (the zigzag
     layout's chunk) first."""
-    ts = [t for t in range(min(sb, _MAX_TILE), 0, -1)
+    ts = [t for t in range(min(sb, cap), 0, -1)
           if t % sub == 0 and sb % t == 0]
     if halves:
         ts.sort(key=lambda t: (sb // 2) % t != 0)  # stable: still descending
@@ -285,6 +310,13 @@ def _clamp0(x):
     return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
 
 
+def _min(a, b):
+    """min(a, b) of Python ints or traced scalars."""
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
 def _band_tiles(off, window: Optional[int], sb: int, tq: int, tk: int):
     """:class:`_Tiles` of one causal arrival on the contiguous layout: my
     query block starts ``off`` (>= 0; Python int or traced) positions
@@ -301,7 +333,7 @@ def _band_tiles(off, window: Optional[int], sb: int, tq: int, tk: int):
     nq, nk = sb // tq, sb // tk
 
     def k_of(i):
-        hi = jnp.minimum(nk, (off + i * tq + tq - 1) // tk + 1)
+        hi = _min(nk, (off + i * tq + tq - 1) // tk + 1)
         if window is None:
             return 0, hi
         return _clamp0(off + i * tq - window + 1) // tk, hi
@@ -310,14 +342,29 @@ def _band_tiles(off, window: Optional[int], sb: int, tq: int, tk: int):
         lo = _clamp0(j * tk - off) // tq
         if window is None:
             return lo, nq
-        return lo, jnp.minimum(nq, _clamp0(j * tk + tk + window - 2 - off
-                                           + tq) // tq)
+        return lo, _min(nq, _clamp0(j * tk + tk + window - 2 - off
+                                    + tq) // tq)
 
     if window is None:
         return _Tiles(0, nq, k_of, 0, nk, q_of)
-    q_hi = _clamp0(sb - 2 + window - off + tq) // tq
-    q_hi = min(nq, q_hi) if isinstance(q_hi, int) else jnp.minimum(nq, q_hi)
+    q_hi = _min(nq, _clamp0(sb - 2 + window - off + tq) // tq)
     return _Tiles(0, q_hi, k_of, _clamp0(off - window + 1) // tk, nk, q_of)
+
+
+def _band_pairs(size: int, sb: int, tq: int, tk: int, window: int) -> int:
+    """Query-key pairs of one query head in the tile pairs the windowed
+    forward folds on the ring's last rank, which folds the most: its
+    diagonal block and every past block that reaches a query's window
+    (``_band_tiles`` at Python-int offsets)."""
+    pairs = 0
+    for a in range(size):
+        if _before_window(a, sb, window):
+            break
+        t = _band_tiles(a * sb, window, sb, tq, tk)
+        for i in range(t.q_lo, t.q_hi):
+            lo, hi = t.k_of(i)
+            pairs += tq * tk * max(0, hi - lo)
+    return pairs
 
 
 def _before_window(a: int, sb: int, window: Optional[int]) -> bool:
@@ -544,38 +591,73 @@ def _mk_snd(first_src, comm_hbm, send_sem, recv_sem, dev_kw, right,
     return snd
 
 
-class _SndPair:
-    """Both planes of one split-dtype circulation hop as one descriptor:
-    every protocol call fans out to the K/V-plane and dK/dV-plane RDMAs
-    (same hop, same slot parity, per-plane semaphore columns), so the
-    backward's send/credit schedule reads — and model-checks — exactly
-    like the single-buffer version."""
+class _SndGroup:
+    """Every plane of one circulation hop as one descriptor: each
+    protocol call fans out to the planes' RDMAs (same hop, same slot
+    parity, per-plane semaphore columns) — the split-dtype backward's
+    K/V and dK/dV planes, and K and V of different widths — so the
+    send/credit schedule reads, and model-checks, exactly like the
+    single-buffer version."""
 
-    __slots__ = ("kv", "dkv")
+    __slots__ = ("planes",)
 
-    def __init__(self, kv, dkv):
-        self.kv, self.dkv = kv, dkv
+    def __init__(self, *planes):
+        self.planes = planes
 
     def start(self):
-        self.kv.start()
-        self.dkv.start()
+        for p in self.planes:
+            p.start()
 
     def wait(self):
-        self.kv.wait()
-        self.dkv.wait()
+        for p in self.planes:
+            p.wait()
 
     def wait_send(self):
-        self.kv.wait_send()
-        self.dkv.wait_send()
+        for p in self.planes:
+            p.wait_send()
 
     def wait_recv(self):
-        self.kv.wait_recv()
-        self.dkv.wait_recv()
+        for p in self.planes:
+            p.wait_recv()
+
+
+def _mk_snd_planes(firsts, comms, send_sem, recv_sem, dev_kw, right):
+    """``_mk_snd`` over every plane of a hop: ``firsts`` / ``comms`` hold
+    one send-0 source and one landing-slot buffer per plane.  One plane
+    sends on the (parity)-indexed semaphores; several send together as
+    a :class:`_SndGroup`, plane p on (parity, p)-indexed ones."""
+    if len(firsts) == 1:
+        return _mk_snd(firsts[0], comms[0], send_sem, recv_sem, dev_kw,
+                       right)
+    snds = [_mk_snd(f, c, send_sem, recv_sem, dev_kw, right, col=p)
+            for p, (f, c) in enumerate(zip(firsts, comms))]
+    return lambda u: _SndGroup(*(snd(u) for snd in snds))
+
+
+def _kv_at(planes, v0: int, slot=None):
+    """(k_at, v_at) of one K/V block held in ``planes``: ``k_at(r0, n)``
+    / ``v_at(r0, n)`` address ``n`` rows, from row ``r0``, of its
+    head-stacked K / V planes — in one [K; V] buffer (the V planes from
+    row ``v0``) where K and V share a width, else in a K buffer and a V
+    buffer.  ``slot``: the buffers are landing slots, indexed first."""
+    def at(ref, r0, n):
+        if slot is None:
+            return ref.at[pl.ds(r0, n)]
+        return ref.at[slot, pl.ds(r0, n)]
+
+    if len(planes) == 1:
+        kv, = planes
+        return (lambda r0, n: at(kv, r0, n),
+                lambda r0, n: at(kv, v0 + r0, n))
+    k, v = planes
+    return lambda r0, n: at(k, r0, n), lambda r0, n: at(v, r0, n)
 
 
 def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
                         vmem_limit_bytes: Optional[int] = None,
-                        for_backward: bool = False, halves: bool = False):
+                        for_backward: bool = False, halves: bool = False,
+                        *, dv: Optional[int] = None,
+                        window: Optional[int] = None):
     """Pick the fold's tile from a VMEM budget (trace time).
 
     Returns ``("tiled", (t, t))`` with the largest sublane-aligned
@@ -588,37 +670,46 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
     (pallas_ring_attention) turns into the loud ppermute-ring fallback
     (warning + ``attention_fallbacks`` pvar).
 
+    ``d`` is the q/k width the kernels stage (whole lanes), ``dv`` the
+    v and output width (default ``d``).  A sliding ``window`` caps the
+    tile at the window rounded up to whole lanes: a band narrower than
+    the tile would fold mostly masked pairs (a 128-key window folds
+    about 2x the pairs it keeps on 128-row tiles, 8x on 512-row ones).
+
     The estimates are deliberately generous (temporaries counted at
     f32, a spare plane for Mosaic's fusions) so a tile that fits here
     fits on real hardware with headroom."""
     from .pallas_ring import _SUBLANES
 
+    dk = d
+    dv = d if dv is None else dv
     esz = jnp.dtype(dtype).itemsize
     limit = _VMEM_BUDGET if vmem_limit_bytes is None else vmem_limit_bytes
     sub = _SUBLANES.get(jnp.dtype(dtype), 8)
+    cap = _MAX_TILE if window is None else min(_MAX_TILE, _lane_pad(window))
     if for_backward:
-        for t in _tile_sizes(sb, sub, halves):
-            tiled = (2 * (2 * t * d * esz      # q/do tiles, two slots
+        for t in _tile_sizes(sb, sub, halves, cap):
+            tiled = (2 * (t * (dk + dv) * esz  # q/do tiles, two slots
                           + 2 * t * _LANES * 4  # lse/delta tiles
-                          + t * d * 4)         # dq tile
-                     + 2 * t * d * esz         # k/v tiles (wire dtype)
-                     + 2 * t * d * 4           # dk/dv staging buffers
-                     + 2 * t * d * 4           # dk/dv loop carries
+                          + t * dk * 4)        # dq tile
+                     + t * (dk + dv) * esz     # k/v tiles (wire dtype)
+                     + t * (dk + dv) * 4       # dk/dv staging buffers
+                     + t * (dk + dv) * 4       # dk/dv loop carries
                      + 4 * t * t * 4           # s/p/dp/ds temporaries
-                     + 2 * t * d * 4)          # fold temporaries
+                     + t * (dk + dv) * 4)      # fold temporaries
             if tiled <= limit:
                 return ("tiled", (t, t))
         return ("fallback", None)  # recompute always works
 
     def tiled(t):
-        return (t * d * esz                    # q tile
-                + 2 * 2 * t * d * esz          # k/v tiles, two slots
+        return (t * dk * esz                   # q tile
+                + 2 * t * (dk + dv) * esz      # k/v tiles, two slots
                 + 2 * t * _LANES * 4           # m, l tiles
-                + t * d * 4                    # o tile
+                + t * dv * 4                   # o tile
                 + 2 * t * t * 4                # score tile temporaries
-                + 2 * t * d * 4)               # fold temporaries
+                + t * (dk + dv) * 4)           # fold temporaries
 
-    for t in _tile_sizes(sb, sub, halves):
+    for t in _tile_sizes(sb, sub, halves, cap):
         if tiled(t) <= limit:
             return ("tiled", (t, t))
     raise NotImplementedError(
@@ -628,22 +719,25 @@ def attention_vmem_plan(sb: int, d: int, hq: int, hkv: int, dtype,
         f"Raise vmem_limit_bytes or shrink the head dim.")
 
 
-def _kernel(params_smem, q_hbm, kv_hbm, *refs,
-            axis_name: str, size: int, sb: int, d: int,
+def _kernel(params_smem, *refs,
+            axis_name: str, size: int, sb: int, dk: int, dv: int,
             scale: float, pipelined: bool, mesh_ids: bool,
             tiles: Tuple[int, int], causal: bool = False, hq: int = 1,
             hkv: int = 1, with_lse: bool = False, zigzag: bool = False,
-            window: Optional[int] = None):
+            window: Optional[int] = None, sink: bool = False):
     """See module docstring for the step/slot/credit schedule.
 
     Multi-head layout (``hq`` query heads, ``hkv`` K/V heads — GQA when
     hkv < hq): the per-head [Sb, dh] planes are stacked along rows —
-    q/out/m/l/o rows [h*Sb, (h+1)*Sb) belong to query head h; the
-    circulating buffer stacks all K planes then all V planes
-    ([hkv*Sb] + [hkv*Sb] rows), so ONE RDMA moves every head's K/V and
-    the circulation/credit protocol is byte-identical to the
-    single-head case (pure payload relabeling — verified by the GQA
-    AttentionSim runs, tests/test_pallas_protocol.py).
+    q/out/m/l/o rows [h*Sb, (h+1)*Sb) belong to query head h; where K
+    and V share a width (``dk == dv``) the circulating buffer stacks all
+    K planes then all V planes ([hkv*Sb] + [hkv*Sb] rows), so ONE RDMA
+    moves every head's K/V and the circulation/credit protocol is
+    byte-identical to the single-head case (pure payload relabeling —
+    verified by the GQA AttentionSim runs, tests/test_pallas_protocol.py);
+    otherwise K ([hkv*Sb, dk]) and V ([hkv*Sb, dv]) circulate as two
+    planes of one hop (``_SndGroup``).  Inputs after ``params_smem``:
+    the [hq] f32 sink logits in SMEM (``sink``), q, then the K/V planes.
 
     ``tiles=(tq, tk)``: the fold's query and K/V tile rows; the m/l/o
     state lives in HBM between arrivals (module docstring).
@@ -658,15 +752,25 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
 
     ``with_lse`` adds a second output ref carrying L = m + log l (the
     fused backward's residual); inference/fallback-backward paths skip
-    its VMEM broadcast and HBM write entirely."""
+    its VMEM broadcast and HBM write entirely.
+
+    ``sink``: query head h's sink logit s_h is the a == 0 fold's initial
+    state (m = s_h, l = 1, o = 0), so it enters every row's denominator
+    once, and L includes it."""
     P = size
+    n_kv = 1 if dk == dv else 2  # K/V planes
+    sinks_smem = refs[0] if sink else None
+    refs = refs[int(sink):]
+    q_hbm, kv_hbm = refs[0], refs[1:1 + n_kv]
+    refs = refs[1 + n_kv:]
     n_out = 2 if with_lse else 1
     out_hbm, lse_hbm = refs[0], refs[1] if with_lse else None
-    comm_hbm = refs[n_out] if P > 1 else None  # landing slots
+    refs = refs[n_out:]
+    comm_hbm = refs[:n_kv] if P > 1 else None  # landing slots, per plane
     (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
      mt_vmem, lt_vmem, ot_vmem, tile_sems,
      copy_sem, send_sem, recv_sem, credit_sem,
-     par_sems) = refs[n_out + (P > 1):]
+     par_sems) = refs[n_kv if P > 1 else 0:]
     tq, tk = tiles
     tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
@@ -678,16 +782,19 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     copy_sync = _mk_copy_sync(copy_sem)
     copy_par = _mk_copy_par(par_sems)
     # send u (0..P-2): the block computed at step u moves on
-    fwd_rdma = _mk_snd(kv_hbm, comm_hbm, send_sem, recv_sem, dev_kw, right)
+    if P > 1:
+        fwd_rdma = _mk_snd_planes(kv_hbm, comm_hbm, send_sem, recv_sem,
+                                  dev_kw, right)
 
     # -- the fold: state in HBM, flash-style [tq, tk] inner loop ------------
 
     def fold(a, src):
-        """Fold arrival ``a`` whose K/V block sits in HBM ref ``src``
-        ([2*hkv*sb, d]).  Reads never conflict with the concurrent
+        """Fold arrival ``a`` whose K/V block sits in the HBM planes
+        ``src`` (``_kv_at``).  Reads never conflict with the concurrent
         forwarding RDMA (read/read); the credit follows both the fold
         and wait_send in program order, so a slot is recycled only after
         its block is folded and forwarded."""
+        k_at, v_at = _kv_at(src, hkv * sb)
 
         def run(t, mask_of):
             """Fold the tile pairs of ``t`` (:class:`_Tiles`);
@@ -700,9 +807,14 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
                     r0 = base + i * tq
                     if a == 0:
                         copy_sync(q_hbm.at[pl.ds(r0, tq)], qt_vmem)
-                        m0 = jnp.full((tq, 1), -jnp.inf, jnp.float32)
-                        l0 = jnp.zeros((tq, 1), jnp.float32)
-                        o0 = jnp.zeros((tq, d), jnp.float32)
+                        if sink:
+                            m0 = jnp.full((tq, 1), sinks_smem[h],
+                                          jnp.float32)
+                            l0 = jnp.ones((tq, 1), jnp.float32)
+                        else:
+                            m0 = jnp.full((tq, 1), -jnp.inf, jnp.float32)
+                            l0 = jnp.zeros((tq, 1), jnp.float32)
+                        o0 = jnp.zeros((tq, dv), jnp.float32)
                     else:
                         # the q tile rides the same parallel batch as
                         # the state tiles (review round 5)
@@ -716,9 +828,8 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
 
                     def kv_tiles(j, slot):
                         r0 = kvh * sb + j * tk
-                        return ((src.at[pl.ds(r0, tk)], kt_vmem.at[slot]),
-                                (src.at[pl.ds(hkv * sb + r0, tk)],
-                                 vt_vmem.at[slot]))
+                        return ((k_at(r0, tk), kt_vmem.at[slot]),
+                                (v_at(r0, tk), vt_vmem.at[slot]))
 
                     def k_body(j, slot, carry):
                         m, l, o = carry
@@ -781,7 +892,7 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
             else:
                 fwd_rdma(a).start()
                 fwd_rdma(a).wait()
-        fold(a, comm_hbm.at[slot])
+        fold(a, tuple(c.at[slot] for c in comm_hbm))
         if pipelined and a <= P - 2:
             # slot free only after the forward READ it out (wait_send),
             # then credit the writer for arrival a+2's reuse
@@ -809,9 +920,9 @@ def _kernel(params_smem, q_hbm, kv_hbm, *refs,
     neighbor_barrier()
 
 
-def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
-                dq_hbm, dkv_hbm, *refs,
-                axis_name: str, size: int, sb: int, d: int, scale: float,
+def _bwd_kernel(params_smem, *refs,
+                axis_name: str, size: int, sb: int, dk: int, dv: int,
+                scale: float,
                 pipelined: bool, mesh_ids: bool, causal: bool,
                 hq: int, hkv: int, tiles: Tuple[int, int],
                 zigzag: bool = False, window: Optional[int] = None):
@@ -834,9 +945,11 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     wire bytes, bit-identical values — bf16→f32 is exact, so nothing is
     lost versus the old f32 circulation); the dK/dV planes are PARTIAL
     SUMS, so they circulate f32.  Each hop is two RDMAs on per-plane
-    semaphore columns sharing one slot parity (_SndPair): the
+    semaphore columns sharing one slot parity (_SndGroup): the
     send/credit/barrier protocol — and therefore the model check — is
-    unchanged, only the payload is split.
+    unchanged, only the payload is split.  Where K and V differ in width
+    (``dk != dv``) each of the two is split again, K from V and dK from
+    dV: four planes a hop.
 
     Per-pair algebra (flash backward, exact):  P_ = exp(S - L) (the
     saved logsumexp — no rescaling pass), dP = dO·Vᵀ,
@@ -855,11 +968,21 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     ``_band_tiles`` (``window``: with the sliding window's bound).
 
     ``size == 1`` is a ring of one: fold 0 writes dK/dV home directly,
-    with no own/landing buffers, RDMA, barrier or credit."""
+    with no own/landing buffers, RDMA, barrier or credit.
+
+    Refs after ``params_smem``: inputs q, the K/V planes, dO, L, delta;
+    outputs dQ, the dK/dV planes (home), and in a ring the own and
+    landing buffers of the K/V and the dK/dV planes."""
     P = size
+    n = 1 if dk == dv else 2  # planes of K/V, and of dK/dV
+    q_hbm, kv_hbm = refs[0], refs[1:1 + n]
+    do_hbm, lse_hbm, delta_hbm, dq_hbm = refs[1 + n:5 + n]
+    dkv_hbm = refs[5 + n:5 + 2 * n]
+    refs = refs[5 + 2 * n:]
     if P > 1:
-        own_kv_hbm, own_dkv_hbm, comm_kv_hbm, comm_dkv_hbm = refs[:4]
-        refs = refs[4:]
+        own_kv_hbm, own_dkv_hbm, comm_kv_hbm, comm_dkv_hbm = (
+            refs[i * n:(i + 1) * n] for i in range(4))
+        refs = refs[4 * n:]
     else:
         # my own contribution is the whole result; nothing circulates
         own_kv_hbm = comm_kv_hbm = comm_dkv_hbm = None
@@ -879,15 +1002,12 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     copy_par = _mk_copy_par(par_sems)
 
     # send u (0..P-1): the block folded at step u moves on; send 0
-    # reads the assembled own-block buffer, not a comm slot.  Two
-    # planes per hop (split dtypes), one protocol (_SndPair).
-    snd_kv = _mk_snd(own_kv_hbm, comm_kv_hbm, send_sem, recv_sem, dev_kw,
-                     right, col=0)
-    snd_dkv = _mk_snd(own_dkv_hbm, comm_dkv_hbm, send_sem, recv_sem,
-                      dev_kw, right, col=1)
-
-    def snd(u):
-        return _SndPair(snd_kv(u), snd_dkv(u))
+    # reads the assembled own-block buffer, not a comm slot.  The K/V
+    # and dK/dV planes of a hop (split dtypes), one protocol (_SndGroup)
+    if P > 1:
+        snd = _mk_snd_planes(own_kv_hbm + own_dkv_hbm,
+                             comm_kv_hbm + comm_dkv_hbm, send_sem, recv_sem,
+                             dev_kw, right)
 
     def tiled_bounds(a):
         """(:class:`_Tiles`, mask_of) of arrival ``a``'s tiled fold,
@@ -901,31 +1021,32 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             return _band_arrival(a, my, sb, tq, tk, window)
         return _full_tiles(sb, tq, tk), lambda i, j: None
 
-    def fold_grads(kv_at, dkv_at, init_zero, bounds):
+    def fold_grads(kv_planes, dkv_planes, init_zero, bounds, slot=None):
         """Flash-tiled pair gradients: dK/dV tiles ride the inner-loop
         carry (loaded from — or, ``init_zero``, started at zero in —
-        the dK/dV planes addressed by ``dkv_at(row0, n)``), residuals
-        and the dQ accumulator stage per Q-tile straight from/to their
-        HBM refs (dQ lives in its OUTPUT ref between arrivals).
-        ``kv_at(row0, n)`` addresses the arrived K/V planes; ``bounds``
-        are ``tiled_bounds``'."""
+        the ``dkv_planes``), residuals and the dQ accumulator stage per
+        Q-tile straight from/to their HBM refs (dQ lives in its OUTPUT
+        ref between arrivals).  ``kv_planes`` hold the arrived K/V
+        block (``_kv_at``; ``slot``: both are landing slots);
+        ``bounds`` are ``tiled_bounds``'."""
         t, mask_of = bounds
+        k_at, v_at = _kv_at(kv_planes, hkv * sb, slot)
+        dk_at, dv_at = _kv_at(dkv_planes, hkv * sb, slot)
 
         def head(h, _):
             kvh = h // g
 
             def j_body(j, _):
                 kr = _head_row(kvh, sb) + j * tk
-                copy_par((kv_at(kr, tk), kt_vmem),
-                         (kv_at(hkv * sb + kr, tk), vt_vmem))
+                copy_par((k_at(kr, tk), kt_vmem), (v_at(kr, tk), vt_vmem))
 
                 def load():
-                    copy_par((dkv_at(kr, tk), accb_vmem),
-                             (dkv_at(hkv * sb + kr, tk), accb2_vmem))
+                    copy_par((dk_at(kr, tk), accb_vmem),
+                             (dv_at(kr, tk), accb2_vmem))
 
                 def zero():
-                    accb_vmem[:] = jnp.zeros((tk, d), jnp.float32)
-                    accb2_vmem[:] = jnp.zeros((tk, d), jnp.float32)
+                    accb_vmem[:] = jnp.zeros((tk, dk), jnp.float32)
+                    accb2_vmem[:] = jnp.zeros((tk, dv), jnp.float32)
 
                 # zero the dK/dV tiles only for the FIRST query head of
                 # each K/V group: later heads of the group must
@@ -958,7 +1079,7 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                     return dqt_vmem.at[slot], dq_hbm.at[q_rows(i)]
 
                 def i_body(i, slot, carry):
-                    dk, dv = carry
+                    dk_t, dv_t = carry
                     dq_c, dk_c, dv_c = _pair_grad_tile(
                         qt_vmem[slot].astype(jnp.float32),
                         dot_vmem[slot].astype(jnp.float32),
@@ -967,16 +1088,16 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
                         vt_vmem[:].astype(jnp.float32), scale,
                         mask_of(i, j))
                     dqt_vmem[slot] = dqt_vmem[slot] + dq_c
-                    return dk + dk_c, dv + dv_c
+                    return dk_t + dk_c, dv_t + dv_c
 
                 # the last dQ write-back is waited at the loop's end, so
                 # the next k tile's first dQ read sees it
-                dk, dv = tile_loop(*t.q_of(j), q_tiles, i_body, (dk0, dv0),
-                                   store=dq_store)
-                accb_vmem[:] = dk
-                accb2_vmem[:] = dv
-                copy_par((accb_vmem, dkv_at(kr, tk)),
-                         (accb2_vmem, dkv_at(hkv * sb + kr, tk)))
+                dk_t, dv_t = tile_loop(*t.q_of(j), q_tiles, i_body,
+                                       (dk0, dv0), store=dq_store)
+                accb_vmem[:] = dk_t
+                accb2_vmem[:] = dv_t
+                copy_par((accb_vmem, dk_at(kr, tk)),
+                         (accb2_vmem, dv_at(kr, tk)))
                 return 0
 
             lax.fori_loop(t.k_lo, t.k_hi, j_body, 0)
@@ -986,7 +1107,7 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
 
     # dQ accumulates in its output ref: zero it tile by tile
     def zq_body(i, _):
-        dqt_vmem[0] = jnp.zeros((tq, d), jnp.float32)
+        dqt_vmem[0] = jnp.zeros((tq, dk), jnp.float32)
         copy_sync(dqt_vmem.at[0], dq_hbm.at[pl.ds(i * tq, tq)])
         return 0
 
@@ -997,10 +1118,9 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
     # planes = my own f32 contribution (every other rank's accumulates
     # en route)
     if P > 1:
-        copy_sync(kv_hbm, own_kv_hbm)
-    fold_grads(kv_at=lambda r0, n: kv_hbm.at[pl.ds(r0, n)],
-               dkv_at=lambda r0, n: own_dkv_hbm.at[pl.ds(r0, n)],
-               init_zero=True, bounds=tiled_bounds(0))
+        for src, own in zip(kv_hbm, own_kv_hbm):
+            copy_sync(src, own)
+    fold_grads(kv_hbm, own_dkv_hbm, init_zero=True, bounds=tiled_bounds(0))
 
     neighbor_barrier()
 
@@ -1020,10 +1140,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             kv_idx = lax.rem(my - a + P, P)
 
             def consume(a=a, slot=slot):
-                fold_grads(
-                    kv_at=lambda r0, n: comm_kv_hbm.at[slot, pl.ds(r0, n)],
-                    dkv_at=lambda r0, n: comm_dkv_hbm.at[slot, pl.ds(r0, n)],
-                    init_zero=False, bounds=tiled_bounds(a))
+                fold_grads(comm_kv_hbm, comm_dkv_hbm, init_zero=False,
+                           bounds=tiled_bounds(a), slot=slot)
 
             if causal and not zigzag:
                 # the diagonal block is always arrival 0 (kv_idx == my
@@ -1057,7 +1175,8 @@ def _bwd_kernel(params_smem, q_hbm, kv_hbm, do_hbm, lse_hbm, delta_hbm,
             # home arrival: my block returns with every rank's dK/dV
             if pipelined:
                 snd(a - 1).wait_send()
-            copy_sync(comm_dkv_hbm.at[slot], dkv_hbm)
+            for comm, home in zip(comm_dkv_hbm, dkv_hbm):
+                copy_sync(comm.at[slot], home)
 
     neighbor_barrier()
 
@@ -1070,27 +1189,34 @@ def _ring_neighbors(axis_name: str, size: int) -> jnp.ndarray:
 
 
 def _fallback_attention(q, k, v, axis_name: str, size: int, scale: float,
-                        causal: bool = False, window: Optional[int] = None):
+                        causal: bool = False, window: Optional[int] = None,
+                        sinks=None):
     """The same online-softmax ring as jax ops over ppermute — the
     vma/multi-axis interpreter path, and the recompute body of the
     out-of-budget custom-vjp backward.  Accepts both layouts ([Sb, d]
     and [H, Sb, d]); the multi-head ring rotates the WHOLE [Hkv, Sb, d]
     K/V stacks once per step (one ppermute pair per step, exactly like
     the kernel's single circulating RDMA) with per-head folds inside —
-    NOT one ring per head (review round 4)."""
+    NOT one ring per head (review round 4).  ``sinks`` ([Hq]): each
+    head's state starts at m = s_h, l = 1, as in the kernel."""
     multihead = q.ndim == 3
     q3 = q if multihead else q[None]
     k3 = k if multihead else k[None]
     v3 = v if multihead else v[None]
-    hq, sb, d = q3.shape
+    hq, sb, _ = q3.shape
+    dv = v3.shape[-1]
     hkv = k3.shape[0]
     g = hq // hkv
     world_pairs = _world_pairs_of(size, None)
     perm = world_pairs([(r, (r + 1) % size) for r in range(size)])
     my = lax.axis_index(axis_name)
-    m = [jnp.full((sb, 1), -jnp.inf, jnp.float32) for _ in range(hq)]
-    l = [jnp.zeros((sb, 1), jnp.float32) for _ in range(hq)]
-    o = [jnp.zeros((sb, d), jnp.float32) for _ in range(hq)]
+    if sinks is None:
+        m = [jnp.full((sb, 1), -jnp.inf, jnp.float32) for _ in range(hq)]
+        l = [jnp.zeros((sb, 1), jnp.float32) for _ in range(hq)]
+    else:
+        m = [jnp.broadcast_to(sinks[h], (sb, 1)) for h in range(hq)]
+        l = [jnp.ones((sb, 1), jnp.float32) for _ in range(hq)]
+    o = [jnp.zeros((sb, dv), jnp.float32) for _ in range(hq)]
     kb, vb = k3, v3
     for step in range(size):
         mask = None
@@ -1112,6 +1238,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           axis_name: str, size: int, *,
                           scale: float = None, causal: bool = False,
                           window: Optional[int] = None,
+                          sinks: Optional[jnp.ndarray] = None,
                           interpret: bool = False,
                           vmem_limit_bytes: Optional[int] = None
                           ) -> jnp.ndarray:
@@ -1119,15 +1246,26 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     within a sliding ``window``) over a sequence-sharded axis.  Two
     shapes:
 
-    * single-head: ``q``/``k``/``v`` = this device's [Sb, dh] blocks;
-    * multi-head / GQA: ``q`` = [Hq, Sb, dh], ``k``/``v`` =
-      [Hkv, Sb, dh] with ``Hq % Hkv == 0`` — query head h attends K/V
-      head ``h // (Hq//Hkv)`` (Hkv == Hq is classic multi-head,
-      Hkv == 1 is MQA).  ALL heads ride ONE circulating RDMA per step.
+    * single-head: ``q``/``k`` = this device's [Sb, d_qk] blocks, ``v``
+      its [Sb, d_v] block;
+    * multi-head / GQA: ``q`` = [Hq, Sb, d_qk], ``k`` = [Hkv, Sb, d_qk],
+      ``v`` = [Hkv, Sb, d_v] with ``Hq % Hkv == 0`` — query head h
+      attends K/V head ``h // (Hq//Hkv)`` (Hkv == Hq is classic
+      multi-head, Hkv == 1 is MQA).  ALL heads ride ONE circulating hop
+      per step.
 
-    Returns this device's output block, shaped like ``q``.  Call inside
-    shard_map over a mesh with ``axis_name``; the global sequence is
-    the concatenation of the blocks in rank order.
+    Returns this device's output block, shaped like ``q`` but ``d_v``
+    wide.  Call inside shard_map over a mesh with ``axis_name``; the
+    global sequence is the concatenation of the blocks in rank order.
+    ``d_v`` must be a multiple of 128; a ``d_qk`` that is not (192) is
+    zero-padded to one before the kernels (module docstring).  The
+    default scale is 1/sqrt(d_qk).
+
+    ``sinks`` ([Hq], float32, optional): a learnable logit per query
+    head in every row's softmax denominator, with no value:
+    o_i = sum_j e^{z_ij} v_j / (e^{s_h} + sum_j e^{z_ij}).  It is
+    differentiable (the custom VJP returns dsinks) and counted by the
+    ``attention_sink`` mpit pvar.
 
     The compiled path is the in-kernel RDMA circulation described in
     the module docstring, its folds tiled by ``attention_vmem_plan``
@@ -1149,9 +1287,11 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     folds skip the k tiles wholly before a query tile's window and mask
     its edge tile as they mask the diagonal; an arrival wholly before
     every local query's window folds nothing (it still circulates).
-    Windowed kernels are named ``mpi_tpu_swa_attention_fwd`` / ``_bwd``,
-    and the ``attention_window`` mpit pvar counts the traces that take
-    the windowed tile ranges.
+    The tiles are at most W rows, rounded up to whole lanes.  Windowed
+    kernels are named ``mpi_tpu_swa_attention_fwd`` / ``_bwd``; the
+    ``attention_window`` mpit pvar counts the traces that take the
+    windowed tile ranges, and ``attention_band_pairs`` adds the
+    query-key pairs each such trace's tile plan folds (``_band_pairs``).
 
     ``size == 1`` runs the same kernels as a ring of one: the step-0
     fold alone, with no landing slots, RDMA, barrier or credit, and
@@ -1169,12 +1309,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(
             f"ring attention wants [Sb, dh] or [H, Sb, dh] blocks, got "
             f"q {q.shape}")
-    if k.shape != v.shape or q.shape[-2:] != k.shape[-2:] or \
-            q.ndim != k.ndim:
+    if k.shape[:-1] != v.shape[:-1] or q.shape[-2:] != k.shape[-2:] or \
+            not q.ndim == k.ndim == v.ndim:
         raise ValueError(
-            f"ring attention wants equal [.., rows, d] blocks for q/k/v "
-            f"(k/v may differ from q only in the HEAD count), got "
-            f"{q.shape}/{k.shape}/{v.shape}")
+            f"ring attention wants equal [.., rows, d] blocks for q/k (k/v "
+            f"may differ from q only in the HEAD count, v from k only in "
+            f"its width), got {q.shape}/{k.shape}/{v.shape}")
     if k.dtype != q.dtype or v.dtype != q.dtype:
         raise ValueError(
             f"ring attention wants one dtype for q/k/v (the circulating "
@@ -1187,10 +1327,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(
             f"GQA wants Hq a positive multiple of Hkv, got Hq={hq} "
             f"Hkv={hkv}")
-    sb, d = q.shape[-2:]
-    if d % _LANES:
+    sb, d_qk = q.shape[-2:]
+    dv = v.shape[-1]
+    if dv % _LANES:
         raise NotImplementedError(
-            f"head dim must be a multiple of {_LANES} (lane width), got {d}")
+            f"value head dim must be a multiple of {_LANES} (lane width), "
+            f"got {dv}")
     from .pallas_ring import _SUBLANES
 
     sub = _SUBLANES.get(jnp.dtype(q.dtype), 8)
@@ -1202,8 +1344,15 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(
             f"a sliding window wants causal=True and window >= 1, got "
             f"causal={causal} window={window}")
+    if sinks is not None:
+        if jnp.shape(sinks) != (hq,):
+            raise ValueError(
+                f"sinks wants one logit per query head, shape ({hq},), got "
+                f"{jnp.shape(sinks)}")
+        sinks = jnp.asarray(sinks, jnp.float32)
     if scale is None:
-        scale = 1.0 / float(np.sqrt(d))
+        scale = 1.0 / float(np.sqrt(d_qk))
+    d = _lane_pad(d_qk)  # the q/k width the kernels stage
     # shared dtype/vma/mesh probing with the ring collectives (f32/bf16)
     vma_on, multi_axis = _check_args(q, axis_name, size, sub, "sum")
     # Tiles from the VMEM budget.  A forward NO tile can satisfy
@@ -1217,9 +1366,11 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     from .. import mpit
 
     halves = causal and window is None and size >= 2
+    plan = functools.partial(attention_vmem_plan, sb, d, hq, hkv, q.dtype,
+                             vmem_limit_bytes, halves=halves, dv=dv,
+                             window=window)
     try:
-        _, tiles = attention_vmem_plan(sb, d, hq, hkv, q.dtype,
-                                       vmem_limit_bytes, False, halves)
+        _, tiles = plan(for_backward=False)
     except NotImplementedError as e:
         import warnings
 
@@ -1229,36 +1380,39 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"kernel. ({e})", RuntimeWarning, stacklevel=2)
         mpit.count(attention_oob=1)
         return _fallback_attention(q, k, v, axis_name, size, scale, causal,
-                                   window)
-    _, bwd_tiles = attention_vmem_plan(sb, d, hq, hkv, q.dtype,
-                                       vmem_limit_bytes, True, halves)
+                                   window, sinks)
+    _, bwd_tiles = plan(for_backward=True)
     bwd_fused = bwd_tiles is not None
 
-    def _reference(q_, k_, v_):
+    def _reference(q_, k_, v_, sinks_=None):
         """Pure-jax ring (differentiable) — primal-identical to the
         kernel; the out-of-budget custom-vjp backward recomputes
         through it."""
         return _fallback_attention(q_, k_, v_, axis_name, size, scale,
-                                   causal, window)
+                                   causal, window, sinks_)
 
     if (vma_on or (multi_axis and size > 1)) and interpret:
         _fallback("ring_attention", axis_name, vma_on, multi_axis)
-        return _reference(q, k, v)
+        return _reference(q, k, v, sinks)
     # causal layout (module docstring): zigzag where both kernels fold
     # tiles of half blocks; every other case keeps contiguous blocks
     zigzag = (halves and bwd_fused
               and all((sb // 2) % t == 0 for t in tiles + bwd_tiles))
     mpit.count(attention_zigzag=int(zigzag),
-               attention_window=int(window is not None))
+               attention_window=int(window is not None),
+               attention_sink=int(sinks is not None),
+               attention_band_pairs=0 if window is None else
+               _band_pairs(size, sb, *tiles, window))
     ring = size > 1
     kind = "ring" if window is None else "swa"
+    n_kv = 1 if d == dv else 2  # K/V planes (the kernels' layout)
 
     vma = (frozenset(jax.typeof(q).vma) | {axis_name} if vma_on
            else frozenset())
 
-    def _hbm_call(name, kern, outs, scratch, collective_id, params, *args):
+    def _hbm_call(name, kern, outs, scratch, collective_id, smem, *args):
         """One ring-kernel launch, named ``name`` in the program and in a
-        device trace: ``params`` in SMEM, ``args`` in HBM.
+        device trace: ``smem`` in SMEM, ``args`` in HBM.
         ``outs`` = (shape, dtype) pairs, all in HBM: the results first,
         then the kernel's HBM working buffers (landing slots, tiled
         state).  Mosaic allocates scratch only in VMEM/SMEM, so those
@@ -1267,7 +1421,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             kern,
             out_shape=tuple(jax.ShapeDtypeStruct(s, dt, vma=vma)
                             for s, dt in outs),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] +
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(smem) +
                      [pl.BlockSpec(memory_space=pl.ANY)] * len(args),
             out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
                             for _ in outs),
@@ -1279,113 +1433,139 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 has_side_effects=ring, vmem_limit_bytes=vmem_limit_bytes),
             interpret=interpret,
             name=name,
-        )(params, *args)
+        )(*smem, *args)
 
-    def _kernel_call(q_, k_, v_, with_lse):
-        """→ out [shaped like q], or (out, lse [hq*sb, _LANES] f32)
-        when ``with_lse`` (the fused-backward residual; inference and
-        fallback-backward paths skip its cost entirely)."""
+    def _flat(x, heads, width):
+        return x.reshape(heads * sb, width) if multihead else x
+
+    def _kv_planes(kf, vf):
+        """The kernels' K/V planes: one [K; V] buffer where K and V share
+        a width, else K and V."""
+        return (jnp.concatenate([kf, vf], axis=0),) if n_kv == 1 else (kf, vf)
+
+    def _kv_shapes(lead=()):
+        """(shape, rows) of the K/V planes, ``lead`` dims first."""
+        if n_kv == 1:
+            return [lead + (2 * hkv * sb, d)]
+        return [lead + (hkv * sb, d), lead + (hkv * sb, dv)]
+
+    def _kernel_call(q_, k_, v_, sinks_=None, *, with_lse):
+        """→ out [shaped like q, ``dv`` wide], or (out, lse [hq*sb,
+        _LANES] f32) when ``with_lse`` (the fused-backward residual;
+        inference and fallback-backward paths skip its cost entirely)."""
         # flat multi-head layout (see _kernel docstring): q/out stack
-        # query heads along rows; the circulating buffer stacks all K
-        # planes then all V planes so one RDMA carries every head
-        qf = q_.reshape(hq * sb, d) if multihead else q_
-        kf = k_.reshape(hkv * sb, d) if multihead else k_
-        vf = v_.reshape(hkv * sb, d) if multihead else v_
-        kv = jnp.concatenate([kf, vf], axis=0)
+        # query heads along rows; the circulating planes stack every
+        # head's K (V) so one hop carries every head
+        qf = _flat(q_, hq, d)
+        kv = _kv_planes(_flat(k_, hkv, d), _flat(v_, hkv, dv))
         params = _ring_neighbors(axis_name, size)
+        smem = (params,) if sinks_ is None else (params, sinks_)
         kern = functools.partial(
-            _kernel, axis_name=axis_name, size=size, sb=sb, d=d,
+            _kernel, axis_name=axis_name, size=size, sb=sb, dk=d, dv=dv,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=tiles,
-            with_lse=with_lse, zigzag=zigzag, window=window)
-        outs = [((hq * sb, d), jnp.float32)]
+            with_lse=with_lse, zigzag=zigzag, window=window,
+            sink=sinks_ is not None)
+        outs = [((hq * sb, dv), jnp.float32)]
         if with_lse:
             outs.append(((hq * sb, _LANES), jnp.float32))
         if ring:
-            outs.append(((2, 2 * hkv * sb, d), q.dtype))  # landing slots
+            outs += [(s, q.dtype) for s in _kv_shapes((2,))]  # landing slots
         tq, tk = tiles
         outs += [((hq * sb, _LANES), jnp.float32),        # m state
                  ((hq * sb, _LANES), jnp.float32),        # l state
-                 ((hq * sb, d), jnp.float32)]             # o state
+                 ((hq * sb, dv), jnp.float32)]            # o state
+        sems = (2,) if n_kv == 1 else (2, n_kv)
         scratch = [
             pltpu.VMEM((tq, d), q.dtype),                # q tile
             pltpu.VMEM((2, tk, d), q.dtype),             # k tiles (slots)
-            pltpu.VMEM((2, tk, d), q.dtype),             # v tiles (slots)
+            pltpu.VMEM((2, tk, dv), q.dtype),            # v tiles (slots)
             pltpu.VMEM((tq, _LANES), jnp.float32),       # m tile
             pltpu.VMEM((tq, _LANES), jnp.float32),       # l tile
-            pltpu.VMEM((tq, d), jnp.float32),            # o tile
+            pltpu.VMEM((tq, dv), jnp.float32),           # o tile
             pltpu.SemaphoreType.DMA((2, 2)),             # k/v tiles (slot)
             pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA((2,)),               # send (parity)
-            pltpu.SemaphoreType.DMA((2,)),               # recv (parity)
+            pltpu.SemaphoreType.DMA(sems),               # send (parity, plane)
+            pltpu.SemaphoreType.DMA(sems),               # recv (parity, plane)
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
         res = _hbm_call(f"mpi_tpu_{kind}_attention_fwd", kern, outs,
-                        scratch, 16, params, qf, kv)
+                        scratch, 16, smem, qf, *kv)
         out = res[0].astype(q_.dtype)
-        out = out.reshape(hq, sb, d) if multihead else out
+        out = out.reshape(hq, sb, dv) if multihead else out
         return (out, res[1]) if with_lse else out
 
-    def _bwd_kernel_call(q_, k_, v_, out, lse, ct):
-        """Fused backward: → (dq, dk, dv) like q/k/v."""
-        qf = q_.reshape(hq * sb, d) if multihead else q_
-        kf = k_.reshape(hkv * sb, d) if multihead else k_
-        vf = v_.reshape(hkv * sb, d) if multihead else v_
-        dof = ct.reshape(hq * sb, d) if multihead else ct
-        outf = out.reshape(hq * sb, d) if multihead else out
+    def _bwd_kernel_call(res, ct):
+        """Fused backward: ``res`` = (q, k, v[, sinks], out, lse) → (dq,
+        dk, dv[, dsinks]) like q/k/v[/sinks]."""
+        q_, k_, v_ = res[:3]
+        sinks_ = res[3] if len(res) == 6 else None
+        out, lse = res[-2:]
+        qf = _flat(q_, hq, d)
+        kf, vf = _flat(k_, hkv, d), _flat(v_, hkv, dv)
+        dof = _flat(ct, hq, dv)
+        outf = _flat(out, hq, dv)
         # the K/V planes circulate in the INPUT dtype (split-dtype seam:
         # pristine inputs lose nothing below f32, and bf16 halves their
         # wire bytes); only the dK/dV partial sums ride f32
-        kv = jnp.concatenate([kf, vf], axis=0)
+        kv = _kv_planes(kf, vf)
         delta = jnp.sum(dof.astype(jnp.float32) * outf.astype(jnp.float32),
                         axis=1, keepdims=True)
+        grads = ()
+        if sinks_ is not None:
+            # dsinks_h = -sum_i e^{s_h - L_i} delta_i over my rows of head h
+            p_sink = jnp.exp(sinks_[:, None] - lse[:, 0].reshape(hq, sb))
+            grads = (-jnp.sum(p_sink * delta.reshape(hq, sb), axis=1),)
         delta = jnp.broadcast_to(delta, (hq * sb, _LANES))
         params = _ring_neighbors(axis_name, size)
         kern = functools.partial(
-            _bwd_kernel, axis_name=axis_name, size=size, sb=sb, d=d,
+            _bwd_kernel, axis_name=axis_name, size=size, sb=sb, dk=d, dv=dv,
             scale=scale, pipelined=not interpret, mesh_ids=multi_axis,
             causal=causal, hq=hq, hkv=hkv, tiles=bwd_tiles, zigzag=zigzag,
             window=window)
-        kv_rows = 2 * hkv * sb
-        outs = [((hq * sb, d), jnp.float32),             # dQ
-                ((kv_rows, d), jnp.float32)]             # dK/dV (home)
+        outs = [((hq * sb, d), jnp.float32)]             # dQ
+        outs += [(s, jnp.float32) for s in _kv_shapes()]  # dK/dV (home)
         if ring:
-            outs += [((kv_rows, d), q.dtype),            # own [K,V] (wire)
-                     ((kv_rows, d), jnp.float32),        # own [dK,dV]
-                     ((2, kv_rows, d), q.dtype),         # K/V landing slots
-                     ((2, kv_rows, d), jnp.float32)]     # dK/dV landing slots
+            outs += ([(s, q.dtype) for s in _kv_shapes()]         # own [K,V]
+                     + [(s, jnp.float32) for s in _kv_shapes()]   # own [dK,dV]
+                     + [(s, q.dtype) for s in _kv_shapes((2,))]   # K/V slots
+                     + [(s, jnp.float32)                          # dK/dV slots
+                        for s in _kv_shapes((2,))])
         tqb, tkb = bwd_tiles
         scratch = [
             pltpu.VMEM((2, tqb, d), q.dtype),            # q tiles (slots)
-            pltpu.VMEM((2, tqb, d), q.dtype),            # dOut tiles
+            pltpu.VMEM((2, tqb, dv), q.dtype),           # dOut tiles
             pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # lse tiles
             pltpu.VMEM((2, tqb, _LANES), jnp.float32),   # delta tiles
             pltpu.VMEM((tkb, d), q.dtype),               # k tile
-            pltpu.VMEM((tkb, d), q.dtype),               # v tile
+            pltpu.VMEM((tkb, dv), q.dtype),              # v tile
             pltpu.VMEM((tkb, d), jnp.float32),           # dk buffer
-            pltpu.VMEM((tkb, d), jnp.float32),           # dv buffer
+            pltpu.VMEM((tkb, dv), jnp.float32),          # dv buffer
             pltpu.VMEM((2, tqb, d), jnp.float32),        # dq tiles
             pltpu.SemaphoreType.DMA((2, 6)),             # 5 loads, dq out
             pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA((2, 2)),             # send (parity, plane)
-            pltpu.SemaphoreType.DMA((2, 2)),             # recv (parity, plane)
+            pltpu.SemaphoreType.DMA((2, 2 * n_kv)),      # send (parity, plane)
+            pltpu.SemaphoreType.DMA((2, 2 * n_kv)),      # recv (parity, plane)
             pltpu.SemaphoreType.REGULAR((2,)),           # slot credits
             pltpu.SemaphoreType.DMA((8,)),               # parallel tiles
         ]
-        dq, dkv = _hbm_call(f"mpi_tpu_{kind}_attention_bwd", kern, outs,
-                            scratch, 17, params, qf, kv, dof, lse,
-                            delta)[:2]
+        dq, *dkv = _hbm_call(f"mpi_tpu_{kind}_attention_bwd", kern, outs,
+                             scratch, 17, (params,), qf, *kv, dof, lse,
+                             delta)[:1 + n_kv]
         dq = dq.astype(q_.dtype)
-        dk = dkv[:hkv * sb].astype(k_.dtype)
-        dv = dkv[hkv * sb:].astype(v_.dtype)
+        if n_kv == 1:
+            dk = dkv[0][:hkv * sb].astype(k_.dtype)
+            dv_ = dkv[0][hkv * sb:].astype(v_.dtype)
+        else:
+            dk, dv_ = dkv[0].astype(k_.dtype), dkv[1].astype(v_.dtype)
         if multihead:
-            return (dq.reshape(hq, sb, d), dk.reshape(hkv, sb, d),
-                    dv.reshape(hkv, sb, d))
-        return dq, dk, dv
+            dq, dk, dv_ = (dq.reshape(hq, sb, d), dk.reshape(hkv, sb, d),
+                           dv_.reshape(hkv, sb, dv))
+        return (dq, dk, dv_) + grads
 
-    def _primal(q_, k_, v_):
-        return _kernel_call(q_, k_, v_, with_lse=False)
+    def _primal(*args):
+        return _kernel_call(*args, with_lse=False)
 
     # Differentiable wrapper: jax cannot autodiff through the kernel's
     # remote DMAs, so the backward is either the fused [K,V,dK,dV]
@@ -1396,26 +1576,35 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # through it.
     attn = jax.custom_vjp(_primal)
 
-    def _fwd(q_, k_, v_):
+    def _fwd(*args):
         if not bwd_fused:
             # the recompute backward needs only the inputs — skip the
             # lse output and do not pin out/lse across fwd..bwd
-            return _kernel_call(q_, k_, v_, with_lse=False), (q_, k_, v_)
-        out, lse = _kernel_call(q_, k_, v_, with_lse=True)
-        return out, (q_, k_, v_, out, lse)
+            return _kernel_call(*args, with_lse=False), args
+        out, lse = _kernel_call(*args, with_lse=True)
+        return out, args + (out, lse)
 
     def _bwd(res, ct):
         if not bwd_fused:
-            q_, k_, v_ = res
-            _, vjp = jax.vjp(_reference, q_, k_, v_)
+            _, vjp = jax.vjp(_reference, *res)
             return vjp(ct)
-        q_, k_, v_, out, lse = res
-        return _bwd_kernel_call(q_, k_, v_, out, lse, ct)
+        return _bwd_kernel_call(res, ct)
 
     attn.defvjp(_fwd, _bwd)
+    if d != d_qk:
+        # whole lanes for the kernels' DMAs; autodiff slices dq/dk back
+        pad = [(0, 0)] * (q.ndim - 1) + [(0, d - d_qk)]
+        q, k = jnp.pad(q, pad), jnp.pad(k, pad)
+    extra = ()
+    if sinks is not None:
+        if vma_on and axis_name not in jax.typeof(sinks).vma:
+            # the custom VJP's dsinks are my rows' partial sums (varying);
+            # autodiff transposes this cast to the sum over the ring
+            sinks = lax.pcast(sinks, axis_name, to="varying")
+        extra = (sinks,)
     if not zigzag:
-        return attn(q, k, v)
+        return attn(q, k, v, *extra)
     # the exchange stays outside the custom_vjp, so autodiff transposes
     # it: the kernels see zigzag blocks only, the caller contiguous ones
     zz = functools.partial(_to_zigzag, axis_name=axis_name, size=size)
-    return _from_zigzag(attn(zz(q), zz(k), zz(v)), axis_name, size)
+    return _from_zigzag(attn(zz(q), zz(k), zz(v), *extra), axis_name, size)

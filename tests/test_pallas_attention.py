@@ -1071,10 +1071,12 @@ def test_zigzag_tiles_cover_causal_pairs_once(Pn, Sb, tq, tk):
 # -- sliding window, and P = 1 on the kernels --------------------------------
 
 
-def _dense_window(q, k, v, window=None, causal=True):
+def _dense_window(q, k, v, window=None, causal=True, sinks=None):
     """Dense causal GQA attention in float32 on the whole sequence, each
     query attending the ``window`` newest keys up to itself (all of them
-    where ``window`` is None; every key where not ``causal``)."""
+    where ``window`` is None; every key where not ``causal``); ``sinks``
+    ([Hq]): a logit per query head in every row's softmax denominator,
+    carrying no value.  q/k may be wider than v; scale 1/sqrt(q width)."""
     g = q.shape[0] // k.shape[0]
     k, v = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
     s = jnp.einsum("hqd,hkd->hqk", q, k,
@@ -1083,8 +1085,11 @@ def _dense_window(q, k, v, window=None, causal=True):
     i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
     keep = (j <= i) if window is None else (j <= i) & (j > i - window)
     s = jnp.where(keep | (not causal), s, -jnp.inf)
-    return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), v,
-                      precision="highest")
+    if sinks is not None:
+        sink = jnp.broadcast_to(sinks[:, None, None], s.shape[:2] + (1,))
+        s = jnp.concatenate([s, sink], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :n]
+    return jnp.einsum("hqk,hkd->hqd", p, v, precision="highest")
 
 
 def _window_grads(Pn, Sb, Hq, Hkv, window, limit, seed=0, causal=True,
@@ -1487,3 +1492,160 @@ def test_cells_keep_512_tiles(sb, hq, hkv, halves):
     for bwd in (False, True):
         assert attention_vmem_plan(sb, 128, hq, hkv, jnp.bfloat16, None, bwd,
                                    halves) == ("tiled", (512, 512))
+
+
+# -- split head widths (q/k 192, v 128) and sinks ----------------------------
+
+
+def _split_grads(Pn, Sb, Hq, Hkv, window, with_sinks, seed):
+    """(kernel out, dq, dk, dv[, dsinks]), (dense ones) and the mpit
+    ``attention_sink`` rise for causal attention over ``Pn`` ranks of
+    ``Sb`` rows with q/k heads 192 wide and v heads 128 wide, through
+    ``jax.grad``; sink logits uniform in [2, 6) where ``with_sinks``
+    (dsinks summed over the ranks' partial sums)."""
+    from mpi_tpu import mpit
+
+    rng = np.random.RandomState(seed)
+    S = Pn * Sb
+    q, k = (rng.randn(h, S, 192).astype(np.float32) for h in (Hq, Hkv))
+    v = rng.randn(Hkv, S, 128).astype(np.float32)
+    ct = rng.randn(Hq, S, 128).astype(np.float32)
+    sinks = rng.uniform(2, 6, Hq).astype(np.float32) if with_sinks else None
+
+    def loss(qb, kb, vb, cb, *s):
+        out = pallas_ring_attention(qb, kb, vb, "world", Pn, causal=True,
+                                    window=window,
+                                    sinks=s[0] if s else None,
+                                    interpret=True)
+        return jnp.sum(out * cb), out
+
+    def per_rank(*xs):
+        nargs = (0, 1, 2) + ((4,) if with_sinks else ())
+        grads, out = jax.grad(loss, argnums=nargs, has_aux=True)(*xs)
+        return tuple(grads[:3]) + tuple(g[None] for g in grads[3:]), out
+
+    spec = P(None, "world")
+    extra = (sinks,) if with_sinks else ()
+    before = mpit.pvar_read("attention_sink")
+    grads, out = jax.jit(jax.shard_map(
+        per_rank, mesh=default_mesh(Pn),
+        in_specs=(spec,) * 4 + (P(),) * len(extra),
+        out_specs=((spec,) * 3 + (P("world"),) * len(extra), spec),
+        check_vma=False))(q, k, v, ct, *extra)
+    rise = mpit.pvar_read("attention_sink") - before
+    got = [out] + list(grads[:3]) + [g.sum(0) for g in grads[3:]]
+
+    def dense_loss(q_, k_, v_, *s):
+        return jnp.sum(_dense_window(q_, k_, v_, window,
+                                     sinks=s[0] if s else None) * ct)
+
+    args = (q, k, v) + extra
+    want = [_dense_window(q, k, v, window, sinks=sinks)] + list(
+        jax.grad(dense_loss, argnums=tuple(range(len(args))))(*args))
+    return [np.asarray(x) for x in got], [np.asarray(x) for x in want], rise
+
+
+@pytest.mark.parametrize("Pn,Hq,Hkv,window,with_sinks", [
+    (1, 8, 1, 128, True), (2, 8, 1, 128, True), (1, 8, 1, 128, False),
+    (2, 8, 1, 128, False), (1, 16, 1, None, False), (2, 16, 1, None, False),
+    (2, 16, 1, None, True), (2, 8, 2, 40, True)],
+    ids=["swa_sinks_p1", "swa_sinks_p2", "swa_p1", "swa_p2", "full_g16_p1",
+         "full_g16_p2", "full_g16_sinks_p2", "swa40_g4_sinks_p2"])
+def test_split_widths_and_sinks_parity(Pn, Hq, Hkv, window, with_sinks):
+    """q/k heads 192 wide (zero-padded to 256 lanes before the kernels)
+    and v heads 128 wide, GQA groups of 8 and 16 (MiMo-V2-Flash's), a
+    128-key window on 128-row tiles (P = 2: K, V, dK and dV circulate as
+    four planes; full attention at P = 2 takes the zigzag layout), sinks
+    on and off: the output, dq, dk, dv and dsinks match dense attention
+    with the sink in the softmax denominator."""
+    got, want, rise = _split_grads(Pn, 256, Hq, Hkv, window, with_sinks,
+                                   seed=Pn * 10 + Hq + int(with_sinks))
+    assert rise == int(with_sinks)
+    assert len(got) == 4 + int(with_sinks)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        assert np.abs(g - r).max() <= 2e-5 * np.abs(r).max()
+
+
+@pytest.mark.parametrize("sb,window,tile,folds", [
+    (8192, 128, 128, 2_080_768), (16384, 128, 128, 4_177_920),
+    (8192, 100, 128, 2_080_768), (8192, 200, 256, 4_128_768),
+    (16384, 4096, 512, 66_060_288)],
+    ids=["mimo", "s16k", "w100", "w200", "trinity"])
+def test_window_tile_plan(sb, window, tile, folds):
+    """A window narrower than 512 rows caps the tile at the window rounded
+    up to whole lanes: a 128-key band folds at most about twice the pairs
+    it keeps, where 512-row tiles would fold 8x (12.8 %, 8,126,464 pairs
+    at S 8192); a window of 512 or more keeps 512-row tiles.
+    ``_band_pairs`` (the ``attention_band_pairs`` counter) counts what the
+    plan folds."""
+    from mpi_tpu.tpu.pallas_attention import _band_pairs, attention_vmem_plan
+
+    for bwd in (False, True):
+        assert attention_vmem_plan(sb, 256, 64, 8, jnp.bfloat16, None, bwd,
+                                   dv=128, window=window) == \
+            ("tiled", (tile, tile))
+    w = min(window, sb)
+    kept = w * (w + 1) // 2 + (sb - w) * w
+    band = _band_pairs(1, sb, tile, tile, window)
+    assert band == folds
+    if window == 128:
+        assert band <= 2 * kept
+        assert _band_pairs(1, sb, 512, 512, window) >= 7.8 * kept
+    if sb == 8192 and window == 128:
+        assert _band_pairs(1, sb, 512, 512, window) == 8_126_464
+
+
+def test_sinks_and_widths_diagnosed():
+    """Sinks want one logit per query head; v's width, unlike q/k's, must
+    be whole lanes."""
+    mesh = default_mesh(1)
+
+    def run(q_shape, v_shape, **kw):
+        def f(x):
+            q = jnp.zeros(q_shape, jnp.float32)
+            v = jnp.zeros(v_shape, jnp.float32)
+            return pallas_ring_attention(q, q, v, "world", 1, causal=True,
+                                         interpret=True, **kw)
+
+        jax.jit(jax.shard_map(lambda x: jnp.ravel(f(x))[:0], mesh=mesh,
+                              in_specs=P("world"), out_specs=P("world"),
+                              check_vma=False))(jnp.zeros(1, jnp.float32))
+
+    with pytest.raises(ValueError, match="one logit per query head"):
+        run((4, 8, 192), (4, 8, 128), sinks=jnp.zeros(3))
+    with pytest.raises(NotImplementedError, match="value head dim"):
+        run((4, 8, 192), (4, 8, 192))
+    with pytest.raises(ValueError, match="equal"):
+        run((4, 8, 192), (4, 16, 128))
+    run((4, 8, 192), (4, 8, 128), sinks=jnp.zeros(4))  # accepted
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["causal", "window"])
+def test_sink_and_band_pairs_counted_once_per_trace(window):
+    """``attention_sink`` rises once per trace that takes a sink, and
+    ``attention_band_pairs`` by the windowed plan's folded pairs per
+    windowed trace (none without a window)."""
+    from mpi_tpu import mpit
+    from mpi_tpu.tpu.pallas_attention import _band_pairs
+
+    def loss(qb, vb, s):
+        return jnp.sum(pallas_ring_attention(
+            qb, qb, vb, "world", 2, causal=True, window=window, sinks=s,
+            interpret=True, vmem_limit_bytes=_TILE8))
+
+    fn = jax.shard_map(jax.grad(loss, argnums=(0, 1, 2)),
+                       mesh=default_mesh(2),
+                       in_specs=(P(None, "world"),) * 2 + (P(),),
+                       out_specs=(P(None, "world"),) * 2 + (P(),),
+                       check_vma=False)
+    avals = (jax.ShapeDtypeStruct((2, 64, 192), jnp.float32),
+             jax.ShapeDtypeStruct((2, 64, 128), jnp.float32),
+             jax.ShapeDtypeStruct((2,), jnp.float32))
+    before = {p: mpit.pvar_read(p)
+              for p in ("attention_sink", "attention_band_pairs")}
+    jax.jit(fn).trace(*avals)
+    assert mpit.pvar_read("attention_sink") - before["attention_sink"] == 1
+    band = 0 if window is None else _band_pairs(2, 32, 8, 8, window)
+    assert mpit.pvar_read("attention_band_pairs") - \
+        before["attention_band_pairs"] == band

@@ -183,6 +183,39 @@ def test_attention_ring_of_one_compiles_for_one_chip(topo, window):
     assert "collective-permute" not in text
 
 
+@pytest.mark.parametrize("window,hkv,sinks", [(128, 8, True),
+                                               (None, 4, False)],
+                         ids=["swa128_sinks", "full"])
+def test_split_width_attention_compiles_for_one_chip(topo, window, hkv,
+                                                     sinks):
+    """MiMo-V2-Flash's two layer kinds at their real sizes, forward and
+    fused backward on one chip: q/k heads 192 wide (padded to 256 lanes
+    before the kernels) and v heads 128, 64 query heads over 8 (window
+    128 on 128-row tiles, a sink per head) or 4 K/V heads (causal)."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("sp",))
+    spec = P(None, "sp")
+    shard = NamedSharding(mesh, spec)
+    q = jax.ShapeDtypeStruct((64, 8192, 192), jnp.bfloat16, sharding=shard)
+    k = jax.ShapeDtypeStruct((hkv, 8192, 192), jnp.bfloat16, sharding=shard)
+    v = jax.ShapeDtypeStruct((hkv, 8192, 128), jnp.bfloat16, sharding=shard)
+    s = jax.ShapeDtypeStruct((64,), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+
+    def fn(qb, kb, vb, sb):
+        out, vjp = jax.vjp(lambda a, b, c, s_: pallas_ring_attention(
+            a, b, c, "sp", 1, causal=True, window=window,
+            sinks=s_ if sinks else None), qb, kb, vb, sb)
+        return vjp(jnp.ones_like(out))
+
+    text = _compile(jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3 + (P(),),
+                                  out_specs=(spec,) * 3 + (P(),)),
+                    q, k, v, s).as_text()
+    kind = "ring" if window is None else "swa"
+    assert f"mpi_tpu_{kind}_attention_fwd" in text
+    assert f"mpi_tpu_{kind}_attention_bwd" in text
+    assert text.count("tpu_custom_call") == 2
+
+
 def test_jacobi_step_8192_compiles_for_one_chip(topo):
     """The single-chip entry program (``__graft_entry__.entry``'s step) at
     the chip_smoke size: an 8192² f32 grid on one described v5e."""
