@@ -44,7 +44,7 @@ class _Counters:
                  "proc_failed", "revokes", "shrinks",
                  "faulty_dropped", "faulty_duplicated", "attention_oob",
                  "attention_zz", "attention_win", "attention_sink",
-                 "attention_band",
+                 "attention_band", "attention_direct",
                  "sm_hits", "sm_bytes", "sm_fallbacks",
                  "v_deadlocks", "v_mismatches", "v_leaked", "v_double_waits",
                  "v_buf_overlaps", "v_comms_unfreed",
@@ -81,6 +81,7 @@ class _Counters:
         self.attention_win = 0
         self.attention_sink = 0
         self.attention_band = 0
+        self.attention_direct = 0
         self.sm_hits = 0
         self.sm_bytes = 0
         self.sm_fallbacks = 0
@@ -138,7 +139,8 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
           faulty_dropped: int = 0, faulty_duplicated: int = 0,
           attention_oob: int = 0, attention_zigzag: int = 0,
           attention_window: int = 0, attention_sink: int = 0,
-          attention_band_pairs: int = 0, coll_sm_hits: int = 0,
+          attention_band_pairs: int = 0, attention_direct_out: int = 0,
+          coll_sm_hits: int = 0,
           coll_sm_bytes: int = 0, coll_sm_fallbacks: int = 0,
           verify_deadlocks: int = 0, verify_mismatches: int = 0,
           verify_requests_leaked: int = 0, verify_double_waits: int = 0,
@@ -199,6 +201,7 @@ def count(sends: int = 0, send_bytes: int = 0, recvs: int = 0,
         counters.attention_win += attention_window
         counters.attention_sink += attention_sink
         counters.attention_band += attention_band_pairs
+        counters.attention_direct += attention_direct_out
         counters.sm_hits += coll_sm_hits
         counters.sm_bytes += coll_sm_bytes
         counters.sm_fallbacks += coll_sm_fallbacks
@@ -297,6 +300,10 @@ _PVARS: Dict[str, Callable[[], int]] = {
     # take the windowed tile ranges: against the pairs the window keeps,
     # what the tile plan wastes
     "attention_band_pairs": lambda: counters.attention_band,
+    # ring-attention traces whose forward writes out and lse from its q
+    # loop (a ring of one: no m/l/o state in HBM, no separate output
+    # pass; tpu/pallas_attention.py)
+    "attention_direct_out": lambda: counters.attention_direct,
     # shared-memory collective arena (mpi_tpu/coll_sm.py): collectives
     # served entirely by arena load/store (zero ring frames), per-rank
     # payload bytes moved through it, and eligible requests that fell

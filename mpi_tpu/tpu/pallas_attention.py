@@ -36,15 +36,15 @@ for bf16 inputs.  Full OR causal attention (``causal=True`` masks by
 global position — block indices come from the SMEM params, so the same
 compiled kernel serves every rank); scale = 1/sqrt(d) by default.
 
-**The tiled fold**: flash-attention-style inner tiling.  The m/l/o
-state lives in HBM; each arrival loops over [tq]-row query tiles and
-[tk]-row K/V tiles (``lax.fori_loop``), staging each tile through small
-VMEM buffers, so the live score block is [tq, tk] and the block size is
-bounded by HBM, not VMEM.  The innermost loops of both folds are
-software-pipelined (``_mk_tile_loop``): two buffer slots, the next tile
-pair's copies running while the MXU folds this one.  The fold is a
-local subroutine between protocol events, so ``AttentionSim``'s
-verification of the circulation covers it.
+**The tiled fold**: flash-attention-style inner tiling.  In a ring the
+m/l/o state lives in HBM between arrivals; each arrival loops over
+[tq]-row query tiles and [tk]-row K/V tiles (``lax.fori_loop``), staging
+each tile through small VMEM buffers, so the live score block is [tq, tk]
+and the block size is bounded by HBM, not VMEM.  The innermost loops of
+both folds are software-pipelined (``_mk_tile_loop``): two buffer slots,
+the next tile pair's copies running while the MXU folds this one.  The
+fold is a local subroutine between protocol events, so
+``AttentionSim``'s verification of the circulation covers it.
 
 **VMEM plan** (``attention_vmem_plan``): the plan only picks the tile —
 the largest sublane-aligned divisor of Sb, at most 512 rows, whose
@@ -101,8 +101,13 @@ mpit pvar counts the traces that take the windowed tile ranges.
 
 **A ring of one**: at P = 1 the same kernels run the step-0 fold alone —
 no landing slots, RDMA, barrier or credit, and none of a collective's
-compiler parameters.  The kernels loop over heads in ``lax.fori_loop``s
-(row offsets traced), so the lowered code does not grow with the heads.
+compiler parameters.  Every q tile is final when its k loop ends, so the
+forward's state never leaves VMEM: each q tile writes out (and L) from
+the q loop, the write-back overlapping the next tile's load and fold,
+with no m/l/o buffers in HBM and no separate output pass
+(``attention_direct_out`` mpit pvar).  The kernels loop over heads in
+``lax.fori_loop``s (row offsets traced), so the lowered code does not
+grow with the heads.
 
 Under the interpreter (CPU tier) RDMAs run serially (start+wait, no
 credits/barriers) — same data path, no overlap; under vma typing or a
@@ -739,8 +744,9 @@ def _kernel(params_smem, *refs,
     planes of one hop (``_SndGroup``).  Inputs after ``params_smem``:
     the [hq] f32 sink logits in SMEM (``sink``), q, then the K/V planes.
 
-    ``tiles=(tq, tk)``: the fold's query and K/V tile rows; the m/l/o
-    state lives in HBM between arrivals (module docstring).
+    ``tiles=(tq, tk)``: the fold's query and K/V tile rows; in a ring
+    the m/l/o state lives in HBM between arrivals (module docstring), and
+    a last pass writes out (and L) from it.
 
     ``zigzag`` (causal): the blocks are in the zigzag layout and
     each arrival folds the tile ranges of ``_zigzag_tiles``; otherwise a
@@ -748,7 +754,10 @@ def _kernel(params_smem, *refs,
     sliding window's lower bound too).
 
     ``size == 1`` is a ring of one: the step-0 fold alone, with no
-    landing slots, RDMA, barrier or credit.
+    landing slots, RDMA, barrier or credit.  Its state never leaves VMEM
+    and there is no m/l/o in HBM: each q tile writes out (and L) from the
+    q loop through the staging tiles, its write-back waited at the next
+    q tile's epilogue or after the last.
 
     ``with_lse`` adds a second output ref carrying L = m + log l (the
     fused backward's residual); inference/fallback-backward paths skip
@@ -766,11 +775,12 @@ def _kernel(params_smem, *refs,
     n_out = 2 if with_lse else 1
     out_hbm, lse_hbm = refs[0], refs[1] if with_lse else None
     refs = refs[n_out:]
-    comm_hbm = refs[:n_kv] if P > 1 else None  # landing slots, per plane
-    (m_hbm, l_hbm, o_hbm, qt_vmem, kt_vmem, vt_vmem,
-     mt_vmem, lt_vmem, ot_vmem, tile_sems,
-     copy_sem, send_sem, recv_sem, credit_sem,
-     par_sems) = refs[n_kv if P > 1 else 0:]
+    if P > 1:
+        comm_hbm = refs[:n_kv]  # landing slots, per plane
+        m_hbm, l_hbm, o_hbm = refs[n_kv:n_kv + 3]  # state between arrivals
+        refs = refs[n_kv + 3:]
+    (qt_vmem, kt_vmem, vt_vmem, mt_vmem, lt_vmem, ot_vmem, tile_sems,
+     copy_sem, send_sem, recv_sem, credit_sem, par_sems) = refs
     tq, tk = tiles
     tile_loop = _mk_tile_loop(tile_sems)
     left = params_smem[0]
@@ -786,7 +796,21 @@ def _kernel(params_smem, *refs,
         fwd_rdma = _mk_snd_planes(kv_hbm, comm_hbm, send_sem, recv_sem,
                                   dev_kw, right)
 
-    # -- the fold: state in HBM, flash-style [tq, tk] inner loop ------------
+    def out_copies(r0):
+        """The write-back of the q tile at rows ``r0`` in a ring of one:
+        out from ``ot_vmem`` (and L from ``mt_vmem``), one semaphore
+        each."""
+        pairs = [(ot_vmem, out_hbm.at[pl.ds(r0, tq)])]
+        if with_lse:
+            pairs.append((mt_vmem, lse_hbm.at[pl.ds(r0, tq)]))
+        return [pltpu.make_async_copy(src, dst, par_sems.at[c])
+                for c, (src, dst) in enumerate(pairs)]
+
+    def wait_out(r0):
+        for cp in out_copies(r0):
+            cp.wait()
+
+    # -- the fold: flash-style [tq, tk] inner loop, state in HBM in a ring --
 
     def fold(a, src):
         """Fold arrival ``a`` whose K/V block sits in the HBM planes
@@ -839,6 +863,19 @@ def _kernel(params_smem, *refs,
 
                     m, l, o = tile_loop(*t.k_of(i), kv_tiles, k_body,
                                         (m0, l0, o0))
+                    if P == 1:
+                        # the only arrival: the tile is final, so out
+                        # (and L) leave from here.  The previous tile's
+                        # write-back still reads the staging tiles
+                        # (tiles run in row order from row 0).
+                        pl.when(r0 > 0)(lambda: wait_out(r0 - tq))
+                        ot_vmem[:] = o / l
+                        if with_lse:
+                            mt_vmem[:] = jnp.broadcast_to(m + jnp.log(l),
+                                                          (tq, _LANES))
+                        for cp in out_copies(r0):
+                            cp.start()
+                        return 0
                     mt_vmem[:] = jnp.broadcast_to(m, (tq, _LANES))
                     lt_vmem[:] = jnp.broadcast_to(l, (tq, _LANES))
                     ot_vmem[:] = o
@@ -851,6 +888,9 @@ def _kernel(params_smem, *refs,
                 return 0
 
             lax.fori_loop(0, hq, head, 0)
+            if P == 1:
+                assert (t.q_lo, t.q_hi) == (0, sb // tq)  # every q tile
+                wait_out(hq * sb - tq)
 
         # q tiles an arrival leaves out keep their m/l/o state in HBM
         # untouched; the diagonal arrival a == 0 visits every q tile
@@ -901,8 +941,9 @@ def _kernel(params_smem, *refs,
             pltpu.semaphore_signal(credit_sem.at[slot], inc=1,
                                    **dev_kw(left))
 
-    # output: out = o / l and (with_lse) the logsumexp L = m + log l —
-    # the fused backward kernel's residual
+    # output of a ring (a ring of one wrote its own from the q loop): out
+    # = o / l and (with_lse) the logsumexp L = m + log l — the fused
+    # backward kernel's residual
     def out_body(i, _):
         r0 = i * tq
         copy_par((m_hbm.at[pl.ds(r0, tq)], mt_vmem),
@@ -915,7 +956,8 @@ def _kernel(params_smem, *refs,
             copy_sync(mt_vmem, lse_hbm.at[pl.ds(r0, tq)])
         return 0
 
-    lax.fori_loop(0, (hq * sb) // tq, out_body, 0)
+    if P > 1:
+        lax.fori_loop(0, (hq * sb) // tq, out_body, 0)
 
     neighbor_barrier()
 
@@ -1295,7 +1337,9 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     ``size == 1`` runs the same kernels as a ring of one: the step-0
     fold alone, with no landing slots, RDMA, barrier or credit, and
-    none of a collective's compiler parameters.
+    none of a collective's compiler parameters; the forward writes out
+    (and the residual) from its q loop, with no softmax state in HBM
+    (``attention_direct_out`` mpit pvar).
 
     Causal layout: with ``causal=True``, no window, ``size >= 2``, a
     fused backward and tiles that divide Sb/2, the kernels run on
@@ -1401,6 +1445,7 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     mpit.count(attention_zigzag=int(zigzag),
                attention_window=int(window is not None),
                attention_sink=int(sinks is not None),
+               attention_direct_out=int(size == 1),
                attention_band_pairs=0 if window is None else
                _band_pairs(size, sb, *tiles, window))
     ring = size > 1
@@ -1469,12 +1514,12 @@ def pallas_ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         outs = [((hq * sb, dv), jnp.float32)]
         if with_lse:
             outs.append(((hq * sb, _LANES), jnp.float32))
-        if ring:
+        if ring:  # a ring of one keeps its state in VMEM
             outs += [(s, q.dtype) for s in _kv_shapes((2,))]  # landing slots
+            outs += [((hq * sb, _LANES), jnp.float32),    # m state
+                     ((hq * sb, _LANES), jnp.float32),    # l state
+                     ((hq * sb, dv), jnp.float32)]        # o state
         tq, tk = tiles
-        outs += [((hq * sb, _LANES), jnp.float32),        # m state
-                 ((hq * sb, _LANES), jnp.float32),        # l state
-                 ((hq * sb, dv), jnp.float32)]            # o state
         sems = (2,) if n_kv == 1 else (2, n_kv)
         scratch = [
             pltpu.VMEM((tq, d), q.dtype),                # q tile

@@ -1649,3 +1649,137 @@ def test_sink_and_band_pairs_counted_once_per_trace(window):
     band = 0 if window is None else _band_pairs(2, 32, 8, 8, window)
     assert mpit.pvar_read("attention_band_pairs") - \
         before["attention_band_pairs"] == band
+
+
+def _pallas_outs(jaxpr, suffix):
+    """[result avals] of every ``pallas_call`` in ``jaxpr`` (nested ones
+    too) whose kernel name ends with ``suffix``."""
+    from jax.extend import core as jcore
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            if str(eqn.params["name"]).endswith(suffix):
+                found.append([v.aval for v in eqn.outvars])
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    found += _pallas_outs(sub, suffix)
+    return found
+
+
+def _lse64(q, k, window, causal, sinks):
+    """float64 numpy logsumexp of every query row's scores (scale
+    1/sqrt(q width)), the sink logit included: [Hq, S]."""
+    g = q.shape[0] // k.shape[0]
+    k = np.repeat(k, g, axis=0).astype(np.float64)
+    s = np.einsum("hqd,hkd->hqk", q.astype(np.float64), k) / \
+        np.sqrt(q.shape[-1])
+    n = q.shape[1]
+    i, j = np.arange(n)[:, None], np.arange(n)[None, :]
+    if causal:
+        keep = (j <= i) if window is None else (j <= i) & (j > i - window)
+        s = np.where(keep, s, -np.inf)
+    if sinks is not None:
+        s = np.concatenate([s, np.broadcast_to(
+            sinks.astype(np.float64)[:, None, None], s.shape[:2] + (1,))],
+            axis=-1)
+    top = s.max(axis=-1, keepdims=True)
+    return (top + np.log(np.exp(s - top).sum(axis=-1, keepdims=True)))[..., 0]
+
+
+@pytest.mark.parametrize("Sb,Hq,Hkv,d_qk,window,with_sinks,limit", [
+    (32, 2, 2, 128, None, False, _TILE8),
+    (32, 6, 1, 128, 5, False, _TILE8),
+    (32, 1, 1, 128, 13, True, _TILE8),
+    (256, 8, 1, 192, 128, True, None),
+    (256, 6, 1, 192, None, True, None),
+    (32, 2, 1, 128, "full", False, _TILE8)],
+    ids=["causal", "w5_gqa6", "w13_sinks", "w128_sinks_split",
+         "causal_gqa6_sinks_split", "full"])
+def test_size_one_forward_writes_out_and_lse_directly(Sb, Hq, Hkv, d_qk,
+                                                      window, with_sinks,
+                                                      limit):
+    """A ring of one writes out and lse from the forward's q loop: its
+    ``pallas_call`` has only its results (out, and lse under
+    differentiation), no m/l/o working buffers in HBM; lse matches a
+    float64 logsumexp with the sink in it, and out dense attention."""
+    rng = np.random.RandomState(Sb + Hq + int(with_sinks))
+    q = rng.randn(Hq, Sb, d_qk).astype(np.float32)
+    k = rng.randn(Hkv, Sb, d_qk).astype(np.float32)
+    v = rng.randn(Hkv, Sb, 128).astype(np.float32)
+    sinks = rng.uniform(2, 6, Hq).astype(np.float32) if with_sinks else None
+    causal = window != "full"
+    window = None if window == "full" else window
+    extra = (sinks,) if with_sinks else ()
+
+    def attn(qb, kb, vb, *s):
+        return pallas_ring_attention(qb, kb, vb, "world", 1, causal=causal,
+                                     window=window, sinks=s[0] if s else None,
+                                     interpret=True, vmem_limit_bytes=limit)
+
+    def with_lse(qb, kb, vb, *s):
+        out, vjp = jax.vjp(lambda a, b, c: attn(a, b, c, *s), qb, kb, vb)
+        lse, = [x for x in jax.tree_util.tree_leaves(vjp)
+                if x.shape == (Hq * Sb, 128)]
+        return out, lse
+
+    smap = lambda f, n_out: jax.shard_map(
+        f, mesh=default_mesh(1), in_specs=(P(),) * (3 + len(extra)),
+        out_specs=(P(),) * n_out if n_out > 1 else P(), check_vma=False)
+    kind = "ring" if window is None else "swa"
+    fwd = f"mpi_tpu_{kind}_attention_fwd"
+    out_aval = jax.core.ShapedArray((Hq * Sb, 128), jnp.float32)
+    for f, n_out, results in ((attn, 1, [out_aval]),
+                              (with_lse, 2, [out_aval, out_aval])):
+        jaxpr = jax.make_jaxpr(smap(f, n_out))(q, k, v, *extra).jaxpr
+        calls = _pallas_outs(jaxpr, fwd)
+        assert len(calls) == 1
+        assert [(a.shape, a.dtype) for a in calls[0]] == \
+            [(a.shape, a.dtype) for a in results]
+    out, lse = jax.jit(smap(with_lse, 2))(q, k, v, *extra)
+    want = _lse64(q, k, window, causal, sinks)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.broadcast_to(want.reshape(-1, 1),
+                                               (Hq * Sb, 128)),
+                               rtol=0, atol=2e-5 * max(1, np.abs(want).max()))
+    dense = _dense_window(q, k, v, window, causal, sinks=sinks)
+    assert np.abs(np.asarray(out) - dense).max() <= \
+        2e-5 * np.abs(dense).max()
+
+
+@pytest.mark.parametrize("case,engaged", [
+    ("size1", 1), ("size1_inference", 1), ("size1_forward_oob", 0),
+    ("size1_vma_fallback", 0), ("p2", 0), ("p4", 0)])
+def test_direct_out_counted_once_per_trace(case, engaged):
+    """``attention_direct_out`` rises by one per trace whose forward writes
+    out and lse from its q loop (a ring of one on the kernels, trained
+    or not), and on no other path: not at P = 2 or 4, not on a
+    fallback."""
+    import warnings
+
+    from mpi_tpu import mpit
+
+    Pn = int(case[1]) if case.startswith("p") else 1
+    kw = dict(causal=True, window=13, interpret=True,
+              vmem_limit_bytes=_TILE8)
+    if case == "size1_forward_oob":
+        kw["vmem_limit_bytes"] = 1
+
+    def attn(qb):
+        return pallas_ring_attention(qb, qb, qb, "world", Pn, **kw)
+
+    fn = attn if case == "size1_inference" else \
+        jax.grad(lambda qb: jnp.sum(attn(qb)))
+    aval = jax.ShapeDtypeStruct((Pn * 32, 128), jnp.float32)
+    before = mpit.pvar_read("attention_direct_out")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # the fallbacks'
+        jax.jit(jax.shard_map(fn, mesh=default_mesh(Pn),
+                              in_specs=P("world"), out_specs=P("world"),
+                              check_vma=case == "size1_vma_fallback")
+                ).trace(aval)
+    assert mpit.pvar_read("attention_direct_out") - before == engaged

@@ -13,6 +13,7 @@ topology can be described.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -156,11 +157,22 @@ def test_ring_attention_compiles(mesh4, plan, backward):
     assert ("mpi_tpu_ring_attention_bwd" in text) == backward
 
 
+def _forward_results(text, kind):
+    """The result arrays (``f32[rows,lanes]``) of the compiled forward
+    attention kernel's custom call: a ring of one's are out and lse
+    alone, with no m/l/o working buffers in HBM."""
+    line, = [ln for ln in text.splitlines()
+             if f"mpi_tpu_{kind}_attention_fwd" in ln and "custom-call(" in ln]
+    return re.findall(r"[a-z]+[0-9]*\[[0-9,]*\]",
+                      line.split("custom-call(")[0].split("=", 1)[1])
+
+
 @pytest.mark.parametrize("window", [None, 1024], ids=["causal", "window"])
 def test_attention_ring_of_one_compiles_for_one_chip(topo, window):
     """Causal and sliding-window attention, forward and fused backward, on
     one chip: a ring of one, whose kernels are local (no side effects, no
-    collective), GQA with a group of 6, tiled at Sb 4096."""
+    collective), GQA with a group of 6, tiled at Sb 4096; the forward
+    returns out and lse alone."""
     mesh = Mesh(np.array(topo.devices[:1]), ("sp",))
     spec = P(None, "sp")
     q = jax.ShapeDtypeStruct((6, 4096, 128), jnp.bfloat16,
@@ -181,6 +193,7 @@ def test_attention_ring_of_one_compiles_for_one_chip(topo, window):
     assert text.count("tpu_custom_call") == 2
     assert "has_side_effect=true" not in text
     assert "collective-permute" not in text
+    assert _forward_results(text, kind) == [f"f32[{6 * 4096},128]"] * 2
 
 
 @pytest.mark.parametrize("window,hkv,sinks", [(128, 8, True),
@@ -191,7 +204,8 @@ def test_split_width_attention_compiles_for_one_chip(topo, window, hkv,
     """MiMo-V2-Flash's two layer kinds at their real sizes, forward and
     fused backward on one chip: q/k heads 192 wide (padded to 256 lanes
     before the kernels) and v heads 128, 64 query heads over 8 (window
-    128 on 128-row tiles, a sink per head) or 4 K/V heads (causal)."""
+    128 on 128-row tiles, a sink per head) or 4 K/V heads (causal): two
+    local kernels, the forward returning out and lse alone."""
     mesh = Mesh(np.array(topo.devices[:1]), ("sp",))
     spec = P(None, "sp")
     shard = NamedSharding(mesh, spec)
@@ -214,6 +228,8 @@ def test_split_width_attention_compiles_for_one_chip(topo, window, hkv,
     assert f"mpi_tpu_{kind}_attention_fwd" in text
     assert f"mpi_tpu_{kind}_attention_bwd" in text
     assert text.count("tpu_custom_call") == 2
+    assert "has_side_effect=true" not in text
+    assert _forward_results(text, kind) == [f"f32[{64 * 8192},128]"] * 2
 
 
 def test_jacobi_step_8192_compiles_for_one_chip(topo):
